@@ -443,7 +443,9 @@ _ALL: list[Knob] = [
        "SO_REUSEPORT worker pool size: N forks N serving processes "
        "sharing the listen port over the same drives (coherent via "
        "ns-lock quorum + cache invalidation broadcasts); 0 = auto from "
-       "nproc. Single-node deployments only for now."),
+       "nproc. Single-node deployments only for now. One process per "
+       "chip: worker 0 keeps `MINIO_TPU_BACKEND` as configured, workers "
+       "1..N-1 are started with `MINIO_TPU_BACKEND=numpy`."),
     _k("MINIO_TPU_WORKER_COUNT", "1", "server",
        "Set by the worker-pool supervisor on each child: total workers "
        "in the pool (divides the node-wide QoS admission budgets)."),

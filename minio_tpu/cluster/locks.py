@@ -108,7 +108,13 @@ class LocalLocker:
                 del e["readers"][uid]
             else:
                 e["readers"][uid] = (c - 1, exp)
-            if not e["readers"] and not e["writer"]:
+            if (
+                not e["readers"] and not e["writer"]
+                # a parked writer's marker must outlive the last reader:
+                # dropping the entry here would hand the resource straight
+                # back to the reader stream and starve the writer anyway
+                and e.get("wwait", 0.0) <= time.monotonic()
+            ):
                 del self._locks[resource]
             return True
 

@@ -194,6 +194,11 @@ class ErasureCoder:
             self._np = rs.get_codec(self.d, self.p)
         self._jax = None
         if _use_jax():
+            from ..ops import runtime
+
+            # before the first device compile of this process: place the
+            # persistent compile cache, then say which device this is
+            runtime.ensure_compile_cache()
             if self.family == FAMILY_CAUCHY:
                 from ..ops import cauchy as cauchy_mod
 
@@ -202,6 +207,7 @@ class ErasureCoder:
                 from ..ops import rs_jax  # deferred: jax import is heavy
 
                 self._jax = rs_jax.get_tpu_codec(self.d, self.p)
+            runtime.announce_device_plane()
 
     @property
     def device_active(self) -> bool:
@@ -522,7 +528,7 @@ class ErasureCoder:
             self._jax is not None
             and w * self.t >= int(os.environ.get("MINIO_TPU_DECODE_MIN_SHARDS", "64"))
         ):
-            from ..ops.bitrot_jax import _try_fused_decode
+            from ..ops.bitrot_jax import _try_fused_decode, count_xla_decode
             from ..ops.highwayhash import MINIO_KEY
 
             arr = survivors.transpose(1, 0, 2)  # [W, d, per]
@@ -530,8 +536,13 @@ class ErasureCoder:
             fused = _try_fused_decode(self._jax, arr, present, missing, MINIO_KEY)
             if fused is not None:
                 return fused[0].transpose(1, 0, 2)
-            out = self._jax.reconstruct_blocks(arr, present, missing)
-            return np.asarray(out).transpose(1, 0, 2)
+            # ascontiguousarray: the host layout of a TPU array is not
+            # promised row-major, and callers copy shard ROWS out of this
+            out = np.ascontiguousarray(
+                np.asarray(self._jax.reconstruct_blocks(arr, present, missing))
+            )
+            count_xla_decode(w)
+            return out.transpose(1, 0, 2)
         mat = self._decode_rows(present, missing)
         flat = survivors.reshape(self.d, w * per)
         if native.available():

@@ -303,8 +303,24 @@ _fused_dec_backoff = 8
 # plane) assert the decode mega-kernel actually carried degraded reads.
 # Lock-guarded: concurrent degraded GETs reconstruct on server worker
 # threads, and a bare += would drop counts.
-decode_stats = {"fused": 0, "blocks": 0, "failures": 0}
+# "fused"/"blocks" count mega-kernel dispatches and their blocks, "xla"/
+# "xla_blocks" the row-major XLA decode rung, "failures" swallowed
+# mega-kernel exceptions. Exported on /api/tpu (server/metrics.py): a
+# degraded GET that was rebuilt on the CPU moves none of the four.
+decode_stats = {"fused": 0, "blocks": 0, "failures": 0, "xla": 0, "xla_blocks": 0}
 _decode_stats_lock = threading.Lock()
+
+
+def decode_stats_snapshot() -> dict:
+    with _decode_stats_lock:
+        return dict(decode_stats)
+
+
+def count_xla_decode(blocks: int) -> None:
+    """One device decode dispatch served by the XLA rung."""
+    with _decode_stats_lock:
+        decode_stats["xla"] += 1
+        decode_stats["xla_blocks"] += blocks
 
 
 def _try_fused_decode(codec, survivors, present, missing, key):
@@ -338,13 +354,17 @@ def _try_fused_decode(codec, survivors, present, missing, key):
             tuple(present), tuple(missing), key,
         )
         rebuilt = fp.unpack_chunk_major(np.asarray(rebuilt_cm))[:b]
-        digs = np.asarray(digests)[:b]
+        # host layout of a TPU array is not promised row-major
+        digs = np.ascontiguousarray(np.asarray(digests)[:b])
         _fused_dec_backoff = 8
         with _decode_stats_lock:
             decode_stats["fused"] += 1
             decode_stats["blocks"] += b
         return rebuilt, digs[:, d:, :], digs[:, :d, :]
-    except Exception:  # noqa: BLE001 — lowering/device failure: XLA path
+    except Exception as e:  # noqa: BLE001 — lowering/device failure: XLA path
+        from . import runtime
+
+        runtime.report_rung_failure("fused-decode", f"{d}+{m}x{bpad}x{n}", e)
         _fused_dec_cooldown = _fused_dec_backoff
         _fused_dec_backoff = min(_fused_dec_backoff * 2, 1024)
         with _decode_stats_lock:
@@ -382,6 +402,7 @@ def reconstruct_and_hash(
     rebuilt = codec.reconstruct_blocks(survivors, present, missing)
     hash_fn = _select_hash_fn()
     digests = hash_fn(rebuilt.reshape(b * m, n), key).reshape(b, m, 32)
+    count_xla_decode(b)
     return rebuilt, digests
 
 

@@ -37,6 +37,7 @@ import numpy as np
 
 from .. import obs
 from ..fault import registry as fault_registry
+from ..ops import runtime
 from ..qos.context import (
     PRI_BACKGROUND,
     PRI_FOREGROUND,
@@ -365,7 +366,8 @@ class TpuDispatcher:
         # miniovet: ignore[error-taint] -- this IS the degradation ladder:
         # a fused-rung failure falls to the XLA rung (byte-identical
         # results), is counted in fused_failures, and backs off
-        except Exception:  # noqa: BLE001 — lowering/device failure: XLA path
+        except Exception as e:  # noqa: BLE001 — lowering/device failure: XLA path
+            runtime.report_rung_failure("fused", f"{self._shape}x{b}x{n}", e)
             # back off exponentially and re-probe: one transient device
             # hiccup must not degrade the server until restart
             self._fused_cooldown = self._fused_backoff
@@ -425,7 +427,8 @@ class TpuDispatcher:
             return True
         # miniovet: ignore[error-taint] -- ladder probe: False means "stay
         # demoted"; the synthetic batch exists to absorb this failure
-        except Exception:  # noqa: BLE001 — device still gone
+        except Exception as e:  # noqa: BLE001 — device still gone
+            runtime.report_rung_failure("probe", self._shape, e)
             return False
 
     def _encode_numpy(self, blocks: np.ndarray, family: str = "reedsolomon"):
@@ -584,7 +587,10 @@ class TpuDispatcher:
                     # np.asarray is the device sync point: execute + D2H
                     # land inside the device window, fan-out is host time
                     parity = np.asarray(parity)[:k]
-                    digests = np.asarray(digests)[:k]
+                    # a TPU array can arrive on the host in the device's
+                    # own (non row-major) layout: waiters frame digest
+                    # ROWS as writev buffers, which must be C-contiguous
+                    digests = np.ascontiguousarray(np.asarray(digests)[:k])
                     shards = np.concatenate(
                         [all_blocks[:k], parity], axis=1
                     )  # [B, t, n]
@@ -606,6 +612,9 @@ class TpuDispatcher:
                     # the device rung failed mid-batch: waiters get
                     # numpy results instead of errors, the ladder
                     # counts the fault and demotes past the threshold
+                    runtime.report_rung_failure(
+                        "device", f"{self._shape}x{all_blocks.shape[0]}x{n}", e
+                    )
                     self._device_fault(e)
                     was_fused = False
                     shards = None

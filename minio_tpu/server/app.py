@@ -11,6 +11,7 @@ from __future__ import annotations
 import asyncio
 import hashlib
 import os
+import sys
 
 from aiohttp import web
 
@@ -707,7 +708,15 @@ class S3Server(
             return self._err_response(request, s3err.NoSuchKey)
         except quorum.VersionNotFound:
             return self._err_response(request, s3err.NoSuchVersion)
-        except quorum.QuorumError:
+        except quorum.QuorumError as e:
+            # a 500 has to say why: the quorum error carries the cause
+            # (lock timeout/loss, or the per-drive errors that broke it)
+            causes = sorted({repr(x) for x in e.errs if x is not None})
+            print(
+                f"500 InternalError {request.method} {request.path}: "
+                f"QuorumError: {e}; drive errors: {causes}"[:2000],
+                file=sys.stderr, flush=True,
+            )
             return self._err_response(request, s3err.InternalError)
         except asyncio.CancelledError:
             # client disconnect: propagate so aiohttp abandons the request
@@ -1117,8 +1126,6 @@ def main(argv: list[str] | None = None) -> None:
     if wid is None:
         n_workers = workermod.resolve_worker_count()
         if n_workers > 1:
-            import sys
-
             probe_eps = parse_endpoints(
                 [p for spec in args.drives for p in ellipses.expand(spec)],
                 my_port,
@@ -1266,9 +1273,14 @@ def main(argv: list[str] | None = None) -> None:
                 # remote RPC) — keep it off the event loop, which must stay
                 # responsive for peers' storage/lock RPCs
                 await loop.run_in_executor(None, srv.set_store, store)
+                who = (
+                    f"worker {worker_index}/{worker_count}: "
+                    if worker_count > 1 else ""
+                )
                 print(
-                    f"object layer online: {len(store.pools)} pool(s), "
-                    f"{len(store.disks)} drives, distributed={distributed}",
+                    f"{who}object layer online: {len(store.pools)} pool(s), "
+                    f"{len(store.disks)} drives, distributed={distributed}, "
+                    f"{workermod.plane()}",
                     flush=True,
                 )
                 return

@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 from collections import defaultdict
@@ -983,6 +984,43 @@ def _g_api_tpu(server) -> list[str]:
          [({}, ds.get("fused", 0))])
     _fmt(out, "minio_tpu_fused_failures_total", "counter",
          [({}, ds.get("fused_failures", 0))])
+    # decode side of the ladder (ops/bitrot_jax.decode_stats): which rung
+    # rebuilt degraded reads. Read only if the module is already loaded —
+    # a scrape must not import jax into a CPU-plane process.
+    bj = sys.modules.get("minio_tpu.ops.bitrot_jax")
+    dec = bj.decode_stats_snapshot() if bj is not None else {}
+    _fmt(out, "minio_tpu_decode_dispatches_total", "counter",
+         [({"rung": "fused"}, dec.get("fused", 0)),
+          ({"rung": "xla"}, dec.get("xla", 0))],
+         "Device reconstruct dispatches by ladder rung (a degraded read "
+         "rebuilt on the host moves neither)")
+    _fmt(out, "minio_tpu_decode_device_blocks_total", "counter",
+         [({"rung": "fused"}, dec.get("blocks", 0)),
+          ({"rung": "xla"}, dec.get("xla_blocks", 0))])
+    _fmt(out, "minio_tpu_fused_decode_failures_total", "counter",
+         [({}, dec.get("failures", 0))])
+    # device runtime (ops/runtime.py): which device this process holds and
+    # what it compiled vs loaded from the persistent compile cache; zeros
+    # and no device row on a CPU-plane process
+    from ..ops import runtime
+
+    comp = runtime.compile_stats() or {}
+    _fmt(out, "minio_tpu_compile_programs_total", "counter",
+         [({}, comp.get("programs", 0))],
+         "Programs through the backend compiler (cache loads included)")
+    _fmt(out, "minio_tpu_compile_seconds_total", "counter",
+         [({}, f"{comp.get('compile_s', 0.0):.3f}")])
+    _fmt(out, "minio_tpu_compile_cache_total", "counter",
+         [({"result": "hit"}, comp.get("cache_hits", 0)),
+          ({"result": "miss"}, comp.get("cache_misses", 0))],
+         "Persistent compile-cache entries loaded (hit) or compiled "
+         "and written (miss); sub-second compiles are neither")
+    # only where a device plane already runs (comp is None-turned-{} on
+    # the CPU plane): a scrape must never be what grabs the chip
+    dev = runtime.device_info() if comp else None
+    _fmt(out, "minio_tpu_device_info", "gauge",
+         [(dict(dev, count=str(dev["count"])), 1)] if dev else [],
+         "The device JAX reports in this process (absent on the CPU plane)")
     _fmt(out, "minio_tpu_dispatch_bg_forced_blocks_total", "counter",
          [({}, ds.get("bg_forced", 0))])
     _fmt(out, "minio_tpu_dispatch_fg_deferred_behind_bg_total", "counter",

@@ -29,6 +29,15 @@ Each worker therefore needs an **addressable** endpoint of its own
 ``i`` binds a loopback *control* listener on ``port_base + i`` serving
 the same aiohttp app (grid, locks, storage REST, admin, metrics).
 
+One process per chip: an accelerator belongs to one process at a time,
+so exactly ONE worker (index ``DEVICE_WORKER``) inherits the operator's
+backend selection and may take the device; every other worker is started
+explicitly on the CPU plane (``MINIO_TPU_BACKEND=numpy``) instead of
+racing it for the chip and failing or hanging at backend init. Each
+worker's boot line says which plane it is. The supervisor itself never
+imports jax. (Whether the pool should instead feed one device-owning
+process, or run one worker per chip, is ROADMAP D5 — not decided here.)
+
 Supervision: the parent is a dumb process herder — no sockets, no store.
 It forwards SIGTERM/SIGINT to the children, restarts a worker that dies
 unexpectedly (throttled: a worker crashing repeatedly right after boot
@@ -54,6 +63,11 @@ import time
 ENV_INDEX = "MINIO_TPU_WORKER_INDEX"
 ENV_COUNT = "MINIO_TPU_WORKER_COUNT"
 ENV_PORT_BASE = "MINIO_TPU_WORKER_PORT_BASE"
+
+# the backend selector the CPU-plane workers are pinned with
+ENV_BACKEND = "MINIO_TPU_BACKEND"
+# the one worker that keeps the operator's backend (and so the chip)
+DEVICE_WORKER = 0
 
 MAX_WORKERS = 64
 # a worker dying this soon after spawn counts against the crash budget
@@ -106,6 +120,26 @@ def worker_identity() -> tuple[int, int, int] | None:
             f"port_base={base}"
         )
     return idx, count, base
+
+
+def worker_env(base_env: dict[str, str], index: int) -> dict[str, str]:
+    """Environment for pool worker `index`: its identity, and — for every
+    worker but DEVICE_WORKER — the numpy codec, so one process at most
+    reaches for the chip."""
+    env = dict(base_env)
+    env[ENV_INDEX] = str(index)
+    if index != DEVICE_WORKER:
+        env[ENV_BACKEND] = "numpy"
+    return env
+
+
+def plane() -> str:
+    """Which codec plane THIS process runs, for its boot line: "device"
+    (the jax codec on JAX's default device — may take the accelerator) or
+    "cpu" (pinned to the numpy/native codec; never imports jax)."""
+    if os.environ.get(ENV_BACKEND) == "numpy":
+        return f"cpu plane ({ENV_BACKEND}=numpy)"
+    return "device plane (JAX default device)"
 
 
 def control_port(port_base: int, index: int) -> int:
@@ -167,10 +201,9 @@ def supervise(argv: list[str], workers: int, my_port: int,
     base_env[ENV_PORT_BASE] = str(port_base)
 
     def spawn(i: int) -> subprocess.Popen:
-        env = dict(base_env)
-        env[ENV_INDEX] = str(i)
         return subprocess.Popen(
-            [sys.executable, "-m", "minio_tpu.server", *argv], env=env
+            [sys.executable, "-m", "minio_tpu.server", *argv],
+            env=worker_env(base_env, i),
         )
 
     procs: dict[int, subprocess.Popen] = {i: spawn(i) for i in range(workers)}
@@ -194,7 +227,8 @@ def supervise(argv: list[str], workers: int, my_port: int,
     print(
         f"worker pool: {workers} workers on shared port {my_port} "
         f"(SO_REUSEPORT), control ports {port_base}..."
-        f"{port_base + workers - 1}",
+        f"{port_base + workers - 1}; worker {DEVICE_WORKER} keeps the "
+        f"configured backend, the others run {ENV_BACKEND}=numpy",
         flush=True,
     )
 

@@ -225,24 +225,30 @@ def test_hedged_read_decodes_around_straggler(tmp_path, monkeypatch):
     t0 = time.monotonic()
     _, it = es.get_object("cbkt", "straggly")
     got = b"".join(it)
-    elapsed = time.monotonic() - t0
+    hedged_s = time.monotonic() - t0
     assert got == body
     after = _counters()
-    hedged = after["hedge_reads"] - before["hedge_reads"]
-    wins = after["hedge_wins"] - before["hedge_wins"]
-    if hedged:
-        # the straggler's 500 ms never reaches the caller
-        assert elapsed < 0.45, f"hedge fired but GET took {elapsed:.3f}s"
-        assert wins >= 1, "hedge fired and beat a 500ms straggler: must win"
-    else:
-        pytest.fail("500ms straggler never triggered a hedged read")
+    assert after["hedge_reads"] > before["hedge_reads"], \
+        "500ms straggler never triggered a hedged read"
+    assert after["hedge_wins"] > before["hedge_wins"], \
+        "hedge fired and beat a 500ms straggler: must win"
 
-    # hedge off: the same GET inherits the straggler's latency
+    # hedge off: the same GET on the same (possibly loaded) host inherits
+    # the straggler — this is the injected latency as actually delivered
     monkeypatch.setenv("MINIO_TPU_HEDGE", "0")
     t0 = time.monotonic()
     _, it = es.get_object("cbkt", "straggly")
     assert b"".join(it) == body
-    assert time.monotonic() - t0 >= 0.45
+    inherited_s = time.monotonic() - t0
+    assert inherited_s >= 0.45  # a sleep's lower bound holds under any load
+    # the straggler's 500 ms never reached the hedged caller: judged
+    # against the latency measured here, not a fixed wall-clock bound (a
+    # loaded CI host inflates both GETs alike), the hedge saved at least
+    # half of what was injected
+    assert inherited_s - hedged_s >= 0.25, (
+        f"hedged GET {hedged_s:.3f}s vs straggler-bound GET "
+        f"{inherited_s:.3f}s"
+    )
 
 
 def test_latency_breaker_trips_chronically_slow_drive(tmp_path):
@@ -776,6 +782,7 @@ def test_drive_failure_storm_family_ingress(tmp_path, monkeypatch):
         problems: list[str] = []
         stop = threading.Event()
         mu = threading.Lock()
+        verified = [0]  # GETs that came back byte-exact
 
         def reader():
             while not stop.is_set():
@@ -790,6 +797,18 @@ def test_drive_failure_storm_family_ingress(tmp_path, monkeypatch):
                     with mu:
                         problems.append("wrong bytes served")
                     return
+                with mu:
+                    verified[0] += 1
+
+        def traffic_flowed(n=4):
+            """Block until n more GETs verified (each reader ~once): the
+            'mid-traffic' in this schedule is an observed count, not a
+            sleep that a loaded host turns into zero reads."""
+            target = verified[0] + n
+            deadline = time.monotonic() + 60
+            while verified[0] < target and not problems:
+                assert time.monotonic() < deadline, "readers stalled"
+                time.sleep(0.01)
 
         threads = [threading.Thread(target=reader) for _ in range(4)]
         for t in threads:
@@ -802,20 +821,20 @@ def test_drive_failure_storm_family_ingress(tmp_path, monkeypatch):
             shutil.rmtree(root / f"d{lost_a}" / "storm" / "obj")
             shutil.rmtree(root / f"d{lost_b}" / "storm" / "obj")
             es.cache.clear()
-            time.sleep(0.2)
+            traffic_flowed()  # degraded GETs under double failure
             res = es.heal_object("storm", "obj")
             assert sorted(res["healed"]) == sorted(
                 [disks[lost_a].endpoint, disks[lost_b].endpoint]
             ), res
             assert not res["partialRepair"]  # 2 stale -> generic rebuild
-            time.sleep(0.1)
+            traffic_flowed()  # healthy again before the next loss
             # phase 2: a single data drive dies — the repair-bandwidth
             # case the second family exists for
             before = family_stats_snapshot()[fam]["heal_ingress_bytes"]
             lost_c = dist.index(2)       # data shard 1
             shutil.rmtree(root / f"d{lost_c}" / "storm" / "obj")
             es.cache.clear()
-            time.sleep(0.2)
+            traffic_flowed()  # degraded GETs under the single loss
             res = es.heal_object("storm", "obj")
             assert res["healed"] == [disks[lost_c].endpoint], res
             assert res["partialRepair"] == (fam == "cauchy")

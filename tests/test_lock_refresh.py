@@ -77,3 +77,48 @@ def test_streaming_put_aborts_on_lock_loss(tmp_path, monkeypatch):
     # pre-existing object untouched
     _, it = es.get_object("lkb", "obj")
     assert b"".join(it) == old
+
+
+def test_writer_not_starved_by_reader_stream():
+    """Writer priority must survive the LAST reader's unlock: the parked
+    writer's marker lives on the lock-table entry, and dropping the entry
+    with the last reader handed the resource straight back to the reader
+    stream — a heal's write lock then timed out (30 s) behind four GET
+    loops. More readers than the GIL can run at once, busy holds, and
+    every writer attempt must get in well inside its deadline."""
+    ns = NamespaceLock()
+    stop = threading.Event()
+    reads = [0]
+
+    def reader():
+        while not stop.is_set():
+            m = ns.new("bkt", "hot")
+            if m.rlock(5.0):
+                t = time.monotonic()
+                while time.monotonic() - t < 0.01:
+                    pass  # busy hold: contend for the GIL like a decode
+                reads[0] += 1
+                m.runlock()
+
+    threads = [threading.Thread(target=reader) for _ in range(4)]
+    for t in threads:
+        t.start()
+    try:
+        waits = []
+        for _ in range(4):
+            before = reads[0]
+            w = ns.new("bkt", "hot")
+            t0 = time.monotonic()
+            assert w.lock(10.0), f"writer starved by readers: {waits}"
+            waits.append(time.monotonic() - t0)
+            w.unlock()
+            # readers flow again between writers (no permanent lockout)
+            deadline = time.monotonic() + 5.0
+            while reads[0] == before and time.monotonic() < deadline:
+                time.sleep(0.005)
+            assert reads[0] > before, "readers never resumed after the writer"
+    finally:
+        stop.set()
+        for t in threads:
+            t.join(timeout=10)
+    assert not any(t.is_alive() for t in threads)
