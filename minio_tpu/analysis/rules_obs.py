@@ -9,12 +9,17 @@ context-manager API::
     with obs.span(obs.TYPE_STORAGE, "readfile", drive=ep) as sp:
         ...
 
+``obs.phase(...)`` (the always-on phase clock) opens the same span when
+someone subscribes, and enters a profiler annotation besides: it is held
+to the same discipline.
+
 This rule flags, everywhere outside ``obs/`` itself:
 
 - any ``obs.span(...)`` / ``trace.span(...)`` / imported ``span(...)``
-  call that is not the context expression of a ``with`` (or
-  ``async with``) item — including ``span(...).__enter__()`` trickery;
-- direct ``Span(...)`` construction and any ``span_start``/``start_span``
+  call — and the same for ``phase`` — that is not the context expression
+  of a ``with`` (or ``async with``) item — including
+  ``span(...).__enter__()`` trickery;
+- direct ``Span(...)`` / ``Phase(...)`` construction and any ``span_start``/``start_span``
   call (no such API exists; if one appears, it is a bug by definition).
 """
 
@@ -26,33 +31,36 @@ from typing import Iterator
 from .core import Finding, dotted_name, rule
 
 _ORPHAN_NAMES = {"span_start", "start_span"}
+_OPENERS = {"span", "phase"}  # context-manager-only factories of obs
+_CLASSES = {"Span", "Phase"}
 
 
-def _is_span_call(node: ast.Call, span_imported: bool) -> bool:
+def _is_span_call(node: ast.Call, imported: set[str]) -> bool:
     name = dotted_name(node.func)
     if name is None:
         return False
-    if name == "span":
-        return span_imported
-    return name.endswith(".span") and name.split(".")[-2] in ("obs", "trace")
+    if name in _OPENERS:
+        return name in imported
+    parts = name.split(".")
+    return parts[-1] in _OPENERS and parts[-2] in ("obs", "trace")
 
 
-def _span_imported_from_obs(tree: ast.AST) -> bool:
+def _openers_imported_from_obs(tree: ast.AST) -> set[str]:
+    found: set[str] = set()
     for node in ast.walk(tree):
         if isinstance(node, ast.ImportFrom) and node.module and (
             node.module == "obs" or node.module.endswith(".obs")
             or node.module.endswith("obs.trace")
         ):
-            if any(a.name == "span" for a in node.names):
-                return True
-    return False
+            found |= {a.name for a in node.names} & _OPENERS
+    return found
 
 
 @rule("span")
 def check_span_discipline(tree: ast.AST, ctx) -> Iterator[Finding]:
     if ctx.relpath.startswith("obs/"):
         return  # the span implementation itself
-    span_imported = _span_imported_from_obs(tree)
+    imported = _openers_imported_from_obs(tree)
     with_exprs: set[int] = set()
     for node in ast.walk(tree):
         if isinstance(node, (ast.With, ast.AsyncWith)):
@@ -71,15 +79,18 @@ def check_span_discipline(tree: ast.AST, ctx) -> Iterator[Finding]:
                 "finally leaks the trace context token",
             )
             continue
-        if short == "Span" and (name == "Span" or name.endswith("obs.Span")
-                                or name.endswith("trace.Span")):
+        if short in _CLASSES and (
+            name == short or name.endswith(f"obs.{short}")
+            or name.endswith(f"trace.{short}")
+        ):
             yield Finding(
                 ctx.path, node.lineno, "span",
-                "direct Span construction: use obs.span(...), which is "
-                "zero-cost when tracing is idle",
+                f"direct {short} construction: use obs.{short.lower()}(...)"
+                + (", which is zero-cost when tracing is idle"
+                   if short == "Span" else ""),
             )
             continue
-        if _is_span_call(node, span_imported) and id(node) not in with_exprs:
+        if _is_span_call(node, imported) and id(node) not in with_exprs:
             yield Finding(
                 ctx.path, node.lineno, "span",
                 f"{name}(...) outside a `with` statement: spans must be "

@@ -41,9 +41,12 @@ _HOT_PATH_GLOBS = (
 HOSTSYNC_BOUNDARY: dict[str, set[str]] = {
     # batch fan-out: futures hand numpy shards back to request threads;
     # the degradation probe's materialization IS the probe verdict
-    # (_dispatch_group is the per-family half of the old _loop body)
+    # (_dispatch_group is the per-family half of the old _loop body;
+    # _encode_on_device is its device attempt, where every phase of the
+    # phase clock ends synced so that its seconds are what the step took)
     "parallel/dispatcher.py": {
-        "_loop", "_dispatch_group", "_fused_cm", "_probe_device",
+        "_loop", "_dispatch_group", "_encode_on_device", "_fused_cm",
+        "_probe_device",
     },
     # decode boundary: rebuilt shards + digests materialize for the
     # bitrot/write plane

@@ -30,6 +30,7 @@ from typing import Iterator
 
 import numpy as np
 
+from .. import obs
 from ..ops import rs
 from ..ops.highwayhash import hash256_batch_numpy
 from . import bitrot_io, bufpool
@@ -258,9 +259,11 @@ class ErasureCoder:
         ):
             from ..parallel.dispatcher import get_dispatcher
 
-            return get_dispatcher(self._jax, blocks.shape[2]).encode(
-                blocks, codec=self._jax
-            )
+            # submit -> result: queue wait + the whole dispatch
+            with obs.phase("put", "encode_wait"):
+                return get_dispatcher(self._jax, blocks.shape[2]).encode(
+                    blocks, codec=self._jax
+                )
         family_stats_add(self.family, "encode_blocks", blocks.shape[0])
         return encode_blocks_numpy(self._np, blocks, self.family)
 
@@ -271,15 +274,16 @@ class ErasureCoder:
         per = self.shard_size
         padded_block = self.d * per  # >= block_size; zero padding at tail
         bufpool.count_copy("staging")  # bytes -> numpy staging materialization
-        arr = np.zeros((full, self.d, per), dtype=np.uint8)
-        flat = np.frombuffer(data, dtype=np.uint8)
-        if padded_block == self.block_size:
-            arr[:] = flat.reshape(full, self.d, per)
-        else:
-            for b in range(full):
-                blk = flat[b * self.block_size : (b + 1) * self.block_size]
-                a = arr[b].reshape(-1)
-                a[: self.block_size] = blk
+        with obs.phase("put", "stage"):
+            arr = np.zeros((full, self.d, per), dtype=np.uint8)
+            flat = np.frombuffer(data, dtype=np.uint8)
+            if padded_block == self.block_size:
+                arr[:] = flat.reshape(full, self.d, per)
+            else:
+                for b in range(full):
+                    blk = flat[b * self.block_size : (b + 1) * self.block_size]
+                    a = arr[b].reshape(-1)
+                    a[: self.block_size] = blk
         files = [bytearray() for _ in range(self.t)]
         max_blocks = max(1, MAX_DEVICE_SHARDS // self.t)
         cauchy = self.family == FAMILY_CAUCHY
@@ -287,16 +291,17 @@ class ErasureCoder:
         for start in range(0, full, max_blocks):
             chunk = arr[start : start + max_blocks]
             shards, digests = self._encode_full_blocks(chunk)
-            for b in range(chunk.shape[0]):
-                for i in range(self.t):
-                    if cauchy:
-                        files[i] += digests[b, i, 0].tobytes()
-                        files[i] += shards[b, i, :h1].tobytes()
-                        files[i] += digests[b, i, 1].tobytes()
-                        files[i] += shards[b, i, h1:].tobytes()
-                    else:
-                        files[i] += digests[b, i].tobytes()
-                        files[i] += shards[b, i].tobytes()
+            with obs.phase("put", "frame"):
+                for b in range(chunk.shape[0]):
+                    for i in range(self.t):
+                        if cauchy:
+                            files[i] += digests[b, i, 0].tobytes()
+                            files[i] += shards[b, i, :h1].tobytes()
+                            files[i] += digests[b, i, 1].tobytes()
+                            files[i] += shards[b, i, h1:].tobytes()
+                        else:
+                            files[i] += digests[b, i].tobytes()
+                            files[i] += shards[b, i].tobytes()
         bufpool.count_copy("frame-tobytes", full * self.t)
         return files
 
@@ -335,24 +340,34 @@ class ErasureCoder:
         if max_batch_bytes is not None:
             batch_bytes = min(batch_bytes, max(self.block_size, max_batch_bytes))
         buf = bytearray()
+        # `ingest` is what this generator spends pulling reader chunks
+        # between two batches (about a thousand chunks per object): one
+        # clock pair per batch, booked when the batch is cut
+        ingest = obs.PhaseClock("put", "ingest")
         for chunk in reader:
             if not chunk:
                 continue
             buf += chunk
             while len(buf) >= batch_bytes:
-                bufpool.count_copy("staging")
-                piece = bytes(buf[:batch_bytes])
-                del buf[:batch_bytes]
-                yield self._encode_full_buffer(memoryview(piece)), piece
+                ingest.book()
+                yield self._cut(buf, batch_bytes)
+                ingest.restart()
+        if buf:
+            ingest.book()
         full = (len(buf) // self.block_size) * self.block_size
         if full:
-            bufpool.count_copy("staging")
-            piece = bytes(buf[:full])
-            del buf[:full]
-            yield self._encode_full_buffer(memoryview(piece)), piece
+            yield self._cut(buf, full)
         if buf:
             piece = bytes(buf)
             yield self._encode_tail_buffer(piece), piece
+
+    def _cut(self, buf: bytearray, nbytes: int) -> tuple[list[bytearray], bytes]:
+        """Take the first nbytes (whole stripe blocks) off `buf`, encoded."""
+        bufpool.count_copy("staging")
+        with obs.phase("put", "stage"):
+            piece = bytes(buf[:nbytes])
+            del buf[:nbytes]
+        return self._encode_full_buffer(memoryview(piece)), piece
 
     def _frame_into(
         self, vecs: list[list], shards: np.ndarray, digests: np.ndarray
@@ -386,7 +401,8 @@ class ErasureCoder:
         max_blocks = max(1, MAX_DEVICE_SHARDS // self.t)
         for start in range(0, full, max_blocks):
             shards, digests = self._encode_full_blocks(arr[start : start + max_blocks])
-            self._frame_into(vecs, shards, digests)
+            with obs.phase("put", "frame"):
+                self._frame_into(vecs, shards, digests)
         return EncodedBatch(vecs, lease.view(nbytes), lease)
 
     def iter_encode_zc(
@@ -408,7 +424,9 @@ class ErasureCoder:
         per = self.shard_size
         if self.d * per != self.block_size or not bufpool.zerocopy_enabled():
             for chunks, raw in self.iter_encode(reader, max_batch_bytes):
-                yield EncodedBatch([[bytes(c)] for c in chunks], raw)
+                with obs.phase("put", "frame"):
+                    vecs = [[bytes(c)] for c in chunks]
+                yield EncodedBatch(vecs, raw)
             return
         batch_blocks = max(1, MAX_DEVICE_SHARDS // self.t)
         if max_batch_bytes is not None:
@@ -425,6 +443,7 @@ class ErasureCoder:
         lease = None
         mv: memoryview | None = None
         pos = 0
+        ingest = obs.PhaseClock("put", "ingest")  # as in iter_encode: booked once per batch
         try:
             for chunk in reader:
                 if not chunk:
@@ -441,9 +460,12 @@ class ErasureCoder:
                     pos += n
                     off += n
                     if pos == batch_bytes:
+                        ingest.book()
                         batch, lease, mv = self._emit_zc(lease, pos), None, None
                         yield batch
+                        ingest.restart()
             if lease is not None:
+                ingest.book()
                 full = (pos // self.block_size) * self.block_size
                 # the tail residue is copied OUT of the arena before the
                 # full-block batch hands the lease to the caller

@@ -576,10 +576,12 @@ class ErasureSet:
 
         def drive_op(i: int, fn, *args):
             if errs[i] is None:
-                try:
-                    fn(*args)
-                except Exception as e:  # noqa: BLE001
-                    errs[i] = e
+                # wall and CPU of the drive call itself, on the pool's thread
+                with obs.phase("put", "drive_io"):
+                    try:
+                        fn(*args)
+                    except Exception as e:  # noqa: BLE001
+                        errs[i] = e
 
         futs = [
             self._pool.submit(drive_op, i, disk.create_file, TMP_VOLUME, stage, b"")
@@ -632,17 +634,19 @@ class ErasureSet:
                                 f"write lock on {bucket}/{obj} lost mid-stream;"
                                 " aborting"
                             )
-                        md5.update(batch.raw)
+                        with obs.phase("put", "md5"):
+                            md5.update(batch.raw)
                         size += len(batch.raw)
-                        futs = []
-                        for i, disk in enumerate(self.disks):
-                            shard_idx = fi.erasure.distribution[i] - 1
-                            futs.append(self._pool.submit(
-                                drive_op, i, disk.append_file, TMP_VOLUME, stage,
-                                batch.shard_vecs[shard_idx],
-                            ))
-                        for f in futs:
-                            f.result()
+                        with obs.phase("put", "drive_write"):
+                            futs = []
+                            for i, disk in enumerate(self.disks):
+                                shard_idx = fi.erasure.distribution[i] - 1
+                                futs.append(self._pool.submit(
+                                    drive_op, i, disk.append_file, TMP_VOLUME,
+                                    stage, batch.shard_vecs[shard_idx],
+                                ))
+                            for f in futs:
+                                f.result()
                         batch.release()
                         batch = None
                         if sum(e is None for e in errs) < write_q:
@@ -685,12 +689,13 @@ class ErasureSet:
                     f"write lock on {bucket}/{obj} lost before commit; aborting"
                 )
             renamed = True
-            futs = [
-                self._pool.submit(drive_op, i, commit_one, i, disk)
-                for i, disk in enumerate(self.disks)
-            ]
-            for f in futs:
-                f.result()
+            with obs.phase("put", "commit"):
+                futs = [
+                    self._pool.submit(drive_op, i, commit_one, i, disk)
+                    for i, disk in enumerate(self.disks)
+                ]
+                for f in futs:
+                    f.result()
             reduce_quorum_errs(errs, write_q)
         except Exception:
             for disk, err in zip(self.disks, errs):

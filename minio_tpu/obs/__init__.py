@@ -21,6 +21,7 @@ Spans are opened ONLY via the context-manager API::
 
 from .trace import (  # noqa: F401
     NOOP_SPAN,
+    PHASES,
     TRACE_TYPES,
     TYPE_DIAG,
     TYPE_FAULT,
@@ -33,11 +34,15 @@ from .trace import (  # noqa: F401
     TYPE_SCANNER,
     TYPE_STORAGE,
     TYPE_TPU,
+    Phase,
+    PhaseClock,
     Span,
     active,
     bind_context,
     current_request_id,
     new_request_id,
+    phase,
+    phases_snapshot,
     publish,
     publisher,
     request_context,

@@ -6,6 +6,13 @@ building a record): ``span()`` returns the shared ``NOOP_SPAN`` singleton
 is attached AND it has subscribers. Code on the hot path may therefore
 open spans unconditionally.
 
+``phase()`` is the always-on sibling: it adds wall seconds, thread CPU
+seconds and one call to a process-global table whether or not anyone
+subscribes (``phases_snapshot()``; exported on ``/api/tpu``), publishes
+the same ``Span`` record when someone does, and — for the dispatch
+thread's leaf phases only — enters a ``jax.profiler.TraceAnnotation``
+so the phase lands in a device trace on the profiler's own clock.
+
 The request context is a ``contextvars.ContextVar`` so it survives both
 ``await`` hops and executor hops (``ContextPool``/``bind_context`` copy
 the context across thread boundaries; storage-REST carries it in an
@@ -18,6 +25,8 @@ import contextvars
 import itertools
 import os
 import socket
+import sys
+import threading
 import time
 from contextlib import contextmanager
 
@@ -115,6 +124,21 @@ def publish(record: dict) -> None:
         p.publish(record)
 
 
+def _record(trace_type: str, name: str, req_id: str, span_id: int,
+            parent_id: int, dur_s: float, error: str = "") -> dict:
+    return {
+        "time": time.time(),
+        "type": trace_type,
+        "name": name,
+        "reqId": req_id,
+        "spanId": span_id,
+        "parentId": parent_id,
+        "node": NODE,
+        "durationNs": int(dur_s * 1e9),
+        "error": error,
+    }
+
+
 class Span:
     """One timed, typed trace record; context-manager only (see the
     ``span`` miniovet rule). Publishes on exit with the error captured
@@ -157,17 +181,11 @@ class Span:
                 pass
         p = _publisher
         if p is not None and p.active:
-            rec = {
-                "time": time.time(),
-                "type": self.trace_type,
-                "name": self.name,
-                "reqId": self.req_id,
-                "spanId": self.span_id,
-                "parentId": self.parent_id,
-                "node": NODE,
-                "durationNs": int(dur * 1e9),
-                "error": "" if exc is None else f"{type(exc).__name__}: {exc}",
-            }
+            rec = _record(
+                self.trace_type, self.name, self.req_id, self.span_id,
+                self.parent_id, dur,
+                "" if exc is None else f"{type(exc).__name__}: {exc}",
+            )
             rec.update(self.fields)
             p.publish(rec)
         return False  # propagate exceptions
@@ -199,3 +217,131 @@ def span(trace_type: str, name: str, **fields):
     if p is None or not p.active:
         return NOOP_SPAN
     return Span(trace_type, name, fields)
+
+
+# -- phases: the always-on aggregate --------------------------------------
+
+# every (layer, phase) the program books, pre-seeded so a scrape never
+# sees the table change size. `dispatch` phases are leaves that tile the
+# dispatch thread's time (parallel/dispatcher.py); `put` phases run on the
+# request thread of a streaming PUT (erasure/set.py, erasure/coder.py),
+# except `drive_io`, which the drive pool's threads book.
+PHASES = {
+    "dispatch": ("wait", "window", "assemble", "pack", "h2d", "kernel",
+                 "d2h", "unpack", "frame", "numpy", "fanout"),
+    "put": ("ingest", "stage", "encode_wait", "frame", "md5",
+            "drive_write", "commit", "drive_io"),
+}
+_PHASE_TYPES = {"dispatch": TYPE_TPU, "put": TYPE_INTERNAL}
+_phase_mu = threading.Lock()
+# (layer, name) -> [wall seconds, thread CPU seconds, calls]
+_phase_table: dict[tuple[str, str], list] = {
+    (layer, name): [0.0, 0.0, 0]
+    for layer, names in PHASES.items() for name in names
+}
+
+
+def phases_snapshot() -> dict[tuple[str, str], tuple[float, float, int]]:
+    """{(layer, phase): (wall_s, cpu_s, calls)} since process start, taken
+    under the one lock the writers hold, so no row is ever torn."""
+    with _phase_mu:
+        return {k: (v[0], v[1], v[2]) for k, v in _phase_table.items()}
+
+
+def _phase_book(row: list, wall_s: float, cpu_s: float) -> None:
+    with _phase_mu:
+        row[0] += wall_s
+        row[1] += cpu_s
+        row[2] += 1
+
+
+class PhaseClock:
+    """A stopwatch for phase time that no ``with`` block can hold — a
+    generator's ingest between two yields. ``book()`` adds the wall and
+    thread CPU seconds since the last ``restart()`` (or construction) as
+    one call, and publishes the record a ``phase()`` would when tracing is
+    active; ``restart()`` after the yield leaves the consumer's time out."""
+
+    __slots__ = ("_layer", "_name", "_row", "_t0", "_c0")
+
+    def __init__(self, layer: str, name: str):
+        self._layer, self._name = layer, name
+        self._row = _phase_table[(layer, name)]
+        self.restart()
+
+    def restart(self) -> None:
+        self._c0 = time.thread_time()
+        self._t0 = time.monotonic()
+
+    def book(self) -> None:
+        wall = time.monotonic() - self._t0
+        _phase_book(self._row, wall, time.thread_time() - self._c0)
+        p = _publisher
+        if p is not None and p.active:
+            req_id, parent_id = _CTX.get() or ("", 0)
+            p.publish(_record(
+                _PHASE_TYPES[self._layer], f"{self._layer}.{self._name}",
+                req_id, next(_span_ids), parent_id, wall,
+            ))
+
+
+def _trace_annotation(label: str):
+    """A profiler TraceMe, only where jax is already loaded: the phase
+    clock must never be what imports jax into a CPU-plane process. With no
+    trace being recorded a TraceMe costs one atomic load."""
+    jax = sys.modules.get("jax")
+    cls = getattr(getattr(jax, "profiler", None), "TraceAnnotation", None)
+    return cls(label) if cls is not None else None
+
+
+class Phase:
+    """One timed entry of a (layer, phase) row; context-manager only, like
+    ``Span`` (the ``span`` miniovet rule covers both)."""
+
+    __slots__ = ("_row", "_name", "_into", "_span", "_ann", "_t0", "_c0")
+
+    def __init__(self, layer: str, name: str, into: dict | None, fields: dict):
+        self._row = _phase_table[(layer, name)]
+        self._name = name
+        self._into = into
+        p = _publisher
+        self._span = (
+            Span(_PHASE_TYPES[layer], f"{layer}.{name}", fields)
+            if p is not None and p.active else None
+        )
+        # only the dispatch thread's leaves go to the profiler: a reader
+        # that names a device-idle gap by the host event covering most of
+        # it would see nothing but an enclosing request-thread phase
+        self._ann = (
+            _trace_annotation(f"dispatch.{name}") if layer == "dispatch" else None
+        )
+        self._t0 = self._c0 = 0.0
+
+    def __enter__(self) -> "Phase":
+        if self._span is not None:
+            self._span.__enter__()
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._c0 = time.thread_time()
+        self._t0 = time.monotonic()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        wall = time.monotonic() - self._t0
+        cpu = time.thread_time() - self._c0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        if self._span is not None:
+            self._span.__exit__(exc_type, exc, tb)
+        _phase_book(self._row, wall, cpu)
+        if self._into is not None:
+            self._into[self._name] = self._into.get(self._name, 0.0) + wall
+        return False  # propagate exceptions
+
+
+def phase(layer: str, name: str, into: dict | None = None, **fields) -> Phase:
+    """A phase of PHASES[layer] for use in a ``with`` statement. Always
+    books wall seconds, thread CPU seconds and a call; `into`, when given,
+    also gets the wall seconds added under `name` (the dispatcher sums one
+    dispatch's phases that way). An unknown (layer, name) is a KeyError."""
+    return Phase(layer, name, into, fields)

@@ -238,8 +238,10 @@ def _build(d: int, p: int, batch: int, nc: int, key: bytes):
 
     CP = pltpu.CompilerParams(vmem_limit_bytes=110 * 1024 * 1024)
 
+    # stable names for a device trace: the program reads
+    # `jit_fused_rs_hash`, the Pallas kernel in it `fused_rs_hash_kernel`
     @jax.jit
-    def run(x, w3):
+    def fused_rs_hash(x, w3):
         s = _init_state(B * t, key)
         init = jnp.concatenate(
             [jnp.stack(s.v0h), jnp.stack(s.v0l), jnp.stack(s.v1h),
@@ -268,6 +270,7 @@ def _build(d: int, p: int, batch: int, nc: int, key: bytes):
             scratch_shapes=[pltpu.VMEM((32, 8, S8), jnp.uint32),
                             pltpu.VMEM((B, p, CB), jnp.uint8)],
             compiler_params=CP,
+            name="fused_rs_hash_kernel",
         )(w3, x, init)
         # the kernel already finalized: out carries the 8 LE u32 digest
         # words per shard; only byte assembly remains on the XLA side
@@ -275,7 +278,7 @@ def _build(d: int, p: int, batch: int, nc: int, key: bytes):
         dig = jax.lax.bitcast_convert_type(words, jnp.uint8).reshape(B * t, 32)
         return parity, dig.reshape(B, t, 32)
 
-    return run
+    return fused_rs_hash
 
 
 @functools.lru_cache(maxsize=32)

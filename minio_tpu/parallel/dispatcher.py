@@ -57,13 +57,24 @@ LEVEL_NUMPY = 0
 
 # fixed histogram edges (seconds) for the metrics-v3 /api/tpu group: the
 # queue-wait edges bracket the 2 ms batch window, the device edges the
-# sub-ms..100 ms kernel range
-QUEUE_WAIT_BUCKETS = (0.0005, 0.001, 0.002, 0.004, 0.008, 0.016, 0.05, 0.1, 0.5)
-DEVICE_TIME_BUCKETS = (0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.5)
+# sub-ms..100 ms kernel range; both run on to 32 s because on the chip a
+# 256-block dispatch takes 1.6 s and its items wait 0.8 s (PERF.md §5)
+_SLOW_BUCKETS = (1.0, 2.0, 4.0, 8.0, 16.0, 32.0)
+QUEUE_WAIT_BUCKETS = (
+    0.0005, 0.001, 0.002, 0.004, 0.008, 0.016, 0.05, 0.1, 0.5) + _SLOW_BUCKETS
+DEVICE_TIME_BUCKETS = (
+    0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.5) + _SLOW_BUCKETS
 # dispatched bucket sizes (blocks) — power-of-two padded, so the edges
 # ARE the possible sizes; pre-seeded so the /api/tpu occupancy series
 # can split pad waste from real batching from the first scrape
 BUCKET_BLOCK_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024)
+# the ladder's rungs as the first-call series label them
+RUNGS = ("fused", "xla", "numpy")
+# one dispatch's phases (obs.PHASES["dispatch"], `wait`/`window` aside):
+# time against the device — relayout for it, transfers, the jitted call,
+# framing its result — and host work. device_s and host_s are these sums.
+DEVICE_PHASES = ("pack", "h2d", "kernel", "d2h", "unpack", "frame")
+HOST_PHASES = ("assemble", "numpy", "fanout")
 
 
 def _hist_add(hist: list[int], edges: tuple, v: float) -> None:
@@ -164,8 +175,9 @@ class TpuDispatcher:
             "backend_level": LEVEL_FUSED,
             "device_faults": 0, "demotions": 0, "promotions": 0, "probes": 0,
             "numpy_blocks": 0,
-            # kernel-level timing (metrics-v3 /api/tpu): host orchestration
-            # vs device execute split + per-item queue wait
+            # dispatch timing (metrics-v3 /api/tpu): sums of this
+            # dispatcher's phases (DEVICE_PHASES / HOST_PHASES) + per-item
+            # queue wait
             "occupancy_pct_sum": 0.0, "host_s": 0.0, "device_s": 0.0,
             "queue_wait_s": 0.0,
             "queue_wait_hist": [0] * (len(QUEUE_WAIT_BUCKETS) + 1),
@@ -176,7 +188,14 @@ class TpuDispatcher:
             # to the device — the streaming-PUT steady state)
             "pad_blocks": 0, "arena_direct": 0,
             "bucket_hist": [0] * (len(BUCKET_BLOCK_BUCKETS) + 1),
+            # first dispatch of each (rung, bucket, family) in this
+            # process: {(rung, bucket): n} and the `kernel`-phase seconds
+            # it took (trace-and-lower + compile or cache load + the run),
+            # counted when that dispatch ENDS — the bucket histogram moves
+            # when it starts. Keys appear under _cv; observers copy under it
+            "first_calls": {}, "first_call_s": {},
         }
+        self._dispatched: set[tuple[str, int, str]] = set()
         self._thread = threading.Thread(
             target=self._loop, daemon=True,
             name=f"tpu-dispatch-{codec.data_shards}+{codec.parity_shards}",
@@ -191,7 +210,7 @@ class TpuDispatcher:
         pass)."""
         with self._cv:
             return {
-                k: (list(v) if isinstance(v, list) else v)
+                k: (v.copy() if isinstance(v, (list, dict)) else v)
                 for k, v in self.stats.items()
             }
 
@@ -259,7 +278,7 @@ class TpuDispatcher:
     def _collect(self) -> list[tuple]:
         batch: list[tuple] = []
         total = 0
-        with self._cv:
+        with obs.phase("dispatch", "wait"), self._cv:
             while not self._fg and not self._bg:
                 self._cv.wait()
             self._promote_aged_locked(_monotonic())
@@ -275,24 +294,8 @@ class TpuDispatcher:
         # to prevent; bg fills leftover capacity below either way.
         native_fg = sum(1 for it in batch if it[2] == PRI_FOREGROUND)
         if native_fg > 1 and total < self.max_blocks:
-            deadline = _monotonic() + self.window
-            while total < self.max_blocks:
-                timeout = deadline - _monotonic()
-                if timeout <= 0:
-                    break
-                with self._cv:
-                    if not self._fg:
-                        self._cv.wait(timeout)
-                    self._promote_aged_locked(_monotonic())
-                    took = self._drain_locked(
-                        self._fg, batch, self.max_blocks - total
-                    )
-                    total += took
-                    if self._fg and took == 0:
-                        # head item cannot fit the remaining room, which
-                        # never grows: stop burning the window (and the
-                        # CPU — waiting here would spin on every notify)
-                        break
+            with obs.phase("dispatch", "window"):
+                total = self._straggler_window(batch, total)
         with self._cv:
             # late fg arrivals still beat queued bg work — drained first
             # under the same lock that grants bg its leftover slots
@@ -318,6 +321,27 @@ class TpuDispatcher:
                     self.stats["fg_deferred_behind_bg"] += 1
         return batch
 
+    def _straggler_window(self, batch: list, total: int) -> int:
+        deadline = _monotonic() + self.window
+        while total < self.max_blocks:
+            timeout = deadline - _monotonic()
+            if timeout <= 0:
+                break
+            with self._cv:
+                if not self._fg:
+                    self._cv.wait(timeout)
+                self._promote_aged_locked(_monotonic())
+                took = self._drain_locked(
+                    self._fg, batch, self.max_blocks - total
+                )
+                total += took
+                if self._fg and took == 0:
+                    # head item cannot fit the remaining room, which
+                    # never grows: stop burning the window (and the
+                    # CPU — waiting here would spin on every notify)
+                    break
+        return total
+
     @staticmethod
     def _bucket(k: int) -> int:
         """Pad batch sizes to power-of-two buckets: the jitted encode+hash
@@ -328,10 +352,11 @@ class TpuDispatcher:
             b <<= 1
         return b
 
-    def _fused_cm(self, all_blocks: np.ndarray):
+    def _fused_cm(self, all_blocks: np.ndarray, took: dict):
         """Chunk-major mega-kernel dispatch when shapes allow (ops/
         fused_pallas.py): one kernel, data read from HBM once. Returns
-        None to fall back to the row-major XLA path (non-TPU backend,
+        (parity_cm, digests) still on the device, both ready, or None to
+        fall back to the row-major XLA path (non-TPU backend,
         unsupported shape, MINIO_TPU_FUSED_CM=0, or a kernel failure —
         the fallback must be real, not just a shape gate)."""
         if not self._fused_enabled:
@@ -339,6 +364,8 @@ class TpuDispatcher:
         if self._fused_cooldown > 0:
             self._fused_cooldown -= 1
             return None
+        import jax
+
         from ..ops import fused_pallas as fp
 
         b, d, n = all_blocks.shape
@@ -353,16 +380,23 @@ class TpuDispatcher:
                 # injected Pallas-kernel failure: caught below, so the
                 # ladder's first demotion rung (fused -> XLA) engages
                 raise RuntimeError("injected TPU kernel fault")
-            parity_cm, digests = fp.fused_encode_hash_cm(
-                fp.pack_chunk_major(all_blocks), d, p
-            )
+            with obs.phase("dispatch", "pack", into=took):
+                packed = fp.pack_chunk_major(all_blocks)
+            with obs.phase("dispatch", "h2d", into=took):
+                data_cm = jax.block_until_ready(jax.device_put(packed))
+                # freeing a 256 MiB buffer takes milliseconds: it belongs
+                # to the phase that is done with it, not to no phase
+                del packed
+            # call -> ready: launch overhead + execution, and on a
+            # bucket's first call trace-and-lower and the compile
+            with obs.phase("dispatch", "kernel", into=took):
+                out = jax.block_until_ready(
+                    fp.fused_encode_hash_cm(data_cm, d, p)
+                )
             self._fused_backoff = 8  # healthy again: reset the backoff
             with self._cv:
                 self.stats["fused"] += 1
-            return (
-                fp.unpack_chunk_major(np.asarray(parity_cm)),
-                np.asarray(digests),
-            )
+            return out
         # miniovet: ignore[error-taint] -- this IS the degradation ladder:
         # a fused-rung failure falls to the XLA rung (byte-identical
         # results), is counted in fused_failures, and backs off
@@ -475,71 +509,19 @@ class TpuDispatcher:
                 self._dispatch_group(items, family)
 
     def _dispatch_group(self, batch: list[tuple], family: str) -> None:
-        from ..erasure import bufpool
-
         t_start = _monotonic()
         arena_lease = None
+        # this dispatch's phases, name -> wall seconds; device_s and
+        # host_s are sums over it, never a stopwatch of their own
+        took: dict[str, float] = {}
         try:
             codec = batch[0][6]
             max_wait = max(
                 (max(t_start - it[3], 0.0) for it in batch), default=0.0
             )
-            # malformed input is a CALLER error: it must propagate to
-            # the waiters, never count as a device fault or get
-            # "served degraded" by the numpy rung
-            for it in batch:
-                if it[0].shape[1] != self.codec.data_shards:
-                    raise ValueError(
-                        f"blocks have d={it[0].shape[1]}, codec "
-                        f"expects {self.codec.data_shards}"
-                    )
-            d = self.codec.data_shards
-            n = batch[0][0].shape[2]
-            k = sum(it[0].shape[0] for it in batch)
-            bucket = self._bucket(k)
-            fusable = family == "reedsolomon"  # mega-kernel weights are RS
-            if (
-                bucket < 16 and fusable and self._fused_enabled
-                and self._fused_cooldown == 0
-            ):
-                from ..ops import fused_pallas as fp
-
-                # low-concurrency batches pad up to the mega-kernel's
-                # floor rather than losing the fused path (VERDICT r2)
-                if fp.supports(d, self.codec.parity_shards, 16, n):
-                    bucket = 16
-            if len(batch) == 1 and k == bucket:
-                # exact-fit single entry (the streaming-PUT steady state:
-                # ingest arenas are sized to the bucket): the caller's
-                # array — often a view of the pooled ingest arena — goes
-                # straight to the device. No concat, no pad, no arena.
-                all_blocks = batch[0][0]
-                with self._cv:
-                    self.stats["arena_direct"] += 1
-            else:
-                # pre-sized bucket arena replaces per-dispatch
-                # np.concatenate + pad allocation: entries copy in once
-                # (inherent — they arrive scattered), only the pad tail
-                # is zeroed, and the arena recycles after the dispatch
-                if bufpool.zerocopy_enabled():
-                    arena_lease = bufpool.get_pool().acquire(bucket * d * n)
-                    all_blocks = arena_lease.array[: bucket * d * n].reshape(
-                        bucket, d, n
-                    )
-                else:
-                    all_blocks = np.empty((bucket, d, n), dtype=np.uint8)
-                off = 0
-                for it in batch:
-                    kk = it[0].shape[0]
-                    all_blocks[off : off + kk] = it[0]
-                    off += kk
-                bufpool.count_copy("dispatch-concat", len(batch))
-                if bucket != k:
-                    all_blocks[k:] = 0
-                    bufpool.count_copy("dispatch-pad")
-            with self._cv:
-                self.stats["pad_blocks"] += bucket - k
-                _hist_add(self.stats["bucket_hist"], BUCKET_BLOCK_BUCKETS, bucket)
+            with obs.phase("dispatch", "assemble", into=took):
+                all_blocks, k, arena_lease = self._assemble(batch, family)
+            n = all_blocks.shape[2]
             level = self.stats["backend_level"]
             if level == LEVEL_NUMPY:
                 # degraded: traffic serves on CPU; every probe_after
@@ -547,7 +529,9 @@ class TpuDispatcher:
                 # re-promotes on success
                 self._probe_countdown -= 1
                 if self._probe_countdown <= 0:
-                    if self._probe_device():
+                    with obs.phase("dispatch", "numpy", into=took):
+                        alive = self._probe_device()
+                    if alive:
                         level = LEVEL_XLA
                         with self._cv:
                             self.stats["backend_level"] = level
@@ -560,40 +544,18 @@ class TpuDispatcher:
                         self._probe_countdown = self._probe_after
             was_fused = False
             shards = digests = None
-            # device_s covers ONLY time spent against the device
-            # (successful or faulted attempts) — the numpy rung and
-            # the probe are host work and land in host_s, so the
-            # host-vs-device split stays honest in degraded mode
-            device_s = 0.0
+            bucket = int(all_blocks.shape[0])
+            # the DEVICE_PHASES cover ONLY time spent against the device
+            # (successful or faulted attempts) — the numpy rung and the
+            # probe are host work (phase `numpy`), so the host-vs-device
+            # split stays honest in degraded mode
             if level != LEVEL_NUMPY:
-                t_dev = _monotonic()
                 try:
-                    self._tpu_fault_hook()
-                    fused = self._fused_cm(all_blocks) if fusable else None
-                    was_fused = fused is not None
-                    if fused is None:
-                        # don't pay mega-kernel padding (16) on the XLA
-                        # path: trim back to the power-of-two bucket
-                        nb = self._bucket(k)
-                        if nb < all_blocks.shape[0]:
-                            all_blocks = all_blocks[:nb]
-                        if family == "cauchy":
-                            from ..ops.cauchy import encode_and_hash_cauchy
-
-                            fused = encode_and_hash_cauchy(codec, all_blocks)
-                        else:
-                            fused = self._encode_and_hash(codec, all_blocks)
-                    parity, digests = fused
-                    # np.asarray is the device sync point: execute + D2H
-                    # land inside the device window, fan-out is host time
-                    parity = np.asarray(parity)[:k]
-                    # a TPU array can arrive on the host in the device's
-                    # own (non row-major) layout: waiters frame digest
-                    # ROWS as writev buffers, which must be C-contiguous
-                    digests = np.ascontiguousarray(np.asarray(digests)[:k])
-                    shards = np.concatenate(
-                        [all_blocks[:k], parity], axis=1
-                    )  # [B, t, n]
+                    shards, digests, was_fused, bucket = (
+                        self._encode_on_device(
+                            all_blocks, k, codec, family, took
+                        )
+                    )
                     self._device_fault_streak = 0
                     # gauge semantics: XLA is a DEGRADATION signal only
                     # when the fused rung is faulted out (cooldown); a
@@ -618,42 +580,54 @@ class TpuDispatcher:
                     self._device_fault(e)
                     was_fused = False
                     shards = None
-                device_s = _monotonic() - t_dev
+            rung = "fused" if was_fused else "xla"
             if shards is None:
-                shards, digests = self._encode_numpy(all_blocks[:k], family)
+                rung = "numpy"
+                with obs.phase("dispatch", "numpy", into=took):
+                    shards, digests = self._encode_numpy(all_blocks[:k], family)
                 with self._cv:
                     self.stats["numpy_blocks"] += k
-            from ..erasure.coder import family_stats_add
+            device_s = sum(took.get(name, 0.0) for name in DEVICE_PHASES)
+            occupancy = 100.0 * k / max(bucket, 1)
+            with obs.phase("dispatch", "fanout", into=took):
+                from ..erasure.coder import family_stats_add
 
-            family_stats_add(family, "encode_blocks", k)
-            occupancy = 100.0 * k / max(all_blocks.shape[0], 1)
-            with self._cv:
-                self.stats["dispatches"] += 1
-                self.stats["blocks"] += k
-                self.stats["max_batch"] = max(self.stats["max_batch"], k)
-                self.stats["occupancy_pct_sum"] += occupancy
-                self.stats["device_s"] += device_s
-                _hist_add(
-                    self.stats["device_time_hist"], DEVICE_TIME_BUCKETS,
-                    device_s,
-                )
+                family_stats_add(family, "encode_blocks", k)
+                with self._cv:
+                    self.stats["dispatches"] += 1
+                    self.stats["blocks"] += k
+                    self.stats["max_batch"] = max(self.stats["max_batch"], k)
+                    self.stats["occupancy_pct_sum"] += occupancy
+                    self.stats["device_s"] += device_s
+                    _hist_add(
+                        self.stats["device_time_hist"], DEVICE_TIME_BUCKETS,
+                        device_s,
+                    )
+                    if (rung, bucket, family) not in self._dispatched:
+                        self._dispatched.add((rung, bucket, family))
+                        key = (rung, bucket)
+                        calls, secs = (
+                            self.stats["first_calls"], self.stats["first_call_s"]
+                        )
+                        calls[key] = calls.get(key, 0) + 1
+                        secs[key] = secs.get(key, 0.0) + took.get("kernel", 0.0)
+                    for it in batch:
+                        kk = it[0].shape[0]
+                        if it[2] == PRI_BACKGROUND:
+                            self.stats["bg_blocks"] += kk
+                            if it[5]:
+                                self.stats["prefetch_blocks"] += kk
+                        else:
+                            self.stats["fg_blocks"] += kk
+                off = 0
                 for it in batch:
-                    kk = it[0].shape[0]
-                    if it[2] == PRI_BACKGROUND:
-                        self.stats["bg_blocks"] += kk
-                        if it[5]:
-                            self.stats["prefetch_blocks"] += kk
-                    else:
-                        self.stats["fg_blocks"] += kk
-            off = 0
-            for it in batch:
-                blocks, fut = it[0], it[1]
-                kk = blocks.shape[0]
-                fut.set_result(
-                    (shards[off : off + kk], digests[off : off + kk])
-                )
-                off += kk
-            host_s = _monotonic() - t_start - device_s
+                    blocks, fut = it[0], it[1]
+                    kk = blocks.shape[0]
+                    fut.set_result(
+                        (shards[off : off + kk], digests[off : off + kk])
+                    )
+                    off += kk
+            host_s = sum(took.get(name, 0.0) for name in HOST_PHASES)
             with self._cv:
                 self.stats["host_s"] += host_s
             if obs.active():
@@ -668,9 +642,12 @@ class TpuDispatcher:
                     "durationNs": int((host_s + device_s) * 1e9),
                     "deviceNs": int(device_s * 1e9),
                     "hostNs": int(host_s * 1e9),
+                    "phaseNs": {
+                        name: int(sec * 1e9) for name, sec in took.items()
+                    },
                     "queueWaitMaxNs": int(max_wait * 1e9),
                     "blocks": k,
-                    "bucket": int(all_blocks.shape[0]),
+                    "bucket": bucket,
                     "occupancyPct": round(occupancy, 1),
                     "fused": was_fused,
                     "family": family,
@@ -688,6 +665,127 @@ class TpuDispatcher:
             # views — so the bucket arena recycles here unconditionally
             if arena_lease is not None:
                 arena_lease.release()
+
+    def _assemble(self, batch: list[tuple], family: str):
+        """The batch's entries as one [bucket, d, n] array, padded to the
+        power-of-two bucket -> (all_blocks, real blocks k, arena lease or
+        None). The caller releases the lease after the dispatch."""
+        from ..erasure import bufpool
+
+        # malformed input is a CALLER error: it must propagate to
+        # the waiters, never count as a device fault or get
+        # "served degraded" by the numpy rung
+        for it in batch:
+            if it[0].shape[1] != self.codec.data_shards:
+                raise ValueError(
+                    f"blocks have d={it[0].shape[1]}, codec "
+                    f"expects {self.codec.data_shards}"
+                )
+        d = self.codec.data_shards
+        n = batch[0][0].shape[2]
+        k = sum(it[0].shape[0] for it in batch)
+        bucket = self._bucket(k)
+        arena_lease = None
+        if (
+            bucket < 16 and family == "reedsolomon" and self._fused_enabled
+            and self._fused_cooldown == 0
+        ):
+            from ..ops import fused_pallas as fp
+
+            # low-concurrency batches pad up to the mega-kernel's
+            # floor rather than losing the fused path (VERDICT r2)
+            if fp.supports(d, self.codec.parity_shards, 16, n):
+                bucket = 16
+        if len(batch) == 1 and k == bucket:
+            # exact-fit single entry (the streaming-PUT steady state:
+            # ingest arenas are sized to the bucket): the caller's
+            # array — often a view of the pooled ingest arena — goes
+            # straight to the device. No concat, no pad, no arena.
+            all_blocks = batch[0][0]
+            with self._cv:
+                self.stats["arena_direct"] += 1
+        else:
+            # pre-sized bucket arena replaces per-dispatch
+            # np.concatenate + pad allocation: entries copy in once
+            # (inherent — they arrive scattered), only the pad tail
+            # is zeroed, and the arena recycles after the dispatch
+            if bufpool.zerocopy_enabled():
+                arena_lease = bufpool.get_pool().acquire(bucket * d * n)
+                all_blocks = arena_lease.array[: bucket * d * n].reshape(
+                    bucket, d, n
+                )
+            else:
+                all_blocks = np.empty((bucket, d, n), dtype=np.uint8)
+            try:
+                off = 0
+                for it in batch:
+                    kk = it[0].shape[0]
+                    all_blocks[off : off + kk] = it[0]
+                    off += kk
+            except BaseException:
+                # a ragged entry: the lease never reaches the caller
+                if arena_lease is not None:
+                    arena_lease.release()
+                raise
+            bufpool.count_copy("dispatch-concat", len(batch))
+            if bucket != k:
+                all_blocks[k:] = 0
+                bufpool.count_copy("dispatch-pad")
+        with self._cv:
+            self.stats["pad_blocks"] += bucket - k
+            _hist_add(self.stats["bucket_hist"], BUCKET_BLOCK_BUCKETS, bucket)
+        return all_blocks, k, arena_lease
+
+    def _encode_on_device(self, all_blocks, k: int, codec, family: str,
+                          took: dict):
+        """One attempt on the device rungs (fused, else XLA), each step a
+        leaf phase -> (shards [k, t, n], digests, was_fused, rows
+        dispatched). Every phase ends synced (`block_until_ready`, `np.asarray`), so the phases'
+        seconds are what each step took and not what it enqueued."""
+        import jax
+        import jax.numpy as jnp
+
+        # injected faults only: a slow-batch stall is no phase's time
+        self._tpu_fault_hook()
+        fused = (
+            self._fused_cm(all_blocks, took) if family == "reedsolomon" else None
+        )
+        was_fused = fused is not None
+        if fused is None:
+            # don't pay mega-kernel padding (16) on the XLA
+            # path: trim back to the power-of-two bucket
+            nb = self._bucket(k)
+            if nb < all_blocks.shape[0]:
+                all_blocks = all_blocks[:nb]
+            with obs.phase("dispatch", "h2d", into=took):
+                data = jax.block_until_ready(jnp.asarray(all_blocks))
+            with obs.phase("dispatch", "kernel", into=took):
+                if family == "cauchy":
+                    from ..ops.cauchy import encode_and_hash_cauchy
+
+                    fused = encode_and_hash_cauchy(codec, data)
+                else:
+                    fused = self._encode_and_hash(codec, data)
+                fused = jax.block_until_ready(fused)
+        parity, digests = fused
+        with obs.phase("dispatch", "d2h", into=took):
+            parity = np.asarray(parity)
+            digests = np.asarray(digests)
+        with obs.phase("dispatch", "unpack", into=took):
+            if was_fused:
+                from ..ops import fused_pallas as fp
+
+                parity = fp.unpack_chunk_major(parity)
+            # a TPU array can arrive on the host in the device's
+            # own (non row-major) layout: waiters frame digest
+            # ROWS as writev buffers, which must be C-contiguous
+            digests = np.ascontiguousarray(digests[:k])
+        with obs.phase("dispatch", "frame", into=took):
+            shards = np.concatenate(
+                [all_blocks[:k], parity[:k]], axis=1
+            )  # [B, t, n]
+            del parity, fused
+        return shards, digests, was_fused, int(all_blocks.shape[0])
 
 
 def _monotonic() -> float:
@@ -726,6 +824,10 @@ def aggregate_stats() -> dict:
                 cur = out.setdefault(k, [0] * len(v))
                 for i, x in enumerate(v):
                     cur[i] += x
+            elif isinstance(v, dict):
+                cur = out.setdefault(k, {})
+                for key, x in v.items():
+                    cur[key] = cur.get(key, 0) + x
             else:
                 out[k] = out.get(k, 0) + v
     return out

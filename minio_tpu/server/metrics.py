@@ -948,10 +948,13 @@ def _hist_rows(edges, hist, label_key="le"):
 
 def _g_api_tpu(server) -> list[str]:
     """TPU dispatcher plane: batch occupancy, queue-wait and device-time
-    histograms, host-vs-device time split, and the QoS lane counters —
-    the series that let the BENCH trajectory separate dispatcher
-    efficiency (host orchestration, batching) from raw kernel throughput
-    (device execute time)."""
+    histograms, the phase clock (obs.phase: where the dispatch thread and
+    a streaming PUT spend wall and CPU seconds, by named phase), first
+    calls per (rung, bucket), and the QoS lane counters. `device_seconds`
+    is the dispatch thread's time against the device — host relayout,
+    transfers, the jitted call, framing — not kernel time; the `kernel`
+    phase is the jitted call alone."""
+    from .. import obs
     from ..parallel import dispatcher as dmod
 
     out: list[str] = []
@@ -968,10 +971,13 @@ def _g_api_tpu(server) -> list[str]:
     _fmt(out, "minio_tpu_batch_max_blocks", "gauge", [({}, ds.get("max_batch", 0))])
     _fmt(out, "minio_tpu_host_seconds_total", "counter",
          [({}, f"{ds.get('host_s', 0.0):.6f}")],
-         "Host-side batch assembly + fan-out time")
+         "Dispatch-thread seconds in the phases assemble + numpy + fanout "
+         "(batch assembly, the numpy rung and the probe, fan-out)")
     _fmt(out, "minio_tpu_device_seconds_total", "counter",
          [({}, f"{ds.get('device_s', 0.0):.6f}")],
-         "Device execute time (incl. transfers) per dispatch")
+         "Dispatch-thread seconds in the phases pack + h2d + kernel + d2h "
+         "+ unpack + frame (host relayout, both transfers, the jitted call "
+         "incl. any trace-and-lower, result framing): not kernel time")
     _fmt(out, "minio_tpu_queue_wait_seconds_total", "counter",
          [({}, f"{ds.get('queue_wait_s', 0.0):.6f}")])
     _fmt(out, "minio_tpu_queue_wait_seconds_distribution", "counter",
@@ -979,7 +985,40 @@ def _g_api_tpu(server) -> list[str]:
          "Per-item wait from submit to dispatch start")
     _fmt(out, "minio_tpu_device_time_seconds_distribution", "counter",
          _hist_rows(dmod.DEVICE_TIME_BUCKETS, ds.get("device_time_hist", [])),
-         "Per-dispatch device execute time")
+         "Per-dispatch seconds of minio_tpu_device_seconds_total")
+    # the phase clock (obs/trace.py): every (layer, phase) row is there
+    # from the first scrape, at zero until the phase runs
+    phases = sorted(obs.phases_snapshot().items())
+    _fmt(out, "minio_tpu_phase_seconds_total", "counter",
+         [({"layer": layer, "phase": name}, f"{row[0]:.6f}")
+          for (layer, name), row in phases],
+         "Wall seconds inside each named phase: layer `dispatch` tiles the "
+         "dispatch thread's time, layer `put` is a streaming PUT's request "
+         "thread (drive_io: the drive pool's threads)")
+    _fmt(out, "minio_tpu_phase_cpu_seconds_total", "counter",
+         [({"layer": layer, "phase": name}, f"{row[1]:.6f}")
+          for (layer, name), row in phases],
+         "Thread CPU seconds (time.thread_time) inside each named phase")
+    _fmt(out, "minio_tpu_phase_calls_total", "counter",
+         [({"layer": layer, "phase": name}, row[2])
+          for (layer, name), row in phases],
+         "Entries of each named phase")
+    first = {(r, b): (0, 0.0) for r in dmod.RUNGS
+             for b in dmod.BUCKET_BLOCK_BUCKETS}
+    first.update({
+        key: (n, ds["first_call_s"].get(key, 0.0))
+        for key, n in ds.get("first_calls", {}).items()
+    })
+    _fmt(out, "minio_tpu_dispatch_first_calls_total", "counter",
+         [({"rung": r, "bucket": str(b)}, first[r, b][0])
+          for r, b in sorted(first)],
+         "First dispatches of a (rung, bucket, family) in this process, "
+         "counted when the dispatch ends")
+    _fmt(out, "minio_tpu_dispatch_first_call_seconds_total", "counter",
+         [({"rung": r, "bucket": str(b)}, f"{first[r, b][1]:.6f}")
+          for r, b in sorted(first)],
+         "Seconds those first dispatches spent in the `kernel` phase: "
+         "trace-and-lower, compile or cache load, and the run")
     _fmt(out, "minio_tpu_fused_dispatches_total", "counter",
          [({}, ds.get("fused", 0))])
     _fmt(out, "minio_tpu_fused_failures_total", "counter",

@@ -4,6 +4,8 @@ exactly one line, and unused pragmas surface under strict mode)."""
 
 import textwrap
 
+import pytest
+
 from minio_tpu.analysis import analyze_source
 
 
@@ -601,6 +603,58 @@ def test_span_rule_exempts_obs_package():
             return Span(t, n, fields)
     """
     assert run(src, relpath="obs/trace.py", rules=["span"]) == []
+
+
+@pytest.mark.parametrize("src,findings", [
+    # the phase clock opens the same span and a profiler annotation besides
+    ("""
+        from minio_tpu import obs
+
+        def f():
+            ph = obs.phase("dispatch", "d2h")
+            ph.__enter__()
+     """, 1),
+    ("""
+        from minio_tpu import obs
+
+        def f(took):
+            with obs.phase("dispatch", "d2h", into=took):
+                pass
+            with obs.phase("put", "md5"), obs.span(obs.TYPE_STORAGE, "x"):
+                pass
+     """, 0),
+    ("""
+        from minio_tpu.obs import phase
+
+        def f():
+            phase("put", "md5")
+     """, 1),
+    ("""
+        from minio_tpu import obs
+
+        def f():
+            return obs.Phase("put", "md5", None, {})
+     """, 1),
+    # a stopwatch is no context manager and is not held to the rule
+    ("""
+        from minio_tpu import obs
+
+        def f():
+            clock = obs.PhaseClock("put", "ingest")
+            clock.book()
+     """, 0),
+    # an unrelated local `phase`
+    ("""
+        def phase(a):
+            return a
+
+        def f():
+            return phase(1)
+     """, 0),
+], ids=["orphan", "with", "imported-name", "direct-construction", "stopwatch", "local-name"])
+def test_phase_is_context_manager_only_too(src, findings):
+    fs = run(src, rules=["span"])
+    assert len(fs) == findings and all(f.rule == "span" for f in fs)
 
 
 # -- retry-discipline ------------------------------------------------------
