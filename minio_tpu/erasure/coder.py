@@ -151,13 +151,17 @@ class EncodedBatch:
     """One streaming-encode batch on the zero-copy plane.
 
     ``shard_vecs[i]`` is the writev-style buffer sequence for erasure
-    index i — alternating digest-row / shard-row views into the encode
-    output, framed exactly like the legacy bytearray chunks. ``raw`` is
-    the input slice this batch encoded (md5/size folding); on the pooled
-    path it is a memoryview into the ingest arena, so the caller MUST
-    finish both the md5 fold and every ``append_file(shard_vecs[i])``
-    before calling :meth:`release` — the release returns the arena to
-    the pool (docs/ERASURE.md buffer-ownership contract)."""
+    index i — alternating digest-row / shard-row views, framed exactly
+    like the legacy bytearray chunks. Digest rows and the shard rows of
+    parity indices (i >= d) are views of the dispatch's result arrays;
+    the shard rows of data indices (i < d) are views of the INGEST ARENA
+    itself — the dispatcher hands no data byte back. ``raw`` is the input
+    slice this batch encoded (md5/size folding), a memoryview into the
+    same arena on the pooled path. So the caller MUST finish both the md5
+    fold and every ``append_file(shard_vecs[i])`` before calling
+    :meth:`release` — the release returns the arena to the pool, and a
+    data row read after it reads another request's bytes
+    (docs/ERASURE.md buffer-ownership contract)."""
 
     __slots__ = ("shard_vecs", "raw", "_lease")
 
@@ -246,14 +250,15 @@ class ErasureCoder:
         return shards, digests
 
     def _encode_full_blocks(self, blocks: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
-        """blocks: [B, d, shard_size] -> (shards [B, t, n], digests).
+        """blocks: [B, d, shard_size] -> (parity [B, p, n], digests).
 
-        digests: [B, t, 32] for reedsolomon, [B, t, 2, 32] (per
-        sub-chunk) for cauchy. The device path goes through the batching
-        dispatcher: blocks from concurrent requests of BOTH families
-        coalesce into one stream (family tag per batch entry). The
-        cauchy composite matmul needs an even shard size; odd geometries
-        take the numpy path below."""
+        Parity rows only: data row i of block b is the caller's own
+        ``blocks[b, i]``. digests cover all t rows: [B, t, 32] for
+        reedsolomon, [B, t, 2, 32] (per sub-chunk) for cauchy. The device
+        path goes through the batching dispatcher: blocks from concurrent
+        requests of BOTH families coalesce into one stream (family tag
+        per batch entry). The cauchy composite matmul needs an even shard
+        size; odd geometries take the numpy path below."""
         if self._jax is not None and (
             self.family != FAMILY_CAUCHY or blocks.shape[2] % 2 == 0
         ):
@@ -265,7 +270,8 @@ class ErasureCoder:
                     blocks, codec=self._jax
                 )
         family_stats_add(self.family, "encode_blocks", blocks.shape[0])
-        return encode_blocks_numpy(self._np, blocks, self.family)
+        shards, digests = encode_blocks_numpy(self._np, blocks, self.family)
+        return shards[:, self.d :], digests
 
     def _encode_full_buffer(self, data: memoryview) -> list[bytearray]:
         """len(data) is a multiple of block_size -> per-shard file chunks
@@ -290,18 +296,19 @@ class ErasureCoder:
         h1 = per // 2
         for start in range(0, full, max_blocks):
             chunk = arr[start : start + max_blocks]
-            shards, digests = self._encode_full_blocks(chunk)
+            parity, digests = self._encode_full_blocks(chunk)
             with obs.phase("put", "frame"):
                 for b in range(chunk.shape[0]):
                     for i in range(self.t):
+                        row = chunk[b, i] if i < self.d else parity[b, i - self.d]
                         if cauchy:
                             files[i] += digests[b, i, 0].tobytes()
-                            files[i] += shards[b, i, :h1].tobytes()
+                            files[i] += row[:h1].tobytes()
                             files[i] += digests[b, i, 1].tobytes()
-                            files[i] += shards[b, i, h1:].tobytes()
+                            files[i] += row[h1:].tobytes()
                         else:
                             files[i] += digests[b, i].tobytes()
-                            files[i] += shards[b, i].tobytes()
+                            files[i] += row.tobytes()
         bufpool.count_copy("frame-tobytes", full * self.t)
         return files
 
@@ -370,25 +377,30 @@ class ErasureCoder:
         return self._encode_full_buffer(memoryview(piece)), piece
 
     def _frame_into(
-        self, vecs: list[list], shards: np.ndarray, digests: np.ndarray
+        self, vecs: list[list], blocks: np.ndarray, parity: np.ndarray,
+        digests: np.ndarray,
     ) -> None:
         """Append digest/shard ROW VIEWS to the per-shard writev vectors
         — same on-disk frame interleave as _encode_full_buffer, zero
-        materialization. The views pin the encode-output arrays alive
-        until the disk layer consumes them."""
+        materialization. Data rows are views of `blocks`, the [B, d, n]
+        array that was submitted; parity and digest rows are views of
+        the dispatch's result. The views pin all three alive until the
+        disk layer consumes them (a pooled arena behind `blocks` is the
+        caller's to keep leased that long)."""
         cauchy = self.family == FAMILY_CAUCHY
-        h1 = shards.shape[2] // 2
-        for b in range(shards.shape[0]):
+        h1 = blocks.shape[2] // 2
+        for b in range(blocks.shape[0]):
             for i in range(self.t):
                 v = vecs[i]
+                row = blocks[b, i] if i < self.d else parity[b, i - self.d]
                 if cauchy:
                     v.append(digests[b, i, 0].data)
-                    v.append(shards[b, i, :h1].data)
+                    v.append(row[:h1].data)
                     v.append(digests[b, i, 1].data)
-                    v.append(shards[b, i, h1:].data)
+                    v.append(row[h1:].data)
                 else:
                     v.append(digests[b, i].data)
-                    v.append(shards[b, i].data)
+                    v.append(row.data)
 
     def _emit_zc(self, lease, nbytes: int) -> EncodedBatch:
         """Encode the first nbytes (whole stripe blocks) of a pooled
@@ -400,9 +412,10 @@ class ErasureCoder:
         vecs: list[list] = [[] for _ in range(self.t)]
         max_blocks = max(1, MAX_DEVICE_SHARDS // self.t)
         for start in range(0, full, max_blocks):
-            shards, digests = self._encode_full_blocks(arr[start : start + max_blocks])
+            chunk = arr[start : start + max_blocks]
+            parity, digests = self._encode_full_blocks(chunk)
             with obs.phase("put", "frame"):
-                self._frame_into(vecs, shards, digests)
+                self._frame_into(vecs, chunk, parity, digests)
         return EncodedBatch(vecs, lease.view(nbytes), lease)
 
     def iter_encode_zc(

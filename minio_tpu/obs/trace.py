@@ -225,7 +225,9 @@ def span(trace_type: str, name: str, **fields):
 # sees the table change size. `dispatch` phases are leaves that tile the
 # dispatch thread's time (parallel/dispatcher.py); `put` phases run on the
 # request thread of a streaming PUT (erasure/set.py, erasure/coder.py),
-# except `drive_io`, which the drive pool's threads book.
+# except `drive_io`, which the drive pool's threads book. Nothing books
+# `dispatch`/`frame` any more (it was the data + parity concatenate): the
+# row stays at zero so that readers of the table keep finding it.
 PHASES = {
     "dispatch": ("wait", "window", "assemble", "pack", "h2d", "kernel",
                  "d2h", "unpack", "frame", "numpy", "fanout"),

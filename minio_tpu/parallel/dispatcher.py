@@ -9,6 +9,12 @@ back to the waiting request threads. The reference's analogue is the
 per-request AVX loop (cmd/erasure-encode.go:76) — batching is what the
 accelerator changes about the architecture.
 
+Result contract: a waiter gets (parity [k, p, n], digests) — what the
+dispatch computed, as fresh arrays with C-contiguous rows — and frames
+data rows from the [k, d, n] array it submitted. The dispatcher copies no
+data byte back: the bucket arena is its own scratch and recycles after
+every dispatch, whatever the waiters still hold.
+
 Latency contract: a block waits at most `window` (default 2 ms) before
 dispatch; an idle queue dispatches immediately. p99 PUT latency gains the
 window; throughput gains the full batch width of the MXU/VPU.
@@ -71,8 +77,10 @@ BUCKET_BLOCK_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024)
 # the ladder's rungs as the first-call series label them
 RUNGS = ("fused", "xla", "numpy")
 # one dispatch's phases (obs.PHASES["dispatch"], `wait`/`window` aside):
-# time against the device — relayout for it, transfers, the jitted call,
-# framing its result — and host work. device_s and host_s are these sums.
+# time against the device — relayout for it, transfers, the jitted call —
+# and host work. device_s and host_s are these sums. `frame` was the
+# data+parity concatenate; nothing runs under it since results are parity
+# only, and its row stays so that the exported table keeps its shape.
 DEVICE_PHASES = ("pack", "h2d", "kernel", "d2h", "unpack", "frame")
 HOST_PHASES = ("assemble", "numpy", "fanout")
 
@@ -217,7 +225,16 @@ class TpuDispatcher:
     def submit(
         self, blocks: np.ndarray, priority: int | None = None, codec=None
     ) -> Future:
-        """blocks: [k, d, n] -> Future of (shards [k, t, n], digests).
+        """blocks: [k, d, n] -> Future of (parity [k, p, n], digests).
+
+        The result holds what the dispatch computed and nothing the
+        caller already has: data row i of block b is the caller's own
+        ``blocks[b, i]``, parity row j is ``parity[b, j]``, and the
+        digests cover all t = d + p rows in erasure-index order. Both
+        result arrays are fresh arrays of this dispatch (never views of
+        the bucket arena) with C-contiguous rows, so a waiter may hand
+        rows to the drives as writev buffers. The caller keeps `blocks`
+        alive and unchanged until it has framed its data rows.
 
         priority: PRI_FOREGROUND / PRI_BACKGROUND; None resolves from the
         qos context (background planes run under ``background_context()``).
@@ -468,7 +485,7 @@ class TpuDispatcher:
     def _encode_numpy(self, blocks: np.ndarray, family: str = "reedsolomon"):
         """Pure-CPU rung: numpy GF parity + numpy HighwayHash digests,
         byte-identical to the device rungs (golden tests pin all three).
-        [k, d, n] -> (shards [k, t, n], family-shaped digests)."""
+        [k, d, n] -> (parity [k, p, n], family-shaped digests)."""
         ref = self._np_codec.get(family)
         if ref is None:
             if family == "cauchy":
@@ -480,7 +497,8 @@ class TpuDispatcher:
             )
         from ..erasure.coder import encode_blocks_numpy
 
-        return encode_blocks_numpy(ref, blocks, family)
+        shards, digests = encode_blocks_numpy(ref, blocks, family)
+        return shards[:, self.codec.data_shards:], digests
 
     def _loop(self) -> None:
         while True:
@@ -543,7 +561,7 @@ class TpuDispatcher:
                     else:
                         self._probe_countdown = self._probe_after
             was_fused = False
-            shards = digests = None
+            parity = digests = None
             bucket = int(all_blocks.shape[0])
             # the DEVICE_PHASES cover ONLY time spent against the device
             # (successful or faulted attempts) — the numpy rung and the
@@ -551,7 +569,7 @@ class TpuDispatcher:
             # split stays honest in degraded mode
             if level != LEVEL_NUMPY:
                 try:
-                    shards, digests, was_fused, bucket = (
+                    parity, digests, was_fused, bucket = (
                         self._encode_on_device(
                             all_blocks, k, codec, family, took
                         )
@@ -579,12 +597,12 @@ class TpuDispatcher:
                     )
                     self._device_fault(e)
                     was_fused = False
-                    shards = None
+                    parity = None
             rung = "fused" if was_fused else "xla"
-            if shards is None:
+            if parity is None:
                 rung = "numpy"
                 with obs.phase("dispatch", "numpy", into=took):
-                    shards, digests = self._encode_numpy(all_blocks[:k], family)
+                    parity, digests = self._encode_numpy(all_blocks[:k], family)
                 with self._cv:
                     self.stats["numpy_blocks"] += k
             device_s = sum(took.get(name, 0.0) for name in DEVICE_PHASES)
@@ -624,7 +642,7 @@ class TpuDispatcher:
                     blocks, fut = it[0], it[1]
                     kk = blocks.shape[0]
                     fut.set_result(
-                        (shards[off : off + kk], digests[off : off + kk])
+                        (parity[off : off + kk], digests[off : off + kk])
                     )
                     off += kk
             host_s = sum(took.get(name, 0.0) for name in HOST_PHASES)
@@ -660,9 +678,10 @@ class TpuDispatcher:
                 if not it[1].done():
                     it[1].set_exception(e)
         finally:
-            # results handed to waiters are always fresh arrays (the
-            # shards concatenate / numpy-rung output), never arena
-            # views — so the bucket arena recycles here unconditionally
+            # waiters get parity and digests only: fresh arrays of this
+            # dispatch (the D2H result / numpy-rung output), never arena
+            # views. Data rows they frame from the array they submitted,
+            # not from this arena — so it recycles here unconditionally
             if arena_lease is not None:
                 arena_lease.release()
 
@@ -739,9 +758,10 @@ class TpuDispatcher:
     def _encode_on_device(self, all_blocks, k: int, codec, family: str,
                           took: dict):
         """One attempt on the device rungs (fused, else XLA), each step a
-        leaf phase -> (shards [k, t, n], digests, was_fused, rows
-        dispatched). Every phase ends synced (`block_until_ready`, `np.asarray`), so the phases'
-        seconds are what each step took and not what it enqueued."""
+        leaf phase -> (parity [k, p, n], digests, was_fused, rows
+        dispatched). Every phase ends synced (`block_until_ready`,
+        `np.asarray`), so the phases' seconds are what each step took and
+        not what it enqueued."""
         import jax
         import jax.numpy as jnp
 
@@ -777,15 +797,16 @@ class TpuDispatcher:
 
                 parity = fp.unpack_chunk_major(parity)
             # a TPU array can arrive on the host in the device's
-            # own (non row-major) layout: waiters frame digest
-            # ROWS as writev buffers, which must be C-contiguous
+            # own (non row-major) layout: waiters frame parity and
+            # digest ROWS as writev buffers, which must be
+            # C-contiguous. Parity is copied only where its rows are
+            # strided (the unpack above already leaves them row-major)
+            parity = parity[:k]
+            if parity.strides[-1] != parity.itemsize:
+                parity = np.ascontiguousarray(parity)
             digests = np.ascontiguousarray(digests[:k])
-        with obs.phase("dispatch", "frame", into=took):
-            shards = np.concatenate(
-                [all_blocks[:k], parity[:k]], axis=1
-            )  # [B, t, n]
-            del parity, fused
-        return shards, digests, was_fused, int(all_blocks.shape[0])
+            del fused
+        return parity, digests, was_fused, int(all_blocks.shape[0])
 
 
 def _monotonic() -> float:

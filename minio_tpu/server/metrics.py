@@ -976,8 +976,9 @@ def _g_api_tpu(server) -> list[str]:
     _fmt(out, "minio_tpu_device_seconds_total", "counter",
          [({}, f"{ds.get('device_s', 0.0):.6f}")],
          "Dispatch-thread seconds in the phases pack + h2d + kernel + d2h "
-         "+ unpack + frame (host relayout, both transfers, the jitted call "
-         "incl. any trace-and-lower, result framing): not kernel time")
+         "+ unpack (host relayout, both transfers, the jitted call incl. "
+         "any trace-and-lower; frame is 0, results are parity only): not "
+         "kernel time")
     _fmt(out, "minio_tpu_queue_wait_seconds_total", "counter",
          [({}, f"{ds.get('queue_wait_s', 0.0):.6f}")])
     _fmt(out, "minio_tpu_queue_wait_seconds_distribution", "counter",

@@ -515,8 +515,8 @@ def test_obs_records_carry_family(monkeypatch):
         blocks = np.random.default_rng(3).integers(
             0, 256, size=(2, 4, 128), dtype=np.uint8
         )
-        shards, digests = disp.encode(blocks, codec=codec)
-        assert shards.shape == (2, 6, 128)
+        parity, digests = disp.encode(blocks, codec=codec)
+        assert parity.shape == (2, 2, 128)
         assert digests.shape == (2, 6, 2, 32)
         import time as _time
 

@@ -340,14 +340,15 @@ def test_backend_degradation_round_trip(monkeypatch):
     blocks = np.random.default_rng(7).integers(
         0, 256, size=(2, 4, 1024), dtype=np.uint8
     )
-    base_shards, base_digests = disp.encode(blocks)
+    base_parity, base_digests = disp.encode(blocks)
+    assert base_parity.shape == (2, 2, 1024)
     assert disp.stats["backend_level"] > LEVEL_NUMPY
 
     fault.inject({"boundary": "tpu", "mode": "device-lost", "seed": 5})
     for i in range(3):
-        shards, digests = disp.encode(blocks)
+        parity, digests = disp.encode(blocks)
         # degraded results stay byte-identical to the device path
-        np.testing.assert_array_equal(shards, base_shards)
+        np.testing.assert_array_equal(parity, base_parity)
         np.testing.assert_array_equal(digests, base_digests)
     assert disp.stats["backend_level"] == LEVEL_NUMPY
     assert disp.stats["demotions"] == 1
@@ -358,8 +359,8 @@ def test_backend_degradation_round_trip(monkeypatch):
     fault.clear()
     promoted = False
     for _ in range(6):
-        shards, digests = disp.encode(blocks)
-        np.testing.assert_array_equal(shards, base_shards)
+        parity, digests = disp.encode(blocks)
+        np.testing.assert_array_equal(parity, base_parity)
         np.testing.assert_array_equal(digests, base_digests)
         if disp.stats["backend_level"] > LEVEL_NUMPY:
             promoted = True

@@ -148,10 +148,10 @@ def test_first_fused_rung_failure_is_written_once(
     ref = rs.get_codec(d, p)
     for _ in range(2):
         disp._fused_cooldown = 0  # re-attempt the rung: it fails again
-        shards, _digests = disp.encode(blocks)
+        parity, _digests = disp.encode(blocks)
         for b in range(2):
             np.testing.assert_array_equal(
-                shards[b, d:], ref.encode(ref.split(blocks[b].tobytes()))[d:]
+                parity[b], ref.encode(ref.split(blocks[b].tobytes()))[d:]
             )
     assert disp.stats["fused_failures"] == 2
     assert disp.stats["numpy_blocks"] == 0  # XLA rung served, not numpy
@@ -257,8 +257,8 @@ def test_decode_rung_counters_are_exported(monkeypatch):
 
 def test_device_results_in_a_foreign_host_layout_still_frame():
     """Found on the chip (PR 21): np.asarray of a TPU array can come back
-    in the device's own layout, not row-major. Digest ROWS are handed to
-    the drives as writev buffers, and a strided row is refused there
+    in the device's own layout, not row-major. Digest and parity ROWS are
+    handed to the drives as writev buffers, and a strided row is refused there
     ('memoryview: underlying buffer is not C-contiguous') — every drive
     append failed and the PUT answered 500. The dispatcher owns the D2H
     boundary: whatever layout arrives, waiters get C-contiguous arrays."""
@@ -284,16 +284,18 @@ def test_device_results_in_a_foreign_host_layout_still_frame():
     blocks = np.random.default_rng(3).integers(
         0, 256, size=(4, d, n), dtype=np.uint8
     )
-    shards, digests = disp.encode(blocks)
+    parity, digests = disp.encode(blocks)
     assert disp.stats["numpy_blocks"] == 0  # the device rung served it
-    assert shards.flags.c_contiguous and digests.flags.c_contiguous
+    assert parity.flags.c_contiguous and digests.flags.c_contiguous
     np.testing.assert_array_equal(
-        digests[0], hash256_batch_numpy(shards[0])
+        digests[0], hash256_batch_numpy(np.concatenate([blocks[0], parity[0]]))
     )
-    # the framing the streaming PUT does, into a real file object
+    # the framing the streaming PUT does, into a real file object: a data
+    # shard's rows come from `blocks`, a parity shard's from the dispatch
     vecs = [[] for _ in range(d + p)]
-    ErasureCoder(d, p)._frame_into(vecs, shards, digests)
-    sink = io.BytesIO()
-    sink.writelines(vecs[0])
-    assert sink.getvalue()[:32] == digests[0, 0].tobytes()
-    assert len(sink.getvalue()) == 4 * (32 + n)
+    ErasureCoder(d, p)._frame_into(vecs, blocks, parity, digests)
+    for i, row in ((0, blocks[0, 0]), (d, parity[0, 0])):
+        sink = io.BytesIO()
+        sink.writelines(vecs[i])
+        assert sink.getvalue()[: 32 + n] == digests[0, i].tobytes() + row.tobytes()
+        assert len(sink.getvalue()) == 4 * (32 + n)

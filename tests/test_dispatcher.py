@@ -36,12 +36,13 @@ def test_dispatch_correctness_and_batching():
         t.join()
 
     for i in range(8):
-        shards, digests = results[i]
+        parity, digests = results[i]
+        assert parity.shape == (2, 2, n)  # parity rows only: data stays the caller's
         for k in range(2):
             expect = ref.encode(
                 np.concatenate([inputs[i][k], np.zeros((2, n), np.uint8)])
             )
-            np.testing.assert_array_equal(shards[k], expect)
+            np.testing.assert_array_equal(parity[k], expect[4:])
             np.testing.assert_array_equal(
                 digests[k], hash256_batch_numpy(expect)
             )

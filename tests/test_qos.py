@@ -514,29 +514,29 @@ def test_dispatch_background_starvation_protection():
              "", False, disp.codec)
         )
         disp._cv.notify()
-    shards, digests = aged_fut.result(timeout=10)
-    assert shards.shape == (1, 6, 256)
+    parity, digests = aged_fut.result(timeout=10)
+    assert parity.shape == (1, 2, 256) and digests.shape == (1, 6, 32)
     assert disp.stats["bg_forced"] >= 1
 
 
 def test_dispatch_priority_results_byte_identical():
     """Priority routing must not change results: both lanes produce the
-    same shards/digests as the numpy reference codec."""
+    same parity/digests as the numpy reference codec."""
     from minio_tpu.ops import rs
     from minio_tpu.ops.highwayhash import hash256_batch_numpy
 
     disp = _dispatcher(window_s=0.0)
     ref = rs.get_codec(4, 2)
     data = _blocks(2)
-    fg_shards, fg_digests = disp.encode(data)
+    fg_parity, fg_digests = disp.encode(data)
     with background_context():
-        bg_shards, bg_digests = disp.encode(data)
+        bg_parity, bg_digests = disp.encode(data)
     for k in range(2):
         expect = ref.encode(
             np.concatenate([data[k], np.zeros((2, 256), np.uint8)])
         )
-        np.testing.assert_array_equal(fg_shards[k], expect)
-        np.testing.assert_array_equal(bg_shards[k], expect)
+        np.testing.assert_array_equal(fg_parity[k], expect[4:])
+        np.testing.assert_array_equal(bg_parity[k], expect[4:])
         np.testing.assert_array_equal(fg_digests[k], hash256_batch_numpy(expect))
         np.testing.assert_array_equal(bg_digests[k], hash256_batch_numpy(expect))
 

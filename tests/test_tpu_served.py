@@ -290,12 +290,12 @@ def test_batch_padding_edges(k):
     rng = np.random.default_rng(k)
     blocks = rng.integers(0, 256, size=(k, d, n), dtype=np.uint8)
     disp = TpuDispatcher(get_tpu_codec(d, p), n, window_s=0.001)
-    shards, digests = disp.encode(blocks)
-    assert shards.shape == (k, d + p, n) and digests.shape == (k, d + p, 32)
+    parity, digests = disp.encode(blocks)
+    assert parity.shape == (k, p, n) and digests.shape == (k, d + p, 32)
     assert disp.stats.get("fused_failures", 0) == 0
     ref = get_codec(d, p)
     for b in range(k):
         want = ref.split(blocks[b].tobytes())
         ref.encode(want)
-        assert (shards[b] == want).all(), f"b={b}"
+        assert (parity[b] == want[d:]).all(), f"b={b}"
         assert (digests[b] == hash256_batch_numpy(want)).all(), f"b={b}"
