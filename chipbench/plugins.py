@@ -2,7 +2,12 @@
 per per-layer metric, named in `BENCHMARK.json`), `generators/<name>.py`
 (the load generator a traffic file names) and `checks/<name>.py` (the steps
 of the comparison a traffic file lists). A later PR adds a file and an
-entry; nothing here changes."""
+entry; nothing here changes. What each receives: a reader `read(w)` a
+`metrics.Window`; a check `run(v)` a `verify.Verification`; a generator the
+traffic file, the endpoint, the bucket and the seed, and then, as the
+attributes `config` and `drives`, the configuration file's content and the
+drive directories that a check finds under `v.config` and `v.srv.drives`
+(`traffic.py` has the whole of what the harness calls)."""
 
 from __future__ import annotations
 

@@ -7,7 +7,7 @@ generator's bodies from the seed, `make_bucket` 200, the device named by the
 child, the generator's warm-up (staged rungs that put every batch bucket the
 cell lists through the device, see `generators/`), then the cell's own client
 loop until `quiet_s` seconds have passed with no new batch bucket, no new
-compiled program, and dispatches still finishing. The window is
+compiled program, and the loop still making progress. The window is
 `--seconds` of that same loop by the clock: nothing starts or stops at its
 edges, so there is no ramp inside it. Then the clients finish what they
 have in flight, the comparison that decides `correct` runs, the server
@@ -17,6 +17,17 @@ The last line of stdout is the result; without a TPU (and without
 `--rehearse`) there is none and the exit code is not 0. `--rehearse` is the
 CPU rehearsal at tiny size: it prints `"platform": "cpu"` and never a
 device metric. `BENCH_RUN` in the environment is not read.
+
+What the generator a traffic file names receives: `Generator(mix, endpoint,
+bucket, seed)`, then, before `prepare()`, two attributes — `gen.config`, the
+content of the cell's `configs/<config>.json`, and `gen.drives`, the server's
+drive directories in the order of its command line — which is what a check
+is given too. A deployment's state (drives offline, say) is therefore stated
+once, in the configuration's file, and enacted by the generator in its
+set-up. `warm.progress` in the traffic file names the counter of `/api/tpu`
+(or of the metrics-v3 group `warm.progress_group`) whose advance says the own
+loop is getting work done; unset, it is `minio_tpu_dispatch_total`, which a
+loop of PUTs moves and a loop of GETs from healthy drives never does.
 """
 
 from __future__ import annotations
@@ -42,6 +53,7 @@ from .procs import (ROOT, BenchFailure, Server, check, check_room, child_env,  #
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 BUCKETS = "minio_tpu_dispatch_bucket_blocks_distribution"
+PROGRESS = "minio_tpu_dispatch_total"
 
 
 def load_json(*parts) -> dict:
@@ -77,6 +89,18 @@ def metric_names(bench: dict, group: str, cell: str) -> list[dict]:
     return [m for m in bench[group] if cell in m.get("workloads", [cell])]
 
 
+def make_generator(mix: dict, endpoint: str, bucket: str, seed: int, config: dict,
+                   drives: list[str]):
+    """The generator the traffic file names, built with the four arguments
+    every generator takes and then given what a check is given: the
+    configuration file's content and the drive directories. `prepare()` has
+    not run yet, so a deployment's state that the configuration states (drives
+    offline, say) can be enacted by the generator in its set-up."""
+    gen = plugins.load("generators", mix["generator"]).Generator(mix, endpoint, bucket, seed)
+    gen.config, gen.drives = config, drives
+    return gen
+
+
 def run(args) -> int:
     check(os.path.isdir(os.path.join(ROOT, "minio_tpu")),
           "minio_tpu/ is not beside chipbench/: run from a checkout of the repository")
@@ -101,8 +125,7 @@ def run(args) -> int:
     try:
         t_boot = time.monotonic()
         endpoint, bucket = f"127.0.0.1:{srv.port}", "chipbench"
-        gen = plugins.load("generators", mix["generator"]).Generator(
-            mix, endpoint, bucket, args.seed)
+        gen = make_generator(mix, endpoint, bucket, args.seed, config, srv.drives)
         gen.prepare()
         bodies_s = time.monotonic() - t_boot
         cli = S3Client(endpoint)
@@ -135,7 +158,10 @@ def run(args) -> int:
         # quiet: no new bucket and no new compiled program for quiet_s, and two
         # dispatches finished since the last one: the histogram counts a bucket
         # when its dispatch STARTS, and its first dispatch may trace and lower
-        # for many seconds, so a bucket seen is not yet a bucket warmed
+        # for many seconds, so a bucket seen is not yet a bucket warmed. Which
+        # counter says "finished" is the mix's to name (`warm.progress`)
+        progress = mix["warm"].get("progress", PROGRESS)
+        group = mix["warm"].get("progress_group", "/api/tpu")
         last_change, state, done_then = t_loop, None, 0.0
         while True:
             time.sleep(0.5)
@@ -143,7 +169,7 @@ def run(args) -> int:
             s = scrape(srv.port, "/api/tpu")
             now = time.monotonic()
             new = (buckets_seen(s), total(s, "minio_tpu_compile_programs_total"))
-            done = total(s, "minio_tpu_dispatch_total")
+            done = total(s if group == "/api/tpu" else scrape(srv.port, group), progress)
             if new != state:
                 state, last_change, done_then = new, now, done
             quiet = now - last_change >= mix["warm"]["quiet_s"] and done >= done_then + 2

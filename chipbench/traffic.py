@@ -8,6 +8,23 @@ everything else is parameters (clients, sizes, the warm-up ladder, samples).
 A later cell with other parameters adds a traffic file and nothing else; one
 that needs a generator or a check that is not here adds that file too, and
 edits none. The harness (`run.py`) keeps only the window and the records.
+
+What the harness reads of every traffic file: `generator`, `checks`,
+`trace_s`, `drives_room_gib` and `warm` = {`quiet_s`, `min_s`, `max_s`, and
+optionally `progress`: the counter whose advance, by two since the last new
+batch bucket or compiled program, says the own-loop warm-up is getting work
+done — `minio_tpu_dispatch_total` of `/api/tpu` where it is not named, which
+only a loop that encodes moves; `progress_group` names another metrics-v3
+group to find it in}. A `rehearse` object overrides top-level keys, each
+whole, for the CPU rehearsal.
+
+What a generator is: `Generator(mix, endpoint, bucket, seed)`; the harness
+then sets `gen.config` (the content of the cell's `configs/<config>.json`)
+and `gen.drives` (the server's drive directories), which is what a check is
+given, and calls `prepare()`, `warm_up(seen, want) -> (records, tries)`,
+`start()`, `stop()`, `records()`; the checks call `sent(record) -> (body,
+md5)` for a PUT it recorded. A deployment's state belongs in the
+configuration's file, and the generator enacts it in set-up.
 """
 
 from __future__ import annotations

@@ -1,7 +1,12 @@
 """The whole run, rehearsed on the CPU at tiny size: launcher, ladder,
 window, verification, last line. `--rehearse` prints `"platform": "cpu"`
 and never a device metric; without it, in a sandbox with no accelerator,
-there is no result line and the exit code is not 0."""
+there is no result line and the exit code is not 0.
+
+Every cell of `BENCHMARK.json` is rehearsed. What the contract asks of a
+run is asserted of every cell; what a check writes into `details` is
+asserted where the cell's traffic file lists that check, so a cell with
+other traffic brings its own data and edits nothing here."""
 
 import json
 import os
@@ -12,11 +17,23 @@ import pytest
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, HERE) if HERE not in sys.path else None
-from harness import REPO, RESULT_KEYS, bench  # noqa: E402
+from harness import REPO, RESULT_KEYS, bench, scratch_copy  # noqa: E402
 
 with open(os.path.join(REPO, "BENCHMARK.json")) as _f:
     BENCH = json.load(_f)
 CELLS = [w["name"] for w in BENCH["workloads"]]
+
+
+def cell_data(cell: str) -> tuple[dict, dict]:
+    """(the cell's traffic file at rehearsal size, its configuration's
+    `deployment`), as the harness finds them: by the names in BENCHMARK.json."""
+    sys.path.insert(0, REPO) if REPO not in sys.path else None
+    from chipbench.traffic import load_mix
+
+    entry = next(w for w in BENCH["workloads"] if w["name"] == cell)
+    cfg = next(c for c in BENCH["configs"] if c["name"] == entry["config"])
+    with open(os.path.join(REPO, cfg["file"])) as f:
+        return load_mix(entry["traffic"], rehearse=True), json.load(f)["deployment"]
 
 
 @pytest.fixture(scope="module")
@@ -30,11 +47,12 @@ def test_rehearsal_runs_one_cell_end_to_end(cell, cache, tmp_path):
                     "--trace", "0", "--rehearse", TMPDIR=str(tmp_path))
     assert r.returncode == 0 and last is not None, r.stderr[-3000:]
     assert os.listdir(tmp_path) == []  # server log, control files, drives: all gone
-    # the contract's keys, all of them; what else is there the driver ignores
+    # -- what the contract asks of every cell
+    # its keys, all of them; what else is there the driver ignores
     assert RESULT_KEYS <= set(last) and list(last)[-1] == "checks"
     assert last["correct"] is True and last["failed"] == 0 and last["attempted"] >= 1
     mine = [m for m in BENCH["end_to_end"] if cell in m.get("workloads", [cell])]
-    assert set(last["metrics"]) == {m["name"] for m in mine} >= {"setup_s", "s3_mib_s"}
+    assert set(last["metrics"]) == {m["name"] for m in mine} >= {"setup_s"} and len(mine) >= 2
     for m in mine:
         got = last["metrics"][m["name"]]
         assert got["unit"] == m["unit"] and got["value"] > 0
@@ -42,20 +60,48 @@ def test_rehearsal_runs_one_cell_end_to_end(cell, cache, tmp_path):
     assert last["device"]["platform"] == "cpu" and last["device"]["count"] == 1
     assert "memory_peak_bytes" in last["device"]
     # each number compared stands beside its limit, on stderr's last lines too
-    assert all(set(c) == {"value", "limit"} and c["value"] <= c["limit"]
-               for c in last["checks"].values())
+    assert last["checks"] and all(set(c) == {"value", "limit"} and c["value"] <= c["limit"]
+                                  for c in last["checks"].values())
     tail = r.stderr.strip().splitlines()[-len(last["checks"]):]
-    assert all(ln.startswith("chipbench check ") and "(limit 0)" in ln for ln in tail)
-    d = last["details"]
-    assert d["ondrive_shards_compared"] == 32 and d["degraded_objects"] == 1
-    assert d["keys_written_in_window"] >= 1 and d["readback_keys"] >= 2
-    assert d["dispatcher_blocks_since_boot"] >= d["put_blocks_since_boot"] > 0
+    for ln, (name, c) in zip(tail, last["checks"].items()):
+        assert ln.startswith(f"chipbench check {name}: ") and f"(limit {c['limit']})" in ln
     # the drives were under this run's TMPDIR and nowhere else, and it says so
-    assert last["drives_on"].endswith(f":{tmp_path}") and d["drives_gib_written"] > 0
+    assert last["drives_on"].endswith(f":{tmp_path}")
     # an earlier line says how many requests the window held
     window = [json.loads(ln) for ln in r.stdout.splitlines()
               if ln.startswith('{"phase": "window"')]
     assert window and window[0]["requests"] == last["attempted"]
+    # -- what this cell's own data say: a detail is asserted where the traffic
+    # file lists the check that writes it
+    mix, dep = cell_data(cell)
+    steps, d = mix["checks"], last["details"]
+    assert all(f"{step}_s" in d for step in steps)  # every listed step ran
+    if "ondrive_frames" in steps:  # every shard file of the sampled objects
+        assert d["ondrive_shards_compared"] == mix["verify"]["ondrive_objects"] * dep["drives"]
+    if "degraded_read" in steps:
+        assert d["degraded_objects"] == mix["verify"]["degraded_objects"] >= 1
+    if "readback" in steps:
+        assert d["readback_keys"] >= 2
+    if "blocks_dispatched" in steps:
+        assert d["dispatcher_blocks_since_boot"] >= d["put_blocks_since_boot"] > 0
+    if mix["generator"] == "closed_loop_put":
+        assert d["keys_written_in_window"] >= 1 and d["drives_gib_written"] > 0
+        # every step of these cells is an exact comparison
+        assert all(c["limit"] == 0 for c in last["checks"].values())
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in BENCH["workloads"]
+                                  if w["traffic"] == "speedtest-put"])
+def test_the_put_cells_assert_every_detail(cell):
+    """For the cells that exist, the data select every assert above: two
+    objects of 16 shard files each (32), one degraded object, `s3_mib_s`."""
+    mix, dep = cell_data(cell)
+    assert "s3_mib_s" in {m["name"] for m in BENCH["end_to_end"]
+                          if cell in m.get("workloads", [cell])}
+    assert {"ondrive_frames", "degraded_read", "readback", "blocks_dispatched"} <= set(
+        mix["checks"]) and mix["generator"] == "closed_loop_put"
+    assert mix["verify"]["ondrive_objects"] * dep["drives"] == 32
+    assert mix["verify"]["degraded_objects"] == 1
 
 
 def test_traced_rehearsal_reports_counters_and_no_device_metric(cache):
@@ -107,11 +153,7 @@ def test_a_cell_added_as_data_files_is_found_without_an_edit(tmp_path, cache):
     """What a later PR does: a traffic file, a cell file and a BENCHMARK.json
     entry — no code. Here the same generator with other parameters: more
     clients (PERF.md's open question "clients 8 -> 16 -> 32")."""
-    for top in ("chipbench", "tests/chipbench"):
-        shutil.copytree(os.path.join(REPO, top), tmp_path / top,
-                        ignore=shutil.ignore_patterns("__pycache__"))
-    os.symlink(os.path.join(REPO, "minio_tpu"), tmp_path / "minio_tpu")
-    bench_json = json.loads(json.dumps(BENCH))
+    bench_json = scratch_copy(tmp_path)
     bench_json["workloads"].append({
         "name": "ec12p4-16d.speedtest-put-16c", "config": "ec12p4-16d",
         "traffic": "speedtest-put-16c", "chips": 1, "why": "16 closed-loop clients"})
@@ -131,11 +173,7 @@ def test_a_cell_added_as_data_files_is_found_without_an_edit(tmp_path, cache):
 
 def test_a_traffic_file_that_names_no_generator_here_fails(tmp_path, cache):
     """A mix this tree has no generator for is an error, never another mix."""
-    for top in ("chipbench", "tests/chipbench"):
-        shutil.copytree(os.path.join(REPO, top), tmp_path / top,
-                        ignore=shutil.ignore_patterns("__pycache__"))
-    os.symlink(os.path.join(REPO, "minio_tpu"), tmp_path / "minio_tpu")
-    shutil.copy(os.path.join(REPO, "BENCHMARK.json"), tmp_path)
+    scratch_copy(tmp_path)
     path = tmp_path / "chipbench" / "traffic" / "speedtest-put.json"
     mix = json.loads(path.read_text())
     mix["generator"] = "open_loop"

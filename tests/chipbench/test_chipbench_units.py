@@ -1,6 +1,14 @@
 """chipbench's yardstick, piece by piece, on the CPU: no server, no chip,
 and no jax while this module is imported. A chip result comes only from
-`python3 -m chipbench` on the chip; nothing here is one."""
+`python3 -m chipbench` on the chip; nothing here is one.
+
+The tests of `BENCHMARK.json` and its data files find the benchmark from
+where this file lies (`REPO`), so a copy of the tree polices itself
+(`test_chipbench_added_cell.py` adds a cell to one and runs them there).
+They hold EVERY configuration, cell and metric to the contract's letter, and
+the ones that exist today to their own numbers by name: what a later PR adds
+as new files and appended entries must pass the first and is not held to the
+second."""
 
 import json
 import os
@@ -77,12 +85,27 @@ def test_config_entry_and_file(c):
     assert "assumed" in body and "guarantees" in body
     dep = body["deployment"]
     # the shapes are the source's own and are never cut
-    assert dep["stripe_block_bytes"] == 1 << 20 and dep["drives"] == 16
+    assert dep["stripe_block_bytes"] == 1 << 20
     assert dep["data_shards"] + dep["parity_shards"] == dep["drives"]
     assert dep["shard_bytes"] == work.shard_len(dep["data_shards"])
     d, p = dep["data_shards"], dep["parity_shards"]
     assert body["guarantees"]["write_quorum"] == (d + 1 if d == p else d)
     assert body["guarantees"]["readable_with_drives_missing"] == p
+    # what the harness and the checks read of every configuration
+    assert isinstance(body["server_env"], dict)
+    assert body["expects"]["backend_level"] in (0, 1, 2) and body["expects"]["device_rung"]
+
+
+@pytest.mark.parametrize("name,d,p,shard", [("ec8p8-16d", 8, 8, 131072),
+                                            ("ec12p4-16d", 12, 4, 87382)])
+def test_the_two_16_drive_sets_keep_their_shapes(name, d, p, shard):
+    """The configurations that exist, by name: one set of 16 drives each."""
+    entry = next(c for c in BENCH["configs"] if c["name"] == name)
+    with open(os.path.join(REPO, entry["file"])) as f:
+        dep = json.load(f)["deployment"]
+    assert dep["drives"] == 16 and dep["erasure_sets"] == 1
+    assert (dep["data_shards"], dep["parity_shards"], dep["shard_bytes"]) == (d, p, shard)
+    assert sorted(entry["reduced"]) == ["clients", "drives_are_directories"]
 
 
 @pytest.mark.parametrize("w", BENCH["workloads"], ids=lambda w: w["name"])
@@ -92,11 +115,34 @@ def test_workload_entry_and_files(w):
     assert 1 <= len(w["why"]) <= 200 and "\t" not in w["why"]
     assert w["config"] in {c["name"] for c in BENCH["configs"]}
     assert os.path.isfile(os.path.join(REPO, "chipbench", "workloads", w["name"] + ".json"))
-    mix = traffic.load_mix(w["traffic"], rehearse=False)
-    assert mix["clients"] == 8 and mix["object_mib"] == 64 and "rehearse" not in mix
-    # the generator and every step of the comparison are files found by name
-    assert callable(plugins.load("generators", mix["generator"]).Generator)
-    assert mix["checks"] and all(callable(plugins.load("checks", c).run) for c in mix["checks"])
+    for mix in (traffic.load_mix(w["traffic"], rehearse=False),
+                traffic.load_mix(w["traffic"], rehearse=True)):
+        # what the harness reads of every traffic file, at both sizes
+        assert "rehearse" not in mix and mix["trace_s"] > 0 and mix["drives_room_gib"] > 0
+        assert 0 < mix["warm"]["quiet_s"] <= mix["warm"]["max_s"] >= mix["warm"]["min_s"] > 0
+        assert NAME.match(mix["warm"].get("progress", "minio_tpu_dispatch_total"))
+        # the generator and every step of the comparison are files found by name
+        assert callable(plugins.load("generators", mix["generator"]).Generator)
+        assert mix["checks"] and all(callable(plugins.load("checks", c).run)
+                                     for c in mix["checks"])
+
+
+def test_speedtest_put_is_8_clients_of_64_mib():
+    """The mix that exists, by name: what `mc admin speedtest`'s PUT phase
+    sends, cut to 8 clients, and every step of `correct` the PUT cells have."""
+    mix = traffic.load_mix("speedtest-put", rehearse=False)
+    assert mix["clients"] == 8 and mix["object_mib"] == 64
+    assert mix["generator"] == "closed_loop_put" and mix["unsigned_payload"] is True
+    assert "progress" not in mix["warm"]  # the default, `minio_tpu_dispatch_total`
+    assert mix["checks"] == ["answers", "readback", "ondrive_frames", "degraded_read",
+                             "device_served", "device_rung", "blocks_dispatched"]
+    assert mix["verify"] == {"readback_keys_per_client": 4, "ondrive_objects": 2,
+                             "degraded_objects": 1, "timeout_s": 60}
+    small = traffic.load_mix("speedtest-put", rehearse=True)
+    assert small["checks"] == mix["checks"] and small["generator"] == mix["generator"]
+    assert small["verify"]["ondrive_objects"] == 2 and small["verify"]["degraded_objects"] == 1
+    both = {w["name"] for w in BENCH["workloads"] if w["traffic"] == "speedtest-put"}
+    assert both >= {"ec12p4-16d.speedtest-put", "ec8p8-16d.speedtest-put"}
 
 
 def test_every_cell_reports_what_its_per_layer_metrics_move():
@@ -309,6 +355,28 @@ def test_every_seed_gives_the_same_work_in_another_order():
     a, _ = mod.make_bodies(2 ** 31 + 12345, 3, 1 << 16)
     b, md5b = mod.make_bodies(2 ** 31 + 12345, 3, 1 << 16)
     assert a == b and len({len(x) for x in a}) == 1 and len(set(md5b)) == len(md5b)
+
+
+def test_the_harness_gives_the_generator_the_configuration_and_the_drives():
+    """Before `prepare()`, as attributes: the one generator that exists takes
+    four arguments, is not edited, and walks and makes bodies as it did."""
+    from chipbench.run import make_generator
+
+    mix = traffic.load_mix("speedtest-put", rehearse=True)
+    mix.update(object_mib=1, distinct_bodies=2)
+    config, drives, seed = {"deployment": {"drives": 2}}, ["/x/d00", "/x/d01"], 2 ** 31 + 12345
+    given = make_generator(mix, "x:1", "b", seed, config, drives)
+    assert given.config is config and given.drives is drives
+    assert given.bodies == [] and given.md5s == []  # prepare() has not run
+    plain = plugins.load("generators", mix["generator"]).Generator(mix, "x:1", "b", seed)
+    assert not hasattr(plain, "config") and not hasattr(plain, "drives")
+    walks = [[g.body_for(c, i) for c in range(g.clients) for i in range(3)]
+             for g in (plain, given)]
+    assert walks[0] == walks[1] == [1, 0, 1, 0, 1, 0]
+    given.prepare(), plain.prepare()
+    assert given.md5s == plain.md5s == ['934c4b34c0cd938d3ab5ccc1b0bf7fae',
+                                        '256ee7a04ff8a7c6e24f7145dbfb9faa']
+    assert given.bodies == plain.bodies
 
 
 def test_a_name_with_no_file_is_an_error():
