@@ -49,8 +49,9 @@ HOSTSYNC_BOUNDARY: dict[str, set[str]] = {
         "_probe_device",
     },
     # decode boundary: rebuilt shards + digests materialize for the
-    # bitrot/write plane
-    "ops/bitrot_jax.py": {"_try_fused_decode"},
+    # bitrot/write plane (both device rungs of a degraded read; every
+    # `decode` phase ends synced so that its seconds are what it took)
+    "ops/bitrot_jax.py": {"_try_fused_decode", "xla_decode"},
     # host-side GF weight construction (cached per-shape, trace time)
     # and the bytes-in/bytes-out API boundary
     "ops/rs_jax.py": {"gf_matrix_to_bitplanes", "encode_data"},

@@ -69,8 +69,8 @@ def repair_reads_enabled() -> bool:
 _FSTATS_LOCK = threading.Lock()
 _FAMILY_STATS: dict[str, dict[str, int]] = {}
 _FSTAT_KEYS = (
-    "encode_blocks", "decode_blocks", "heal_ingress_bytes",
-    "degraded_ingress_bytes", "repair_partial_blocks",
+    "encode_blocks", "decode_blocks", "decode_host_blocks",
+    "heal_ingress_bytes", "degraded_ingress_bytes", "repair_partial_blocks",
 )
 
 
@@ -533,6 +533,7 @@ class ErasureCoder:
             shards[i] = present[i]
         rec = self._np.reconstruct(shards)
         family_stats_add(self.family, "decode_blocks", 1)
+        family_stats_add(self.family, "decode_host_blocks", 1)
         return {i: rec[i] for i in range(self.t)}
 
     def reconstruct_data_flat(
@@ -549,21 +550,14 @@ class ErasureCoder:
         without a transpose, and a thread pool splits the column range so
         the apply scales past one core (ctypes releases the GIL).
         """
-        from .. import native
-
         d_, w, per = survivors.shape
         family_stats_add(self.family, "decode_blocks", w)
-        if self.family == FAMILY_CAUCHY:
-            # cauchy decode runs on the numpy/native GF plane: the
-            # piggyback purify step chains two applies, and repair-path
-            # reads (the family's point) are bandwidth- not compute-
-            # bound. Device decode is a named PERF round-9 next lever.
-            return self._np.reconstruct_flat(survivors, present, missing)
         if (
-            self._jax is not None
+            self.family != FAMILY_CAUCHY
+            and self._jax is not None
             and w * self.t >= int(os.environ.get("MINIO_TPU_DECODE_MIN_SHARDS", "64"))
         ):
-            from ..ops.bitrot_jax import _try_fused_decode, count_xla_decode
+            from ..ops.bitrot_jax import _try_fused_decode, xla_decode
             from ..ops.highwayhash import MINIO_KEY
 
             arr = survivors.transpose(1, 0, 2)  # [W, d, per]
@@ -571,13 +565,23 @@ class ErasureCoder:
             fused = _try_fused_decode(self._jax, arr, present, missing, MINIO_KEY)
             if fused is not None:
                 return fused[0].transpose(1, 0, 2)
-            # ascontiguousarray: the host layout of a TPU array is not
-            # promised row-major, and callers copy shard ROWS out of this
-            out = np.ascontiguousarray(
-                np.asarray(self._jax.reconstruct_blocks(arr, present, missing))
-            )
-            count_xla_decode(w)
-            return out.transpose(1, 0, 2)
+            return xla_decode(self._jax, arr, present, missing).transpose(1, 0, 2)
+        # the cauchy family, a group under the device floor, a CPU-plane
+        # process: the host's GF apply, on the calling thread and the pool
+        family_stats_add(self.family, "decode_host_blocks", w)
+        with obs.phase("decode", "host"):
+            return self._reconstruct_flat_host(survivors, present, missing, pool)
+
+    def _reconstruct_flat_host(self, survivors, present, missing, pool):
+        from .. import native
+
+        if self.family == FAMILY_CAUCHY:
+            # cauchy decode runs on the numpy/native GF plane: the
+            # piggyback purify step chains two applies, and repair-path
+            # reads (the family's point) are bandwidth- not compute-
+            # bound. Device decode is a named PERF round-9 next lever.
+            return self._np.reconstruct_flat(survivors, present, missing)
+        d_, w, per = survivors.shape
         mat = self._decode_rows(present, missing)
         flat = survivors.reshape(self.d, w * per)
         if native.available():

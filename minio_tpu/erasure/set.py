@@ -995,6 +995,12 @@ class ErasureSet:
             return memoryview(buf)[a:b] if zc else bytes(memoryview(buf)[a:b])
 
         def read_shard_block(part_num: int, idx: int, per: int, f_off: int):
+            # the read pool's threads: the drive read and the frame's
+            # bitrot verify, as `put`/`drive_io` is the write side's
+            with obs.phase("get", "shard_io"):
+                return _read_shard_block(part_num, idx, per, f_off)
+
+        def _read_shard_block(part_num: int, idx: int, per: int, f_off: int):
             disk, m = sources[idx]
             wf = _whole_file_hash(m, part_num)
             if wf is not None:
@@ -1229,6 +1235,9 @@ class ErasureSet:
                 return
             plan = plan[k:]  # resume on the reconstructing path
 
+        # once per read that reaches the reconstructing path: a reader of
+        # the phase table divides by its calls to get "per GET"
+        starting = obs.PhaseClock("get", "start")
         pool = _read_pool()
         window = max(1, int(os.environ.get("MINIO_TPU_READ_WINDOW", "8")))
         hedge_budget = self._hedge_budget_s()
@@ -1375,10 +1384,11 @@ class ErasureSet:
                 present = tuple(sorted(got[bi].keys())[:d])
                 if present == tuple(range(d)):
                     per = win[bi][1]
-                    buf = bytearray(d * per)
-                    mv = memoryview(buf)
-                    for i in range(d):
-                        mv[i * per : (i + 1) * per] = got[bi][i]
+                    with obs.phase("get", "join"):
+                        buf = bytearray(d * per)
+                        mv = memoryview(buf)
+                        for i in range(d):
+                            mv[i * per : (i + 1) * per] = got[bi][i]
                     bufpool.count_copy("gather-join")
                     out[bi] = buf
                 else:
@@ -1402,30 +1412,37 @@ class ErasureSet:
                 nb = d * len(bis) * per
                 stack_lease = bufpool.get_pool().acquire(nb) if zc else None
                 try:
-                    if stack_lease is not None:
-                        survivors = stack_lease.array[:nb].reshape(
-                            d, len(bis), per
-                        )
-                    else:
-                        survivors = np.empty((d, len(bis), per), dtype=np.uint8)
-                    for k, i in enumerate(present):
-                        for w, bi in enumerate(bis):
-                            survivors[k, w] = np.frombuffer(
-                                got[bi][i], dtype=np.uint8
+                    with obs.phase("get", "stack"):
+                        if stack_lease is not None:
+                            survivors = stack_lease.array[:nb].reshape(
+                                d, len(bis), per
                             )
-                    rec = coder.reconstruct_data_flat(
-                        survivors, present, missing, pool
-                    )
+                        else:
+                            survivors = np.empty(
+                                (d, len(bis), per), dtype=np.uint8
+                            )
+                        for k, i in enumerate(present):
+                            for w, bi in enumerate(bis):
+                                survivors[k, w] = np.frombuffer(
+                                    got[bi][i], dtype=np.uint8
+                                )
+                    # not a leaf: the `decode` phases tile it
+                    with obs.phase("get", "decode_wait", blocks=len(bis),
+                                   missing=len(missing)):
+                        rec = coder.reconstruct_data_flat(
+                            survivors, present, missing, pool
+                        )
                 finally:
                     if stack_lease is not None:
                         stack_lease.release()
                 mj = {i: j for j, i in enumerate(missing)}
                 for w, bi in enumerate(bis):
-                    buf = bytearray(d * per)
-                    mv = memoryview(buf)
-                    for i in range(d):
-                        src = rec[mj[i], w] if i in mj else got[bi][i]
-                        mv[i * per : (i + 1) * per] = src
+                    with obs.phase("get", "join"):
+                        buf = bytearray(d * per)
+                        mv = memoryview(buf)
+                        for i in range(d):
+                            src = rec[mj[i], w] if i in mj else got[bi][i]
+                            mv[i * per : (i + 1) * per] = src
                     bufpool.count_copy("gather-join")
                     out[bi] = buf
             return out
@@ -1758,9 +1775,15 @@ class ErasureSet:
         # ---- pipelined execution: window k+1 reads under window k decode ----
         windows = [plan[i : i + window] for i in range(0, len(plan), window)]
         futs = start_window(windows[0]) if windows else {}
+        starting.book()
+        # yield -> resumption: the front end's write and its executor hop
+        responding = obs.PhaseClock("get", "respond")
         try:
             for wi, win in enumerate(windows):
-                got = gather_window(win, futs)
+                # the window's reads were submitted as the last one's
+                # readahead: what is left of them is what a GET waits for
+                with obs.phase("get", "read_wait", blocks=len(win)):
+                    got = gather_window(win, futs)
                 futs = {}
                 if wi + 1 < len(windows):
                     futs = start_window(windows[wi + 1])  # readahead
@@ -1772,11 +1795,17 @@ class ErasureSet:
                         # so even a partial-range request fills whole
                         # verified segments (the cache copies on admit —
                         # site "cache-fill" — so serving views is safe)
-                        seg_sink(
-                            pnum, f_off // (fdig + coder.shard_size),
-                            block,
-                        )
-                    yield serve_slice(block, lo, hi)
+                        with obs.phase("get", "cache_fill"):
+                            seg_sink(
+                                pnum, f_off // (fdig + coder.shard_size),
+                                block,
+                            )
+                    piece = serve_slice(block, lo, hi)
+                    responding.restart()
+                    yield piece
+                    # resumed, maybe on another thread of the I/O pool: a
+                    # thread's CPU clock says nothing across that
+                    responding.book(cpu=False)
         finally:
             # abandoned iterator (client hung up) or error: don't let
             # readahead reads+verifies hog the shared pool

@@ -9,8 +9,9 @@ open spans unconditionally.
 ``phase()`` is the always-on sibling: it adds wall seconds, thread CPU
 seconds and one call to a process-global table whether or not anyone
 subscribes (``phases_snapshot()``; exported on ``/api/tpu``), publishes
-the same ``Span`` record when someone does, and — for the dispatch
-thread's leaf phases only — enters a ``jax.profiler.TraceAnnotation``
+the same ``Span`` record when someone does, and — for the leaf phases
+that surround a device call (the dispatch thread's, the device decode's
+and a reconstructing GET's own) — enters a ``jax.profiler.TraceAnnotation``
 so the phase lands in a device trace on the profiler's own clock.
 
 The request context is a ``contextvars.ContextVar`` so it survives both
@@ -228,13 +229,41 @@ def span(trace_type: str, name: str, **fields):
 # except `drive_io`, which the drive pool's threads book. Nothing books
 # `dispatch`/`frame` any more (it was the data + parity concatenate): the
 # row stays at zero so that readers of the table keep finding it.
+# `get` phases run on the thread that serves a GET on the reconstructing
+# (windowed) read path (erasure/set.py): `start` (once per such read:
+# the first window's shard reads submitted), `read_wait` (a window's shard
+# reads until every block has d shards, hedges included), `stack`
+# (survivors into [d, W, per]), `decode_wait` (the whole
+# reconstruct_data_flat call; the `decode` leaves tile it), `join` (the
+# per-block gather-join copy), `cache_fill` (the block offered to the
+# range-segment cache), `respond` (yield -> resumption: the front end's
+# write and the executor hop; wall only, the thread may change), and
+# `shard_io` on the read pool's threads (read_file + verify_block).
+# `decode` phases are the leaves of one device reconstruct
+# (ops/bitrot_jax.py, erasure/coder.py): `pad` (survivors made
+# block-major and zero-padded to the kernel's batch), `pack`, `h2d`,
+# `kernel` (call -> ready; a first call's trace-and-lower too), `d2h`,
+# `unpack`; `host` is a group rebuilt by the native/numpy GF apply.
 PHASES = {
     "dispatch": ("wait", "window", "assemble", "pack", "h2d", "kernel",
                  "d2h", "unpack", "frame", "numpy", "fanout"),
     "put": ("ingest", "stage", "encode_wait", "frame", "md5",
             "drive_write", "commit", "drive_io"),
+    "get": ("start", "read_wait", "stack", "decode_wait", "join",
+            "cache_fill", "respond", "shard_io"),
+    "decode": ("pad", "pack", "h2d", "kernel", "d2h", "unpack", "host"),
 }
-_PHASE_TYPES = {"dispatch": TYPE_TPU, "put": TYPE_INTERNAL}
+_PHASE_TYPES = {"dispatch": TYPE_TPU, "put": TYPE_INTERNAL,
+                "get": TYPE_INTERNAL, "decode": TYPE_TPU}
+# the phases that go to the profiler: leaves only. An enclosing phase
+# (`put`/`encode_wait`, `get`/`decode_wait`) would win every idle gap of a
+# device trace and say nothing; the pools' threads (`drive_io`,
+# `shard_io`) would bury it in events.
+_ANNOTATED = {
+    "dispatch": frozenset(PHASES["dispatch"]),
+    "decode": frozenset(PHASES["decode"]),
+    "get": frozenset(PHASES["get"]) - {"decode_wait", "shard_io"},
+}
 _phase_mu = threading.Lock()
 # (layer, name) -> [wall seconds, thread CPU seconds, calls]
 _phase_table: dict[tuple[str, str], list] = {
@@ -275,9 +304,14 @@ class PhaseClock:
         self._c0 = time.thread_time()
         self._t0 = time.monotonic()
 
-    def book(self) -> None:
+    def book(self, cpu: bool = True) -> None:
+        """`cpu=False` books wall seconds only: for a stretch that may end
+        on another thread than it began on (a generator resumed by an
+        executor), where a thread's CPU clock says nothing."""
         wall = time.monotonic() - self._t0
-        _phase_book(self._row, wall, time.thread_time() - self._c0)
+        _phase_book(
+            self._row, wall, time.thread_time() - self._c0 if cpu else 0.0
+        )
         p = _publisher
         if p is not None and p.active:
             req_id, parent_id = _CTX.get() or ("", 0)
@@ -311,11 +345,12 @@ class Phase:
             Span(_PHASE_TYPES[layer], f"{layer}.{name}", fields)
             if p is not None and p.active else None
         )
-        # only the dispatch thread's leaves go to the profiler: a reader
-        # that names a device-idle gap by the host event covering most of
-        # it would see nothing but an enclosing request-thread phase
+        # only leaves go to the profiler (_ANNOTATED): a reader that names
+        # a device-idle gap by the host event covering most of it would
+        # see nothing but an enclosing request-thread phase
         self._ann = (
-            _trace_annotation(f"dispatch.{name}") if layer == "dispatch" else None
+            _trace_annotation(f"{layer}.{name}")
+            if name in _ANNOTATED.get(layer, ()) else None
         )
         self._t0 = self._c0 = 0.0
 

@@ -294,6 +294,11 @@ def _select_hash_fn():
     return hash256_blocks
 
 
+# imported here, below the hash chain, so that the lines above keep their
+# numbers: the Mosaic kernels carry source locations of the functions traced
+# into them, and a shifted line is another key in the compile cache
+from .. import obs  # noqa: E402
+
 # decode mega-kernel fallback discipline: transient failures back off
 # exponentially and re-probe (same policy as the encode dispatcher)
 _fused_dec_cooldown = 0
@@ -307,20 +312,74 @@ _fused_dec_backoff = 8
 # "xla_blocks" the row-major XLA decode rung, "failures" swallowed
 # mega-kernel exceptions. Exported on /api/tpu (server/metrics.py): a
 # degraded GET that was rebuilt on the CPU moves none of the four.
-decode_stats = {"fused": 0, "blocks": 0, "failures": 0, "xla": 0, "xla_blocks": 0}
+# "by_missing" splits the same dispatches and blocks by how many shards a
+# dispatch rebuilt ({(rung, m): [dispatches, blocks]}: a hedge that wins
+# puts a second parity shard in a straggler's place, and the kernel for
+# m = 2 is another program); "pad_blocks" counts the zero blocks that
+# filled a fused batch up to the kernel's multiple of 16; "first_calls" /
+# "first_call_s" the first call of each (rung, m, batch) in this process
+# and its `kernel`-phase seconds (trace-and-lower, compile or cache load,
+# the run), counted when the call ENDS, as the dispatcher counts its own.
+decode_stats = {
+    "fused": 0, "blocks": 0, "failures": 0, "xla": 0, "xla_blocks": 0,
+    "pad_blocks": 0, "by_missing": {}, "first_calls": {}, "first_call_s": {},
+}
 _decode_stats_lock = threading.Lock()
+_decode_called: set[tuple] = set()  # (rung, d, m, batch, n) that ended once
 
 
 def decode_stats_snapshot() -> dict:
     with _decode_stats_lock:
-        return dict(decode_stats)
+        return {k: (dict(v) if isinstance(v, dict) else v)
+                for k, v in decode_stats.items()}
 
 
-def count_xla_decode(blocks: int) -> None:
-    """One device decode dispatch served by the XLA rung."""
+def _count_decode(rung: str, blocks: int, shape: tuple, kernel_s: float,
+                  pad: int = 0) -> None:
+    """One device decode dispatch that ended: `shape` is (d, m, batch, n)
+    as the program was built for it."""
+    m, batch = shape[1], shape[2]
     with _decode_stats_lock:
-        decode_stats["xla"] += 1
-        decode_stats["xla_blocks"] += blocks
+        st = decode_stats
+        if rung == "fused":
+            st["fused"] += 1
+            st["blocks"] += blocks
+        else:
+            st["xla"] += 1
+            st["xla_blocks"] += blocks
+        st["pad_blocks"] += pad
+        row = st["by_missing"].setdefault((rung, m), [0, 0])
+        row[0] += 1
+        row[1] += blocks
+        if (rung, *shape) not in _decode_called:
+            _decode_called.add((rung, *shape))
+            key = (rung, m, batch)
+            st["first_calls"][key] = st["first_calls"].get(key, 0) + 1
+            st["first_call_s"][key] = st["first_call_s"].get(key, 0.0) + kernel_s
+
+
+def xla_decode(codec, survivors, present, missing) -> np.ndarray:
+    """The XLA rung of a device reconstruct: [B, d, n] survivors (any
+    strides) -> rebuilt [B, m, n], row-major on the host. Books the same
+    `decode` leaves as the fused rung, but `pack` (nothing is relaid out)."""
+    took: dict[str, float] = {}
+    with obs.phase("decode", "pad"):
+        surv = np.ascontiguousarray(survivors, dtype=np.uint8)
+    b, d, n = surv.shape
+    with obs.phase("decode", "h2d"):
+        on_dev = jax.block_until_ready(jax.device_put(surv))
+    with obs.phase("decode", "kernel", into=took):
+        out = jax.block_until_ready(
+            codec.reconstruct_blocks(on_dev, present, missing)
+        )
+    with obs.phase("decode", "d2h"):
+        host = np.asarray(out)
+    with obs.phase("decode", "unpack"):
+        # the host layout of a TPU array is not promised row-major, and
+        # callers copy shard ROWS out of this
+        host = np.ascontiguousarray(host)
+    _count_decode("xla", b, (d, len(missing), b, n), took["kernel"])
+    return host
 
 
 def _try_fused_decode(codec, survivors, present, missing, key):
@@ -344,22 +403,35 @@ def _try_fused_decode(codec, survivors, present, missing, key):
     bpad = -(-b // 16) * 16
     if not fp.supports(d, m, bpad, n):
         return None
+    took: dict[str, float] = {}
     try:
-        if bpad != b:
-            surv = np.concatenate(
-                [surv, np.zeros((bpad - b, d, n), dtype=np.uint8)], axis=0
+        with obs.phase("decode", "pad"):
+            if bpad != b:
+                surv = np.concatenate(
+                    [surv, np.zeros((bpad - b, d, n), dtype=np.uint8)], axis=0
+                )
+        with obs.phase("decode", "pack"):
+            packed = fp.pack_chunk_major(surv)
+        with obs.phase("decode", "h2d"):
+            surv_cm = jax.block_until_ready(jax.device_put(packed))
+            del packed
+        # call -> ready: launch + execution, and on a shape's first call
+        # trace-and-lower and the compile
+        with obs.phase("decode", "kernel", into=took):
+            rebuilt_cm, digests = jax.block_until_ready(
+                fp.fused_decode_hash_cm(
+                    surv_cm, d, codec.parity_shards,
+                    tuple(present), tuple(missing), key,
+                )
             )
-        rebuilt_cm, digests = fp.fused_decode_hash_cm(
-            fp.pack_chunk_major(surv), d, codec.parity_shards,
-            tuple(present), tuple(missing), key,
-        )
-        rebuilt = fp.unpack_chunk_major(np.asarray(rebuilt_cm))[:b]
-        # host layout of a TPU array is not promised row-major
-        digs = np.ascontiguousarray(np.asarray(digests)[:b])
+        with obs.phase("decode", "d2h"):
+            rebuilt_host, digs_host = np.asarray(rebuilt_cm), np.asarray(digests)
+        with obs.phase("decode", "unpack"):
+            rebuilt = fp.unpack_chunk_major(rebuilt_host)[:b]
+            # host layout of a TPU array is not promised row-major
+            digs = np.ascontiguousarray(digs_host[:b])
         _fused_dec_backoff = 8
-        with _decode_stats_lock:
-            decode_stats["fused"] += 1
-            decode_stats["blocks"] += b
+        _count_decode("fused", b, (d, m, bpad, n), took["kernel"], pad=bpad - b)
         return rebuilt, digs[:, d:, :], digs[:, :d, :]
     except Exception as e:  # noqa: BLE001 — lowering/device failure: XLA path
         from . import runtime
@@ -397,12 +469,17 @@ def reconstruct_and_hash(
         rebuilt, rdig, _sdig = fused
         return rebuilt, rdig
     survivors = jnp.asarray(survivors, dtype=jnp.uint8)
-    b, _, n = survivors.shape
+    b, d, n = survivors.shape
     m = len(missing)
-    rebuilt = codec.reconstruct_blocks(survivors, present, missing)
-    hash_fn = _select_hash_fn()
-    digests = hash_fn(rebuilt.reshape(b * m, n), key).reshape(b, m, 32)
-    count_xla_decode(b)
+    took: dict[str, float] = {}
+    # heal keeps its results on the device: the phase ends when the calls
+    # return (a first call's trace-and-lower and compile are inside it, the
+    # run itself may not be), and nothing is synced for the clock's sake
+    with obs.phase("decode", "kernel", into=took):
+        rebuilt = codec.reconstruct_blocks(survivors, present, missing)
+        hash_fn = _select_hash_fn()
+        digests = hash_fn(rebuilt.reshape(b * m, n), key).reshape(b, m, 32)
+    _count_decode("xla", b, (d, m, b, n), took["kernel"])
     return rebuilt, digests
 
 

@@ -1086,6 +1086,11 @@ def main(argv: list[str] | None = None) -> None:
     import argparse
 
     from ..analysis import sanitizer
+    from ..utils import malloc
+
+    # before the data plane allocates: freed buffers stay in the heaps,
+    # whatever layout this boot's arenas take (utils/malloc.py)
+    malloc.retain_freed_memory()
 
     if sanitizer.enabled():
         # before any object-layer construction so instance locks created

@@ -1029,16 +1029,59 @@ def _g_api_tpu(server) -> list[str]:
     # a scrape must not import jax into a CPU-plane process.
     bj = sys.modules.get("minio_tpu.ops.bitrot_jax")
     dec = bj.decode_stats_snapshot() if bj is not None else {}
+    # by rung and by how many shards a dispatch rebuilt: the sums over
+    # `missing` are what the two series were before they had the label.
+    # Rows for 1..8 missing are there from the first scrape
+    by_m = {(r, m): (0, 0) for r in ("fused", "xla") for m in range(1, 9)}
+    by_m.update({k: tuple(v) for k, v in dec.get("by_missing", {}).items()})
     _fmt(out, "minio_tpu_decode_dispatches_total", "counter",
-         [({"rung": "fused"}, dec.get("fused", 0)),
-          ({"rung": "xla"}, dec.get("xla", 0))],
-         "Device reconstruct dispatches by ladder rung (a degraded read "
-         "rebuilt on the host moves neither)")
+         [({"rung": r, "missing": str(m)}, by_m[r, m][0])
+          for r, m in sorted(by_m)],
+         "Device reconstruct dispatches by ladder rung and by the number "
+         "of shards each rebuilt (a degraded read rebuilt on the host "
+         "moves none)")
     _fmt(out, "minio_tpu_decode_device_blocks_total", "counter",
-         [({"rung": "fused"}, dec.get("blocks", 0)),
-          ({"rung": "xla"}, dec.get("xla_blocks", 0))])
+         [({"rung": r, "missing": str(m)}, by_m[r, m][1])
+          for r, m in sorted(by_m)],
+         "Stripe blocks those dispatches rebuilt (padding excluded)")
+    _fmt(out, "minio_tpu_decode_pad_blocks_total", "counter",
+         [({}, dec.get("pad_blocks", 0))],
+         "Zero blocks that filled fused decode batches up to the kernel's "
+         "multiple of 16: device work that rebuilt nothing")
+    # the shapes the shipped read window gives (8 blocks: 16 padded on
+    # the fused rung) are there at zero; others appear when they are met
+    first = {("fused", m, 16): (0, 0.0) for m in range(1, 9)}
+    first.update({("xla", m, 8): (0, 0.0) for m in range(1, 9)})
+    first.update({
+        key: (n, dec["first_call_s"].get(key, 0.0))
+        for key, n in dec.get("first_calls", {}).items()
+    })
+    _fmt(out, "minio_tpu_decode_first_calls_total", "counter",
+         [({"rung": r, "missing": str(m), "batch": str(b)}, first[r, m, b][0])
+          for r, m, b in sorted(first)],
+         "First device reconstructs of a (rung, shards rebuilt, batch, "
+         "shard length) in this process, counted when the call ends")
+    _fmt(out, "minio_tpu_decode_first_call_seconds_total", "counter",
+         [({"rung": r, "missing": str(m), "batch": str(b)},
+           f"{first[r, m, b][1]:.6f}") for r, m, b in sorted(first)],
+         "Seconds those first calls spent in the `decode`/`kernel` phase, "
+         "on the thread of the GET that met them: trace-and-lower, "
+         "compile or cache load, and the run")
     _fmt(out, "minio_tpu_fused_decode_failures_total", "counter",
          [({}, dec.get("failures", 0))])
+    # the read path's hedge bets (erasure/set.py gather_window), mirrored
+    # from /api/fault so that one scrape of this group holds a degraded
+    # GET's whole account
+    from .. import fault
+
+    fc = fault.status()["counters"]
+    _fmt(out, "minio_tpu_get_hedges_total", "counter",
+         [({"event": e}, fc.get(f"hedge_{e}", 0))
+          for e in ("reads", "wins", "losses")],
+         "GET read windows that fired hedged parity reads past the "
+         "straggler budget (reads), and how the bet ended: a hedged shard "
+         "in some block's decode set (wins) or none (losses); the "
+         "minio_fault_hedge_* series of /api/fault")
     # device runtime (ops/runtime.py): which device this process holds and
     # what it compiled vs loaded from the persistent compile cache; zeros
     # and no device row on a CPU-plane process
@@ -1078,6 +1121,11 @@ def _g_api_tpu(server) -> list[str]:
     _fmt(out, "minio_tpu_decode_blocks_total", "counter",
          [({"family": f}, fs[f].get("decode_blocks", 0)) for f in fams],
          "Stripe blocks reconstructed per code family")
+    _fmt(out, "minio_tpu_decode_host_blocks_total", "counter",
+         [({"family": f}, fs[f].get("decode_host_blocks", 0)) for f in fams],
+         "Of those, the blocks rebuilt on the host (native/numpy GF apply): "
+         "a group under MINIO_TPU_DECODE_MIN_SHARDS, the cauchy family, a "
+         "CPU-plane process")
     _fmt(out, "minio_heal_ingress_bytes_total", "counter",
          [({"family": f}, fs[f].get("heal_ingress_bytes", 0)) for f in fams],
          "Survivor bytes read into heal reconstructions per family")

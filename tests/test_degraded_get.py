@@ -1,0 +1,225 @@
+"""A degraded GET on the EC 8+8 set of 16 drives, served over HTTP with drives
+taken offline by the storage fault rule: the bytes returned are the body PUT
+and are what the plain reference (`chipbench/reference_decode.py`) rebuilds
+from the shard files on the surviving drives, for every pair (k, k+8) and for
+1, 4 and 8 drives offline; the read-side phase clock (`obs.phase`, layers
+`get` and `decode`) tiles such a GET; every new `/api/tpu` row is there from
+the first scrape. CPU, seeded, small: the device plane on XLA's CPU backend,
+as the chipbench rehearsals force it."""
+
+import os
+import sys
+import time
+
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO) if REPO not in sys.path else None
+
+from chipbench import reference_decode  # noqa: E402
+from chipbench.procs import parse_metrics  # noqa: E402
+from minio_tpu import fault, obs  # noqa: E402
+from minio_tpu.client import S3Client  # noqa: E402
+
+from test_s3_api import ServerThread  # noqa: E402
+
+BUCKET, KEY, MIB = "degraded", "obj/0000", 1 << 20
+PAIRS = [(k, k + 8) for k in range(8)]
+MORE = [(5,), (0, 2, 9, 15), (1, 2, 3, 4, 10, 11, 12, 13)]
+
+
+@pytest.fixture(scope="module")
+def served(tmp_path_factory):
+    """One 16-drive EC:8 server and one 8 MiB object (8 stripe blocks: one
+    read window of 128 shards, over the 64 that send it to the device rung)."""
+    pytest.importorskip("jax")
+    mp = pytest.MonkeyPatch()
+    mp.setenv("MINIO_TPU_BACKEND", "jax")
+    mp.setenv("MINIO_STORAGE_CLASS_STANDARD", "EC:8")
+    mp.setenv("MINIO_TPU_SCAN_INTERVAL", "0")
+    # a drive's breaker re-probes at once, so that one case's offline drives
+    # are back for the next (the probe fails while the rule is armed)
+    mp.setenv("MINIO_TPU_DRIVE_COOLDOWN_S", "0.01")
+    mp.delenv("MINIO_COMPRESSION_ENABLE", raising=False)
+    base = tmp_path_factory.mktemp("degraded-drives")
+    drives = [str(base / f"d{i:02d}") for i in range(16)]
+    first_scrape = None
+    st = ServerThread(drives)
+    try:
+        cli = S3Client(f"127.0.0.1:{st.port}")
+        first_scrape = cli.request("GET", "/minio/metrics/v3/api/tpu").body.decode()
+        assert cli.make_bucket(BUCKET).status == 200
+        body = np.random.default_rng([2 ** 31 + 29, 7]).bytes(8 * MIB)
+        r = cli.request("PUT", f"/{BUCKET}/{KEY}", body=body, unsigned_payload=True)
+        assert r.status == 200
+        yield st, cli, drives, body, first_scrape
+    finally:
+        fault.clear()
+        st.stop()
+        mp.undo()
+
+
+def take_offline(cli, drives, which):
+    for i in which:
+        r = cli.admin("POST", "fault/inject", body={
+            "boundary": "storage", "mode": "error", "target": drives[i]})
+        assert r.status == 200, r.body
+    assert cli.admin("POST", "cache/clear").status == 200
+
+
+def scrape(cli) -> dict:
+    return parse_metrics(cli.request("GET", "/minio/metrics/v3/api/tpu").body.decode())
+
+
+def total(series, name, **match):
+    return sum(v for labels, v in series.get(name, [])
+               if all(labels.get(k) == w for k, w in match.items()))
+
+
+@pytest.mark.parametrize("offline", PAIRS + MORE, ids=lambda o: "off-" + "-".join(map(str, o)))
+def test_served_degraded_get_is_the_body_and_the_references_reconstruction(served, offline):
+    _, cli, drives, body, _ = served
+    fault.clear()
+    time.sleep(0.05)  # past the breakers' cooldown: the next call probes
+    assert cli.request("GET", f"/{BUCKET}/{KEY}").body == body  # all 16 back
+    take_offline(cli, drives, offline)
+    before = scrape(cli)
+    try:
+        r = cli.request("GET", f"/{BUCKET}/{KEY}")
+    finally:
+        fault.clear()
+    assert r.status == 200 and r.body == body
+    files = reference_decode.read_shards(drives, BUCKET, KEY, skip=offline)
+    assert len(files) == 16 - len(offline)
+    assert reference_decode.decode_object(files, 8, 8) == body == r.body
+    # a pair (k, k+8) holds one data and one parity shard whatever the key;
+    # the other cases lose as many data shards as their drives hold
+    order = reference_decode.shard_order(BUCKET, KEY, 16)
+    lost = sum(1 for i in offline if order[i] < 8)
+    after = scrape(cli)
+    rebuilt = total(after, "minio_tpu_decode_blocks_total") \
+        - total(before, "minio_tpu_decode_blocks_total")
+    assert rebuilt == (8 if lost else 0)
+    if offline in PAIRS:
+        assert lost == 1
+    if lost:
+        # the device rebuilt it (off the TPU: the XLA rung), `lost` shards a block
+        name = "minio_tpu_decode_device_blocks_total"
+        on_device = total(after, name) - total(before, name)
+        host = total(after, "minio_tpu_decode_host_blocks_total") \
+            - total(before, "minio_tpu_decode_host_blocks_total")
+        assert on_device + host == 8
+        by_m = total(after, name, missing=str(lost)) - total(before, name, missing=str(lost))
+        assert by_m == on_device
+
+
+def test_the_reference_refuses_a_frame_whose_digest_is_wrong(served):
+    _, _, drives, body, _ = served
+    files = reference_decode.read_shards(drives, BUCKET, KEY, skip=(3, 11))
+    assert reference_decode.decode_object(files, 8, 8) == body
+    victim = sorted(files)[4]
+    spoiled = bytearray(files[victim])
+    spoiled[32 + 131072 + 40] ^= 0x01  # a byte of the second frame's block
+    with pytest.raises(reference_decode.BadFrame, match=f"shard {victim + 1}, frame 1"):
+        reference_decode.decode_object({**files, victim: bytes(spoiled)}, 8, 8)
+    with pytest.raises(ValueError):
+        reference_decode.decode_object(dict(list(files.items())[:7]), 8, 8)
+
+
+def test_the_phases_tile_a_degraded_get(served):
+    """`decode` leaves tile `get`/`decode_wait`; the `get` phases of the
+    request side stay inside the GET's wall time."""
+    _, cli, drives, body, _ = served
+    fault.clear()
+    time.sleep(0.05)
+    take_offline(cli, drives, (3, 11))
+    try:
+        assert cli.request("GET", f"/{BUCKET}/{KEY}").body == body  # its kernel compiles here
+        before = obs.phases_snapshot()
+        t0 = time.monotonic()
+        for _ in range(3):
+            assert cli.request("GET", f"/{BUCKET}/{KEY}").body == body
+        wall = time.monotonic() - t0
+    finally:
+        fault.clear()
+    after = obs.phases_snapshot()
+    moved = {k: tuple(a - b for a, b in zip(after[k], before[k])) for k in after}
+    assert moved["get", "start"][2] == 3                    # once per GET
+    assert moved["get", "read_wait"][2] == 3                # one window of 8 blocks each
+    assert moved["get", "decode_wait"][2] == moved["get", "stack"][2] >= 3
+    assert moved["get", "join"][2] == 3 * 8 == moved["get", "respond"][2]
+    assert moved["get", "cache_fill"][2] == 3 * 8
+    assert moved["get", "shard_io"][2] >= 3 * 8 * 8 and moved["get", "shard_io"][1] > 0
+    leaves = sum(moved["decode", p][0] for p in obs.PHASES["decode"])
+    decode_wait = moved["get", "decode_wait"][0]
+    # (on the chip the leaves hold 86-87 % of it, PERF.md §5; a loaded test
+    # host leaves more between two phases)
+    assert 0.75 * decode_wait <= leaves <= decode_wait
+    # the device rung of a CPU process: nothing packed, the leaves around the call ran
+    assert moved["decode", "pack"][2] == 0
+    # (a hedge that wins splits a window into groups, and a group under the
+    # device floor is the host's: each `decode_wait` is one or the other)
+    on_device = moved["decode", "kernel"][2]
+    assert on_device >= 1
+    assert on_device + moved["decode", "host"][2] == moved["get", "decode_wait"][2]
+    for p in ("pad", "h2d", "d2h", "unpack"):
+        assert moved["decode", p][2] == on_device
+    assert moved["get", "respond"][1] == 0.0  # wall only: the thread may change
+    request_side = sum(moved["get", p][0] for p in obs.PHASES["get"] if p != "shard_io")
+    assert decode_wait < request_side <= wall
+
+
+def test_every_new_row_is_on_the_first_scrape(served):
+    *_, first = served
+    for layer in ("get", "decode"):
+        for name in obs.PHASES[layer]:
+            for series in ("seconds", "cpu_seconds", "calls"):
+                # (at zero in a fresh process; a test process has run others)
+                assert f'minio_tpu_phase_{series}_total{{layer="{layer}",phase="{name}"}} ' \
+                    in first
+    rows = parse_metrics(first)
+    for name in ("minio_tpu_decode_dispatches_total", "minio_tpu_decode_device_blocks_total"):
+        assert {(lb["rung"], lb["missing"]) for lb, _ in rows[name]} \
+            >= {(r, str(m)) for r in ("fused", "xla") for m in range(1, 9)}
+    assert {(lb["rung"], lb["missing"], lb["batch"])
+            for lb, _ in rows["minio_tpu_decode_first_calls_total"]} \
+        >= {("fused", str(m), "16") for m in range(1, 9)}
+    assert rows["minio_tpu_decode_first_calls_total"][0][0].keys() \
+        == rows["minio_tpu_decode_first_call_seconds_total"][0][0].keys()
+    assert {lb["event"] for lb, _ in rows["minio_tpu_get_hedges_total"]} \
+        == {"reads", "wins", "losses"}
+    for name in ("minio_tpu_decode_pad_blocks_total", "minio_tpu_decode_host_blocks_total",
+                 "minio_tpu_fused_decode_failures_total"):
+        assert name in rows
+    # nothing of this process ever ran on the fused rung: its rows stand at zero
+    assert all(v == 0 for lb, v in rows["minio_tpu_decode_dispatches_total"]
+               if lb["rung"] == "fused")
+    assert "HELP minio_tpu_decode_first_calls_total" in first
+    assert "HELP minio_tpu_get_hedges_total" in first
+
+
+def test_a_decode_shape_counts_one_first_call_when_it_ends(served):
+    _, cli, *_ = served
+    rows = scrape(cli)
+    calls = {(lb["rung"], lb["missing"], lb["batch"]): v
+             for lb, v in rows["minio_tpu_decode_first_calls_total"] if v}
+    secs = {(lb["rung"], lb["missing"], lb["batch"]): v
+            for lb, v in rows["minio_tpu_decode_first_call_seconds_total"] if v}
+    # the cases above rebuilt one shard a block and more, in windows of 8
+    # blocks (fewer where a hedge split one), on the XLA rung; each shape was
+    # first met once however often it came back
+    assert any(k[:2] == ("xla", "1") for k in calls) and len({k[1] for k in calls}) >= 2
+    assert all(v == 1 for v in calls.values()) and set(secs) == set(calls)
+    assert total(rows, "minio_tpu_decode_dispatches_total", rung="xla") \
+        > len(calls) and total(rows, "minio_tpu_decode_dispatches_total", rung="fused") == 0
+
+
+def test_the_profiler_gets_the_leaves_and_no_enclosing_phase():
+    pytest.importorskip("jax")
+    for layer, name in (("decode", "kernel"), ("decode", "host"), ("get", "read_wait"),
+                        ("get", "join"), ("get", "respond")):
+        assert obs.phase(layer, name)._ann is not None, (layer, name)
+    # an enclosing phase would win every idle gap; the pool's threads would bury it
+    assert obs.phase("get", "decode_wait")._ann is None
+    assert obs.phase("get", "shard_io")._ann is None

@@ -222,10 +222,17 @@ def test_decode_rung_counters_are_exported(monkeypatch):
     from minio_tpu.ops import bitrot_jax
     from minio_tpu.server.metrics import _g_api_tpu
 
-    def series(name):
-        rows = [ln for ln in _g_api_tpu(None) if ln.startswith(name + "{")]
-        return {ln.split("{")[1].split("}")[0]: float(ln.rsplit(" ", 1)[1])
-                for ln in rows}
+    def series(name, by="rung"):
+        """{label value: the series summed over its other labels}: the
+        decode counters are split by `missing` too, and their sums over it
+        are what they were before they had that label."""
+        out: dict = {}
+        for ln in _g_api_tpu(None):
+            if ln.startswith(name + "{"):
+                labels = dict(kv.split("=") for kv in ln.split("{")[1].split("}")[0].split(","))
+                key = labels[by].strip('"')
+                out[key] = out.get(key, 0.0) + float(ln.rsplit(" ", 1)[1])
+        return out
 
     monkeypatch.setenv("MINIO_TPU_BACKEND", "jax")
     coder = ErasureCoder(4, 2)
@@ -239,19 +246,31 @@ def test_decode_rung_counters_are_exported(monkeypatch):
     ], axis=1)  # [t, w, per]
     present, missing = (1, 2, 3, 4), (0,)
     before = series("minio_tpu_decode_dispatches_total")
+    by_m = series("minio_tpu_decode_dispatches_total", by="missing")
+    host = series("minio_tpu_decode_host_blocks_total", by="family")
     rec = coder.reconstruct_data_flat(full[list(present)], present, missing)
     np.testing.assert_array_equal(rec[0], data[0])
     after = series("minio_tpu_decode_dispatches_total")
-    assert after['rung="xla"'] - before['rung="xla"'] == 1  # off-TPU rung
-    assert after['rung="fused"'] == before['rung="fused"']
+    assert after["xla"] - before["xla"] == 1  # off-TPU rung
+    assert after["fused"] == before["fused"]
+    now_m = series("minio_tpu_decode_dispatches_total", by="missing")
+    assert now_m["1"] - by_m["1"] == 1 and now_m["2"] == by_m["2"]
     blocks = series("minio_tpu_decode_device_blocks_total")
-    assert blocks['rung="xla"'] >= w
+    assert blocks["xla"] >= w
+    # two shards rebuilt at once: another row of the same series
+    rec2 = coder.reconstruct_data_flat(full[[2, 3, 4, 5]], (2, 3, 4, 5), (0, 1))
+    np.testing.assert_array_equal(rec2, data[:2])
+    assert series("minio_tpu_decode_dispatches_total", by="missing")["2"] == by_m["2"] + 1
+    after = series("minio_tpu_decode_dispatches_total")
+    assert series("minio_tpu_decode_host_blocks_total", by="family") == host
     # below the device floor the host rebuilds: no rung counter moves
     small = coder.reconstruct_data_flat(
         full[list(present)][:, :2], present, missing
     )
     np.testing.assert_array_equal(small[0], data[0, :2])
     assert series("minio_tpu_decode_dispatches_total") == after
+    moved = series("minio_tpu_decode_host_blocks_total", by="family")
+    assert moved["reedsolomon"] - host["reedsolomon"] == 2
     assert bitrot_jax.decode_stats_snapshot()["xla"] >= 1
 
 
