@@ -168,6 +168,66 @@ def verify_block(
     return block if view else bytes(block)
 
 
+def verify_run(
+    buf: bytes, lens, algo: BitrotAlgorithm = DEFAULT_BITROT_ALGO,
+    view: bool = False,
+) -> list:
+    """Split a run of consecutive reedsolomon frames (digest || block, the
+    blocks ``lens`` bytes long, read from the shard file in ONE piece) and
+    verify EVERY frame before any payload is handed out; returns the
+    payloads in order.
+
+    A run of one frame is ``verify_block``'s reedsolomon case. Raises
+    FileCorrupt on a short read or on any frame whose digest is wrong: the
+    whole run is then unusable to the caller, which reads those blocks
+    from another shard. The payloads hash where they were read — no copy
+    of the run first — and a stretch of equal-length frames in one native
+    call. ``view=True`` returns views of ``buf`` (which the views keep
+    alive), else bytes."""
+    if len(buf) != sum(lens) + DIGEST_SIZE * len(lens):
+        raise errors.FileCorrupt("short shard run")
+    mv = memoryview(buf)
+    offs = []  # of the payloads; a frame's digest ends where its payload starts
+    off = 0
+    for n in lens:
+        offs.append(off + DIGEST_SIZE)
+        off += DIGEST_SIZE + n
+    for k, dig in enumerate(_run_digests(mv, offs, lens, algo)):
+        if dig != mv[offs[k] - DIGEST_SIZE : offs[k]]:
+            raise errors.FileCorrupt(f"bitrot detected (frame {k} of run)")
+    out = [mv[o : o + n] for o, n in zip(offs, lens)]
+    return out if view else [bytes(b) for b in out]
+
+
+def _run_digests(mv: memoryview, offs, lens, algo: BitrotAlgorithm) -> list[bytes]:
+    """Digest of each payload ``mv[offs[k]:][:lens[k]]``; native
+    HighwayHash takes a stretch of equal-length frames in one strided call."""
+    from .. import native
+
+    if algo not in (
+        BitrotAlgorithm.HIGHWAYHASH256, BitrotAlgorithm.HIGHWAYHASH256S
+    ) or not native.available():
+        return [_digest(mv[o : o + n], algo) for o, n in zip(offs, lens)]
+    import numpy as np
+
+    from ..ops.highwayhash import MINIO_KEY
+
+    flat = np.frombuffer(mv, dtype=np.uint8)
+    digs: list[bytes] = []
+    i = 0
+    while i < len(lens):
+        j = i + 1
+        while j < len(lens) and lens[j] == lens[i]:
+            j += 1
+        digs += [
+            d.tobytes() for d in native.hh256_frames(
+                MINIO_KEY, flat, offs[i], DIGEST_SIZE + lens[i], lens[i], j - i
+            )
+        ]
+        i = j
+    return digs
+
+
 def verify_sub_chunk(
     buf: bytes, expect_len: int, algo: BitrotAlgorithm = DEFAULT_BITROT_ALGO
 ) -> bytes:

@@ -150,6 +150,24 @@ def _read_pool() -> ThreadPoolExecutor:
     return _READ_POOL
 
 
+# frames the reconstructing read path verified on the read pool, by the
+# unit of the read that held them: "run" inside a read of several
+# consecutive frames, "block" inside a read of one. Over the phase
+# table's `get`/`shard_io` calls they are frames per read
+_SHARD_FRAMES = {"run": 0, "block": 0}
+_SHARD_FRAMES_LOCK = threading.Lock()
+
+
+def _shard_frames_add(unit: str, n: int) -> None:
+    with _SHARD_FRAMES_LOCK:
+        _SHARD_FRAMES[unit] += n
+
+
+def shard_frames_snapshot() -> dict[str, int]:
+    with _SHARD_FRAMES_LOCK:
+        return dict(_SHARD_FRAMES)
+
+
 def default_parity_count(drive_count: int) -> int:
     """Default storage-class parity by set width (reference
     internal/config/storageclass defaults)."""
@@ -915,11 +933,14 @@ class ErasureSet:
         length: int,
         seg_sink=None,
     ) -> Iterator[bytes]:
-        """Windowed parallel striped read: per-shard reads fan out on a
-        thread pool (greedy data-first, parity spill on failure), whole
-        windows of same-pattern blocks reconstruct in ONE batched matrix
-        apply, and the next window's reads start before the current one is
-        decoded (readahead). Mirrors the reference's parallelReader +
+        """Windowed parallel striped read: a window's reads are planned
+        once, as runs — ONE read of each of the d shards it decodes from
+        (data first, the lowest parity standing in for what is missing)
+        covers the window's consecutive frames of a part — and fan out on
+        a thread pool (spill to the next shard on failure, hedge on
+        latency), whole windows of same-pattern blocks reconstruct in ONE
+        batched matrix apply, and the next window's reads start before the
+        current one is decoded (readahead). Mirrors the reference's parallelReader +
         readahead (/root/reference/cmd/erasure-decode.go:32,127-235,
         cmd/erasure-object.go:1429) but trades its per-block goroutine
         choreography for window-batched decode — the TPU-shaped version.
@@ -994,29 +1015,43 @@ class ErasureSet:
             the response: a view when zero-copy, bytes on the A/B path."""
             return memoryview(buf)[a:b] if zc else bytes(memoryview(buf)[a:b])
 
-        def read_shard_block(part_num: int, idx: int, per: int, f_off: int):
-            # the read pool's threads: the drive read and the frame's
+        def read_shard_run(part_num: int, idx: int, pers: tuple, f_off: int):
+            # the read pool's threads: the drive read and the frames'
             # bitrot verify, as `put`/`drive_io` is the write side's
             with obs.phase("get", "shard_io"):
-                return _read_shard_block(part_num, idx, per, f_off)
+                blks = _read_shard_run(part_num, idx, pers, f_off)
+            _shard_frames_add("run" if len(pers) > 1 else "block", len(pers))
+            return blks
 
-        def _read_shard_block(part_num: int, idx: int, per: int, f_off: int):
+        def _read_shard_run(part_num: int, idx: int, pers: tuple, f_off: int):
+            """ONE read of a shard's consecutive frames from `f_off` (their
+            blocks `pers` bytes long), every frame verified before its
+            payload is returned: the payloads, in order."""
             disk, m = sources[idx]
             wf = _whole_file_hash(m, part_num)
             if wf is not None:
+                (per,) = pers  # `runs_of` gives such a part single frames
                 block_i = f_off // (fdig + coder.shard_size)
                 data = read_whole_shard(idx, part_num, *wf)
                 blk = data[block_i * coder.shard_size:][:per]
                 if len(blk) != per:
                     raise errors.FileCorrupt("short whole-file shard")
-                return blk
+                return [blk]
+            nbytes = sum(pers) + fdig * len(pers)
             if m.inline_data:
-                buf = m.inline_data[f_off : f_off + fdig + per]
+                buf = m.inline_data[f_off : f_off + nbytes]
             else:
                 buf = disk.read_file(
-                    bucket, f"{obj}/{fi.data_dir}/part.{part_num}", f_off, fdig + per
+                    bucket, f"{obj}/{fi.data_dir}/part.{part_num}", f_off, nbytes
                 )
-            return bitrot_io.verify_block(buf, per, family=family, view=zc)
+            if family != bitrot_io.FAMILY_RS:
+                (per,) = pers  # two sub-frames a block: single frames too
+                return [bitrot_io.verify_block(buf, per, family=family, view=zc)]
+            return bitrot_io.verify_run(buf, pers, view=zc)
+
+        def read_shard_block(part_num: int, idx: int, per: int, f_off: int):
+            # the repair plan's full-frame reads: a run of one
+            return read_shard_run(part_num, idx, (per,), f_off)[0]
 
         def read_sub_chunk(
             part_num: int, idx: int, per: int, f_off: int, which: int
@@ -1242,25 +1277,63 @@ class ErasureSet:
         window = max(1, int(os.environ.get("MINIO_TPU_READ_WINDOW", "8")))
         hedge_budget = self._hedge_budget_s()
 
-        def start_window(win):
-            """Submit data-first reads for every block of the window."""
-            futs = {}
-            for bi, (pnum, per, f_off, _lo, _hi) in enumerate(win):
-                for idx in range(d):
-                    if idx in sources and idx not in bad:
-                        futs[(bi, idx)] = pool.submit(
-                            read_shard_block, pnum, idx, per, f_off
-                        )
-            return futs
+        single_frames: dict[int, bool] = {}
 
-        def gather_window(win, futs):
-            """Resolve reads until every block has d shards, spilling to
-            parity on FAILURE — and, when a straggling drive blows the
-            hedge budget, on LATENCY: extra parity reads race the
-            straggler and decode around it, whichever reaches d first
-            wins (the hedged-read policy; the reference instead pays the
-            straggler's full latency before spilling)."""
-            got: list[dict[int, bytes]] = [{} for _ in win]
+        def reads_single_frames(pnum: int) -> bool:
+            """A part whose frames are not `digest || block` on a drive —
+            sub-packetized (two sub-frames a block), inline in xl.meta,
+            or legacy raw shards under one whole-file digest — is read
+            a block at a time: its runs are one frame long."""
+            if pnum not in single_frames:
+                single_frames[pnum] = family != bitrot_io.FAMILY_RS or any(
+                    m.inline_data or _whole_file_hash(m, pnum) is not None
+                    for _disk, m in sources.values()
+                )
+            return single_frames[pnum]
+
+        def runs_of(win) -> list[tuple[int, int, tuple, list[int]]]:
+            """The window's blocks as runs of consecutive frames of one
+            part: (part#, first frame's offset, block lengths, the blocks'
+            places in the window). A shard file holds a part's frames end
+            to end, so a run is ONE read of each shard; a part boundary
+            or a range's edge starts the next run."""
+            runs: list = []
+            nxt = None
+            for bi, (pnum, per, f_off, _lo, _hi) in enumerate(win):
+                if (runs and (pnum, f_off) == nxt
+                        and not reads_single_frames(pnum)):
+                    runs[-1][2].append(per)
+                    runs[-1][3].append(bi)
+                else:
+                    runs.append((pnum, f_off, [per], [bi]))
+                nxt = (pnum, f_off + fdig + per)
+            return [(p, o, tuple(pers), bis) for p, o, pers, bis in runs]
+
+        def start_window(win):
+            """Plan the window's reads once, as runs, and submit them all:
+            for each run the d shards it decodes from — data shards first,
+            then the lowest parity shards standing in for those with no
+            source or marked bad — one pool task a shard."""
+            runs = runs_of(win)
+            picked = [
+                i for i in range(self.n) if i in sources and i not in bad
+            ][:d]
+            futs = {
+                (ri, idx): pool.submit(read_shard_run, pnum, idx, pers, f_off)
+                for ri, (pnum, f_off, pers, _bis) in enumerate(runs)
+                for idx in picked
+            }
+            return runs, futs
+
+        def gather_window(win, runs, futs):
+            """Resolve reads until every run has d shards, spilling to
+            the next candidate shard on FAILURE — and, when a straggling
+            drive blows the hedge budget, on LATENCY: extra parity reads
+            race the straggler and decode around it, whichever reaches d
+            first wins (the hedged-read policy; the reference instead
+            pays the straggler's full latency before spilling). A run's
+            blocks share their reads, so they share their shards."""
+            got: list[dict[int, list]] = [{} for _ in runs]
             pending: dict[tuple[int, int], object] = dict(futs)
             rev = {f: k for k, f in pending.items()}
             hedged_idx: set[int] = set()
@@ -1272,22 +1345,22 @@ class ErasureSet:
                 if hedge_budget is not None else None
             )
 
-            def submit_more(bi: int, racing: bool) -> int:
-                """Spill reads for block bi so results (+ inflight unless
+            def submit_more(ri: int, racing: bool) -> int:
+                """Spill reads for run ri so results (+ inflight unless
                 `racing`) can reach d; hedge submissions race stragglers
                 instead of counting them."""
-                inflight = [k[1] for k in pending if k[0] == bi]
-                have = len(got[bi]) + (0 if racing else len(inflight))
-                tried = set(got[bi]) | bad | set(inflight)
+                inflight = [k[1] for k in pending if k[0] == ri]
+                have = len(got[ri]) + (0 if racing else len(inflight))
+                tried = set(got[ri]) | bad | set(inflight)
                 cands = [
                     i for i in range(self.n) if i in sources and i not in tried
                 ]
                 n_sub = 0
-                pnum, per, f_off, _lo, _hi = win[bi]
+                pnum, f_off, pers, _bis = runs[ri]
                 for idx in cands[: max(d - have, 0)]:
-                    f = pool.submit(read_shard_block, pnum, idx, per, f_off)
-                    pending[(bi, idx)] = f
-                    rev[f] = (bi, idx)
+                    f = pool.submit(read_shard_run, pnum, idx, pers, f_off)
+                    pending[(ri, idx)] = f
+                    rev[f] = (ri, idx)
                     if racing:
                         hedged_idx.add(idx)
                     n_sub += 1
@@ -1295,18 +1368,18 @@ class ErasureSet:
 
             try:
                 while any(len(g) < d for g in got):
-                    # keep every deficient block able to reach d (failure
+                    # keep every deficient run able to reach d (failure
                     # spill)
-                    for bi in range(len(win)):
-                        if len(got[bi]) >= d:
+                    for ri in range(len(runs)):
+                        if len(got[ri]) >= d:
                             continue
-                        inflight = sum(1 for k in pending if k[0] == bi)
-                        if len(got[bi]) + inflight < d:
-                            if submit_more(bi, False) == 0 and inflight == 0:
-                                pnum, _per, f_off, _lo, _hi = win[bi]
+                        inflight = sum(1 for k in pending if k[0] == ri)
+                        if len(got[ri]) + inflight < d:
+                            if submit_more(ri, False) == 0 and inflight == 0:
+                                pnum, f_off, _pers, _bis = runs[ri]
                                 raise QuorumError(
                                     f"cannot read part {pnum} shard offset "
-                                    f"{f_off}: only {len(got[bi])} of {d} "
+                                    f"{f_off}: only {len(got[ri])} of {d} "
                                     "shards"
                                 )
                     if not pending:
@@ -1323,8 +1396,8 @@ class ErasureSet:
                         # parity-decode of the remaining shards against them
                         hedge_fired = True
                         fired = sum(
-                            submit_more(bi, True)
-                            for bi in range(len(win)) if len(got[bi]) < d
+                            submit_more(ri, True)
+                            for ri in range(len(runs)) if len(got[ri]) < d
                         )
                         if fired:
                             fault_registry.stats_add("hedge_reads")
@@ -1338,10 +1411,10 @@ class ErasureSet:
                             deadline = None  # nothing left to hedge with
                         continue
                     for f in done:
-                        bi, idx = rev.pop(f)
-                        del pending[(bi, idx)]
+                        ri, idx = rev.pop(f)
+                        del pending[(ri, idx)]
                         try:
-                            got[bi][idx] = f.result()
+                            got[ri][idx] = f.result()
                         except (errors.FileCorrupt, errors.FileNotFound,
                                 errors.DiskNotFound, errors.DiskFull,
                                 errors.VolumeNotFound, OSError):
@@ -1351,7 +1424,9 @@ class ErasureSet:
                             # VolumeNotFound a bucket that vanished under
                             # a cached-metadata read: the drive is a
                             # failed shard to spill around, not a reason
-                            # to fail a GET that still has quorum
+                            # to fail a GET that still has quorum. One bad
+                            # frame fails its run's read: the run's blocks,
+                            # and no others, go to the next shard
                             bad.add(idx)
                             report_degraded()
             finally:
@@ -1361,7 +1436,7 @@ class ErasureSet:
                 for f in pending.values():
                     f.cancel()
             # window satisfied: settle the hedge bet (win = a hedged
-            # shard ended up in some block's decode set)
+            # shard ended up in some run's decode set)
             if hedged_idx:
                 used: set[int] = set()
                 for g in got:
@@ -1369,7 +1444,13 @@ class ErasureSet:
                 fault_registry.stats_add(
                     "hedge_wins" if used & hedged_idx else "hedge_losses"
                 )
-            return got
+            # per block, as `decode_window` takes them
+            by_block: list[dict[int, bytes]] = [{} for _ in win]
+            for (_pnum, _f_off, _pers, bis), g in zip(runs, got):
+                for idx, blks in g.items():
+                    for bi, blk in zip(bis, blks):
+                        by_block[bi][idx] = blk
+            return by_block
 
         def decode_window(win, got) -> list:
             """Per-block data buffers; same-pattern degraded blocks batch.
@@ -1774,7 +1855,7 @@ class ErasureSet:
 
         # ---- pipelined execution: window k+1 reads under window k decode ----
         windows = [plan[i : i + window] for i in range(0, len(plan), window)]
-        futs = start_window(windows[0]) if windows else {}
+        runs, futs = start_window(windows[0]) if windows else ([], {})
         starting.book()
         # yield -> resumption: the front end's write and its executor hop
         responding = obs.PhaseClock("get", "respond")
@@ -1783,10 +1864,10 @@ class ErasureSet:
                 # the window's reads were submitted as the last one's
                 # readahead: what is left of them is what a GET waits for
                 with obs.phase("get", "read_wait", blocks=len(win)):
-                    got = gather_window(win, futs)
+                    got = gather_window(win, runs, futs)
                 futs = {}
                 if wi + 1 < len(windows):
-                    futs = start_window(windows[wi + 1])  # readahead
+                    runs, futs = start_window(windows[wi + 1])  # readahead
                 blocks = decode_window(win, got)
                 for (pnum, per, f_off, lo, hi), block in zip(win, blocks):
                     if seg_sink is not None:

@@ -130,6 +130,22 @@ def hh256_batch(key: bytes, blocks: np.ndarray) -> np.ndarray:
     return out
 
 
+def hh256_frames(
+    key: bytes, buf: np.ndarray, first: int, stride: int, n: int, count: int
+) -> np.ndarray:
+    """Digests of `count` spans of `n` bytes that start `stride` bytes apart
+    in ONE flat uint8 buffer, the first at `first` -> [count, 32]. The
+    payloads of a run of digest||block frames hash where they were read,
+    in one GIL-releasing call."""
+    lib = _load()
+    if first < 0 or count < 1 or first + (count - 1) * stride + n > buf.size:
+        raise ValueError("frames reach past the buffer")
+    out = np.empty((count, 32), dtype=np.uint8)
+    karr = np.frombuffer(key, dtype=np.uint8)
+    lib.hh256_batch(_ptr(karr), _ptr(buf[first:]), stride, n, count, _ptr(out))
+    return out
+
+
 class DataplanePut:
     """Streaming native PUT: feed raw bytes, shards land framed on disk.
 

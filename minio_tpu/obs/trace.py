@@ -238,7 +238,7 @@ def span(trace_type: str, name: str, **fields):
 # per-block gather-join copy), `cache_fill` (the block offered to the
 # range-segment cache), `respond` (yield -> resumption: the front end's
 # write and the executor hop; wall only, the thread may change), and
-# `shard_io` on the read pool's threads (read_file + verify_block).
+# `shard_io` on the read pool's threads (a run of frames: read_file + verify_run).
 # `decode` phases are the leaves of one device reconstruct
 # (ops/bitrot_jax.py, erasure/coder.py): `pad` (survivors made
 # block-major and zero-padded to the kernel's batch), `pack`, `h2d`,

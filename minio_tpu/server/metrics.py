@@ -1082,6 +1082,15 @@ def _g_api_tpu(server) -> list[str]:
          "straggler budget (reads), and how the bet ended: a hedged shard "
          "in some block's decode set (wins) or none (losses); the "
          "minio_fault_hedge_* series of /api/fault")
+    # the unit of a shard read on that path: frames over the phase
+    # table's `get`/`shard_io` calls are frames per read
+    from ..erasure.set import shard_frames_snapshot
+
+    _fmt(out, "minio_tpu_get_shard_frames_total", "counter",
+         [({"unit": u}, n) for u, n in sorted(shard_frames_snapshot().items())],
+         "Shard frames the reconstructing read path verified, by the read "
+         "that held them: one of several consecutive frames of a shard "
+         "file (run) or of a single frame (block)")
     # device runtime (ops/runtime.py): which device this process holds and
     # what it compiled vs loaded from the persistent compile cache; zeros
     # and no device row on a CPU-plane process

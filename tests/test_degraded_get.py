@@ -136,6 +136,7 @@ def test_the_phases_tile_a_degraded_get(served):
     take_offline(cli, drives, (3, 11))
     try:
         assert cli.request("GET", f"/{BUCKET}/{KEY}").body == body  # its kernel compiles here
+        time.sleep(0.1)  # its generator books its last `respond` after the body is out
         before = obs.phases_snapshot()
         t0 = time.monotonic()
         for _ in range(3):
@@ -143,6 +144,7 @@ def test_the_phases_tile_a_degraded_get(served):
         wall = time.monotonic() - t0
     finally:
         fault.clear()
+    time.sleep(0.1)  # the last GET's last `respond`, as above
     after = obs.phases_snapshot()
     moved = {k: tuple(a - b for a, b in zip(after[k], before[k])) for k in after}
     assert moved["get", "start"][2] == 3                    # once per GET
@@ -150,7 +152,9 @@ def test_the_phases_tile_a_degraded_get(served):
     assert moved["get", "decode_wait"][2] == moved["get", "stack"][2] >= 3
     assert moved["get", "join"][2] == 3 * 8 == moved["get", "respond"][2]
     assert moved["get", "cache_fill"][2] == 3 * 8
-    assert moved["get", "shard_io"][2] >= 3 * 8 * 8 and moved["get", "shard_io"][1] > 0
+    # a window's reads are runs: one read of each of the d shards it decodes
+    # from (8 frames each), and a hedge that fires adds at most as many
+    assert 3 * 8 <= moved["get", "shard_io"][2] <= 3 * 16 and moved["get", "shard_io"][1] > 0
     leaves = sum(moved["decode", p][0] for p in obs.PHASES["decode"])
     decode_wait = moved["get", "decode_wait"][0]
     # (on the chip the leaves hold 86-87 % of it, PERF.md §5; a loaded test
