@@ -32,8 +32,7 @@ docs:
 # Then every named workload profile at toy scale, each with its real
 # gates armed (a missing gate series fails the run, never passes it) —
 # --all includes repair-degraded-storm, the seeded drive-failure +
-# straggler storm with verifying traffic and the windowed-vs-serial
-# repair A/B.
+# straggler storm with verifying traffic and the heal-ingress bound.
 bench-smoke:
 	MINIO_TPU_BACKEND=numpy $(PY) benchmarks/bench_load.py --quick
 	MINIO_TPU_BACKEND=numpy $(PY) -m benchmarks.scenarios --all --quick

@@ -30,9 +30,8 @@ The four profiles:
   fault schedule under verifying zipf traffic over a hive-partitioned
   keyspace while a heal flood runs. Gated on degraded-GET p99 within a
   declared band of healthy p99, zero wrong bytes anywhere, the
-  BENCH_r09 cauchy-ingress bound (<= 0.75x rs, controlled synthetic),
-  and windowed repair beating the block-serial baseline wall-clock
-  under a seeded per-read straggler.
+  and the BENCH_r09 cauchy-ingress bound (<= 0.75x rs, controlled
+  synthetic).
 """
 
 from __future__ import annotations
@@ -737,100 +736,52 @@ def _wipe_drive_bucket(base: str, idx: int) -> int:
     return dropped
 
 
-def _synthetic_repair_ab(spec: dict) -> dict:
+def _synthetic_heal_ingress(spec: dict) -> dict:
     """In-process SYNTHETIC measurement (no server, labelled as such in
     the output): the controlled single-lost-DATA-shard case the BENCH_r09
-    ingress bound is defined over, plus the windowed-vs-block-serial
-    repair wall-clock A/B under a seeded +straggler-per-shard-read
-    schedule. In-process because both need per-object control the wire
-    API doesn't expose: choosing WHICH shard is lost (data shard 0, the
-    apples-to-apples repair-plan case — a whole-drive wipe mixes parity
-    losses in, which repair_schedule correctly refuses) and flipping
-    MINIO_TPU_REPAIR_WINDOWED between otherwise-identical reads."""
-    from minio_tpu import fault
+    ingress bound is defined over — survivor bytes a heal moves, per
+    family. A byte count, which a CPU run may report. In-process because
+    it needs per-object control the wire API doesn't expose: choosing
+    WHICH shard is lost (data shard 0, the apples-to-apples repair-plan
+    case — a whole-drive wipe mixes parity losses in, which
+    repair_schedule correctly refuses)."""
     from minio_tpu.erasure.set import ErasureSet
-    from minio_tpu.fault.storage import FaultInjectedDisk
-    from minio_tpu.storage.health import HealthCheckedDisk
     from minio_tpu.storage.xlstorage import XLStorage
 
-    def rig(base: str, tag: str) -> ErasureSet:
-        # production wrap order: faults inject UNDER the breaker, so the
-        # straggler schedule feeds the same EWMA the hedge budget reads
-        es = ErasureSet(
-            [HealthCheckedDisk(FaultInjectedDisk(
-                XLStorage(os.path.join(base, tag, f"d{i}"))))
-             for i in range(16)],
-            default_parity=8,
-        )
-        es.make_bucket("fam")
-        return es
-
-    def drain(it) -> bytes:
-        return b"".join(bytes(c) for c in it)
-
-    def lose_data_shard0(base: str, tag: str, es: ErasureSet) -> None:
-        fi, _ = es._cached_fileinfo("fam", "o", "")
-        lost = fi.erasure.distribution.index(1)  # data shard 0's drive
-        shutil.rmtree(os.path.join(base, tag, f"d{lost}", "fam", "o"))
-        es.cache.clear()
-
     saved = {k: os.environ.get(k) for k in (
-        "MINIO_TPU_EC_FAMILY", "MINIO_TPU_NATIVE_PLANE",
-        "MINIO_TPU_REPAIR_WINDOWED")}
-    base = tempfile.mkdtemp(prefix="repair-ab-")
+        "MINIO_TPU_EC_FAMILY", "MINIO_TPU_NATIVE_PLANE", "MINIO_TPU_HEDGE")}
+    base = tempfile.mkdtemp(prefix="heal-ingress-")
     try:
         os.environ["MINIO_TPU_NATIVE_PLANE"] = "0"
-        body = tbody("ab", 0, spec["ab_mib"] * MIB)
-
-        # -- ingress bound: single lost data shard, heal per family -----
+        # the plan's bytes: a hedge on a slow host would add the
+        # fallback's full frames to the cauchy side
+        os.environ["MINIO_TPU_HEDGE"] = "0"
+        body = tbody("ab", 0, spec["ingress_mib"] * MIB)
         ingress: dict[str, int] = {}
         for fam in ("reedsolomon", "cauchy"):
             os.environ["MINIO_TPU_EC_FAMILY"] = fam
-            es = rig(base, fam)
+            es = ErasureSet(
+                [XLStorage(os.path.join(base, fam, f"d{i}"))
+                 for i in range(16)],
+                default_parity=8,
+            )
+            es.make_bucket("fam")
             es.put_object("fam", "o", body)
-            lose_data_shard0(base, fam, es)
+            fi, _ = es._cached_fileinfo("fam", "o", "")
+            lost = fi.erasure.distribution.index(1)  # data shard 0's drive
+            shutil.rmtree(os.path.join(base, fam, f"d{lost}", "fam", "o"))
+            es.cache.clear()
             res = es.heal_object("fam", "o")
             assert res["healed"], f"{fam} heal failed: {res}"
             ingress[fam] = res["ingressBytes"]
-
-        # -- wall clock: windowed vs block-serial degraded GET ----------
-        os.environ["MINIO_TPU_EC_FAMILY"] = "cauchy"
-        es = rig(base, "ab")
-        es.put_object("fam", "o", body)
-        lose_data_shard0(base, "ab", es)
-        fault.inject({
-            "boundary": "storage", "mode": "latency", "op": "read_file",
-            "latency_ms": spec["ab_straggler_ms"], "seed": 42,
-        })
-        walls: dict[str, list[float]] = {"windowed": [], "serial": []}
-        modes = (("windowed", "1"), ("serial", "0"))
-        for mode, env in modes:  # warm decode matrices etc., unmeasured
-            os.environ["MINIO_TPU_REPAIR_WINDOWED"] = env
-            es.cache.clear()
-            _, it = es.get_object("fam", "o")
-            assert drain(it) == body, f"warmup {mode}: wrong bytes"
-        for _ in range(spec["ab_trials"]):
-            for mode, env in modes:  # interleaved: drift washes out
-                os.environ["MINIO_TPU_REPAIR_WINDOWED"] = env
-                es.cache.clear()  # every trial re-reads the drives
-                t0 = time.perf_counter()
-                _, it = es.get_object("fam", "o")
-                got = drain(it)
-                walls[mode].append(time.perf_counter() - t0)
-                assert got == body, f"{mode} repair served wrong bytes"
         return {
             "label": "synthetic-in-process",
-            "object_mib": spec["ab_mib"],
+            "object_mib": spec["ingress_mib"],
             "heal_ingress_bytes": ingress,
             "cauchy_over_rs_ingress": round(
                 ingress["cauchy"] / max(ingress["reedsolomon"], 1), 4),
-            "ab_trials": spec["ab_trials"],
-            "ab_straggler_ms_per_read": spec["ab_straggler_ms"],
-            "degraded_get_wall_ms": {
-                m: round(median(w) * 1e3, 2) for m, w in walls.items()},
         }
     finally:
-        fault.clear()
         for k, v in saved.items():
             if v is None:
                 os.environ.pop(k, None)
@@ -912,14 +863,13 @@ async def repair_storm_phase(ctx: Ctx) -> dict:
             scrape_series, ctx.port, "/api/tpu",
             "minio_heal_ingress_bytes_total")
 
-    synth = await asyncio.to_thread(_synthetic_repair_ab, spec)
+    synth = await asyncio.to_thread(_synthetic_heal_ingress, spec)
 
     healthy_s = healthy.summary(healthy.wall)
     storm_sum = storm.summary(storm.wall)
     p99_h = healthy_s["per_class"].get("HGET", {}).get("p99_ms", 0.0)
     p99_d = storm_sum["per_class"].get("DGET", {}).get("p99_ms", 0.0)
     deltas = {s: c1[s] - c0[s] for _, s in REPAIR_GATE_SERIES}
-    walls = synth["degraded_get_wall_ms"]
 
     out = {
         "objects": n,
@@ -963,11 +913,6 @@ async def repair_storm_phase(ctx: Ctx) -> dict:
         failures.append(
             f"cauchy heal ingress {ratio:.3f}x rs > "
             f"{spec['ingress_ratio_max']} (BENCH_r09 bound regressed)")
-    if walls["windowed"] >= walls["serial"]:
-        failures.append(
-            f"windowed repair {walls['windowed']}ms did not beat "
-            f"block-serial {walls['serial']}ms under "
-            f"+{spec['ab_straggler_ms']}ms/shard-read straggler")
     if sweeps == 0:
         failures.append("heal flood swept nothing (vacuous storm)")
     out["gates_passed"] = not failures
@@ -1088,7 +1033,7 @@ PROFILES: dict[str, Profile] = {p.name: p for p in [
         name="repair-degraded-storm",
         summary="seeded drive failure + stragglers under verifying "
                 "traffic + heal flood; p99 band, zero wrong bytes, "
-                "cauchy ingress bound, windowed beats serial repair",
+                "cauchy ingress bound",
         drives=16,  # EC 8+8: every object stripes across all drives
         workers=1,  # fault registry + counters live per-process
         scan_interval=300.0,
@@ -1108,7 +1053,7 @@ PROFILES: dict[str, Profile] = {p.name: p for p in [
             "error_prob": 0.08,
             "p99_band_mult": 30.0, "p99_floor_ms": 600.0,
             "ingress_ratio_max": 0.75,
-            "ab_trials": 5, "ab_mib": 2, "ab_straggler_ms": 1.5,
+            "ingress_mib": 2,
         },
         full_spec={
             "objects": 96, "object_kb": 256, "clients": 24,
@@ -1118,7 +1063,7 @@ PROFILES: dict[str, Profile] = {p.name: p for p in [
             "error_prob": 0.08,
             "p99_band_mult": 12.0, "p99_floor_ms": 500.0,
             "ingress_ratio_max": 0.75,
-            "ab_trials": 5, "ab_mib": 8, "ab_straggler_ms": 1.5,
+            "ingress_mib": 8,
         },
         phase=repair_storm_phase,
     ),

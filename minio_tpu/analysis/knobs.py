@@ -127,13 +127,6 @@ _ALL: list[Knob] = [
        "repair schedule's sub-chunk frames instead of full survivor "
        "shards. 0 forces full-shard reads (correctness never depends "
        "on this — it is purely the repair-bandwidth optimization)."),
-    _k("MINIO_TPU_REPAIR_WINDOWED", "1", "erasure",
-       "Windowed + hedged execution of partial-repair plans (degraded "
-       "GET and heal): a window of blocks' sub-chunk reads issues "
-       "concurrently with next-window readahead, and straggling or "
-       "failed helpers degrade per BLOCK to the generic gather. 0 "
-       "falls back to the block-serial baseline (the A/B lever the "
-       "repair-degraded-storm wall-clock gate measures against)."),
     _k("MINIO_TPU_DECODE_MATRIX_CACHE", "256", "erasure",
        "Entries in the decode-matrix LRU shared by the code families "
        "(ops/decode_cache.py): GF inverses keyed by (family, d, p, "

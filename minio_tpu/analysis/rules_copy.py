@@ -36,6 +36,7 @@ from .core import Finding, FunctionStackVisitor, rule
 # files whose function bodies count as data-plane hot path
 _HOT_PATH_GLOBS = (
     "erasure/set.py",
+    "erasure/shardread.py",
     "erasure/coder.py",
     "parallel/dispatcher.py",
 )
@@ -50,10 +51,12 @@ COPY_BOUNDARY: dict[str, set[str]] = {
     # zero-copy uint8 views for the decode kernels; the heal plane's
     # tobytes feeds the bitrot re-framing writer (cold path, per-object)
     "erasure/set.py": {
-        "read_sub_chunk", "repair_read_block", "decode_window",
-        "assemble_repair", "read_sub", "assemble", "finish_fb",
-        "repair_part_windowed", "_heal_object_locked",
+        "decode_window", "lost_from_frames", "repair_part",
+        "_heal_object_locked",
     },
+    # the shard reader's sub-chunk frames and the repair plan's full
+    # frames, wrapped as uint8 views for the repair kernels
+    "erasure/shardread.py": {"sub_chunk", "repair_shard"},
     # the dispatcher assembles into pooled bucket arenas; no
     # materialization site is legitimate there
     "parallel/dispatcher.py": set(),

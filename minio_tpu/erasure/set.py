@@ -5,7 +5,9 @@ Behavioral mirror of the reference's erasureObjects
 rename-into-place, greedy degraded reads with bitrot verification and
 on-the-fly reconstruction, versioned deletes with delete markers, and
 object healing. Compute (RS encode/decode + bitrot digests) rides the
-TPU coder (erasure/coder.py).
+TPU coder (erasure/coder.py). Where a shard's bytes are and how they are
+verified, and how a partial-repair plan's reads race their fallback, are
+erasure/shardread.py's, shared by GET and heal.
 """
 
 from __future__ import annotations
@@ -14,7 +16,7 @@ import hashlib
 import os
 import threading
 import uuid
-from concurrent.futures import ALL_COMPLETED, FIRST_COMPLETED, ThreadPoolExecutor
+from concurrent.futures import FIRST_COMPLETED, ThreadPoolExecutor
 from concurrent.futures import wait as _fut_wait
 from typing import Callable, Iterator
 
@@ -35,7 +37,7 @@ from ..storage.datatypes import (
 from ..storage.format import INLINE_DATA_THRESHOLD
 from ..storage.interface import StorageAPI
 from ..utils.hashing import hash_order
-from . import bitrot_io, bufpool
+from . import bitrot_io, bufpool, shardread
 from .coder import (
     BLOCK_SIZE,
     ErasureCoder,
@@ -88,19 +90,6 @@ def _lock_dyn(mtx, write: bool = True) -> bool:
 TAGS_META_KEY = "x-minio-internal-tags"
 
 
-def _whole_file_hash(m: "FileInfo", part_number: int):
-    """This drive's stored (digest, algorithm) for a part, or None when the
-    shard uses the streaming format (reference cmd/bitrot-whole.go: legacy
-    shards carry one metadata digest instead of interleaved frames). The
-    stored algorithm matters: legacy data may be sha256/blake2b hashed."""
-    from ..ops.bitrot import algorithm_from_string
-
-    for c in m.erasure.checksums:
-        if c.part_number == part_number and c.hash:
-            return c.hash, algorithm_from_string(c.algorithm)
-    return None
-
-
 def _native_plane_enabled(device_active: bool = False) -> bool:
     """Native C++ streaming data plane (native/dataplane.cpp): used for the
     PUT/GET hot path whenever every target drive is local. One GIL-releasing
@@ -120,13 +109,6 @@ def _native_plane_enabled(device_active: bool = False) -> bool:
     from .. import native
 
     return native.dataplane_available()
-
-def _repair_windowed_enabled() -> bool:
-    """MINIO_TPU_REPAIR_WINDOWED gates the windowed + hedged execution of
-    partial-repair plans (degraded GET and heal). "0" keeps the original
-    block-serial executor — the A/B baseline the BENCH_r12 wall-clock
-    gate measures against; correctness is identical either way."""
-    return os.environ.get("MINIO_TPU_REPAIR_WINDOWED", "1") != "0"
 
 
 # shared shard-read pool: per-block shard reads of ALL in-flight GETs fan
@@ -945,6 +927,10 @@ class ErasureSet:
         cmd/erasure-object.go:1429) but trades its per-block goroutine
         choreography for window-batched decode — the TPU-shaped version.
         Spans multiple parts (each part is its own erasure stream).
+        Elsewhere: the reads themselves (`ShardReader.run`) and the
+        partial-repair plan's executor are erasure/shardread.py's; what
+        is here is the plan of blocks, the native span path, and the
+        healthy/reconstructing windowed pipeline.
 
         ``seg_sink(part#, block#, block_bytes)``: every stripe block the
         read fully materializes (verified + decoded) is offered to the
@@ -975,35 +961,6 @@ class ErasureSet:
         if len(sources) < self.n:
             report_degraded()  # some drive lacks this version entirely
 
-        # legacy whole-file shards: raw bytes on disk, one digest in the
-        # drive's metadata; read+verify the whole shard once per part.
-        # Futures memoize the load so the read pool's concurrent blocks
-        # share ONE read+hash instead of racing past a bare dict check.
-        from concurrent.futures import Future
-
-        whole_cache: dict[tuple[int, int], Future] = {}
-        whole_lock = threading.Lock()
-
-        def read_whole_shard(idx: int, part_num: int, wh, algo) -> bytes:
-            k = (idx, part_num)
-            with whole_lock:
-                fut = whole_cache.get(k)
-                owner = fut is None
-                if owner:
-                    fut = whole_cache[k] = Future()
-            if owner:
-                try:
-                    disk, m = sources[idx]
-                    raw = m.inline_data if m.inline_data else disk.read_file(
-                        bucket, f"{obj}/{fi.data_dir}/part.{part_num}", 0, -1
-                    )
-                    fut.set_result(
-                        bitrot_io.verify_whole_file(bytes(raw), wh, algo)
-                    )
-                except Exception as e:  # noqa: BLE001 — typed via the future
-                    fut.set_exception(e)
-            return fut.result()
-
         # zero-copy gather: verified shard payloads flow as views of the
         # read buffer (reedsolomon frames; cauchy's interleaved digests
         # make its one assembly copy inherent), and blocks assemble ONCE
@@ -1015,62 +972,17 @@ class ErasureSet:
             the response: a view when zero-copy, bytes on the A/B path."""
             return memoryview(buf)[a:b] if zc else bytes(memoryview(buf)[a:b])
 
+        reader = shardread.ShardReader(
+            bucket, obj, fi, coder, sources, view=zc
+        )
+
         def read_shard_run(part_num: int, idx: int, pers: tuple, f_off: int):
             # the read pool's threads: the drive read and the frames'
             # bitrot verify, as `put`/`drive_io` is the write side's
             with obs.phase("get", "shard_io"):
-                blks = _read_shard_run(part_num, idx, pers, f_off)
+                blks = reader.run(part_num, idx, pers, f_off)
             _shard_frames_add("run" if len(pers) > 1 else "block", len(pers))
             return blks
-
-        def _read_shard_run(part_num: int, idx: int, pers: tuple, f_off: int):
-            """ONE read of a shard's consecutive frames from `f_off` (their
-            blocks `pers` bytes long), every frame verified before its
-            payload is returned: the payloads, in order."""
-            disk, m = sources[idx]
-            wf = _whole_file_hash(m, part_num)
-            if wf is not None:
-                (per,) = pers  # `runs_of` gives such a part single frames
-                block_i = f_off // (fdig + coder.shard_size)
-                data = read_whole_shard(idx, part_num, *wf)
-                blk = data[block_i * coder.shard_size:][:per]
-                if len(blk) != per:
-                    raise errors.FileCorrupt("short whole-file shard")
-                return [blk]
-            nbytes = sum(pers) + fdig * len(pers)
-            if m.inline_data:
-                buf = m.inline_data[f_off : f_off + nbytes]
-            else:
-                buf = disk.read_file(
-                    bucket, f"{obj}/{fi.data_dir}/part.{part_num}", f_off, nbytes
-                )
-            if family != bitrot_io.FAMILY_RS:
-                (per,) = pers  # two sub-frames a block: single frames too
-                return [bitrot_io.verify_block(buf, per, family=family, view=zc)]
-            return bitrot_io.verify_run(buf, pers, view=zc)
-
-        def read_shard_block(part_num: int, idx: int, per: int, f_off: int):
-            # the repair plan's full-frame reads: a run of one
-            return read_shard_run(part_num, idx, (per,), f_off)[0]
-
-        def read_sub_chunk(
-            part_num: int, idx: int, per: int, f_off: int, which: int
-        ) -> np.ndarray:
-            """Partial-repair read unit: ONE digest||sub-chunk frame of a
-            sub-packetized shard block (the other half never moves)."""
-            disk, m = sources[idx]
-            rel, dlen = bitrot_io.sub_chunk_in_block(per, which)
-            off = f_off + rel
-            if m.inline_data:
-                buf = m.inline_data[off : off + DIGEST + dlen]
-            else:
-                buf = disk.read_file(
-                    bucket, f"{obj}/{fi.data_dir}/part.{part_num}",
-                    off, DIGEST + dlen,
-                )
-            return np.frombuffer(
-                bitrot_io.verify_sub_chunk(bytes(buf), dlen), dtype=np.uint8
-            )
 
         # ---- partial-repair plan: sub-packetized family, exactly one ----
         # data shard gone, every helper present — degraded reads fetch
@@ -1088,79 +1000,6 @@ class ErasureSet:
                     h in sources for h in sched.helpers
                 ):
                     repair_sched = sched
-
-        def repair_read_block(
-            pnum: int, per: int, f_off: int, lo: int, hi: int
-        ):
-            """Serve [lo, hi) of one stripe block under the repair plan:
-            full frames only for the data shards the range needs, the
-            schedule's sub-chunk frames to rebuild the lost one."""
-            i_m = repair_sched.missing
-            lo_sh, hi_sh = lo // per, (hi - 1) // per
-            needed = list(range(lo_sh, min(hi_sh, d - 1) + 1))
-            ingress = 0
-            full_idx = set(idx for idx in needed if idx != i_m)
-            if i_m in needed:
-                # every group mate is also a b_helper, so it needs BOTH
-                # sub-chunks — one contiguous frame-group read moves the
-                # same bytes as two sub-chunk reads with half the
-                # round-trips
-                full_idx.update(repair_sched.mates)
-            full_futs = {
-                idx: pool.submit(read_shard_block, pnum, idx, per, f_off)
-                for idx in full_idx
-            }
-            sub_futs = {}
-            if i_m in needed:
-                for r in repair_sched.b_helpers:
-                    if r not in full_futs:
-                        sub_futs[(r, 1)] = pool.submit(
-                            read_sub_chunk, pnum, r, per, f_off, 1
-                        )
-                sub_futs[(repair_sched.pb_parity, 1)] = pool.submit(
-                    read_sub_chunk, pnum, repair_sched.pb_parity, per, f_off, 1
-                )
-            try:
-                got_full = {
-                    idx: np.frombuffer(f.result(), dtype=np.uint8)
-                    for idx, f in full_futs.items()
-                }
-            except BaseException:
-                # a failed full read fails the plan (caller falls back to
-                # the generic gather): don't leave sub-chunk reads queued
-                for f in sub_futs.values():
-                    f.cancel()
-                raise
-            if i_m in needed:
-                # same semantics as the generic path's counter: EVERY
-                # frame fetched for a block that needs reconstruction —
-                # full frames the range needed anyway included — so the
-                # per-family comparison stays apples-to-apples
-                ingress += len(got_full) * (fdig + per)
-                h1, h2 = bitrot_io.sub_lens(per)
-                sub2 = {}
-                for r in repair_sched.b_helpers:
-                    sub2[r] = (
-                        got_full[r][h1:] if r in got_full
-                        else sub_futs[(r, 1)].result()
-                    )
-                    ingress += DIGEST + h2 if r not in got_full else 0
-                pb = sub_futs[(repair_sched.pb_parity, 1)].result()
-                ingress += DIGEST + h2
-                # mates were fetched as full frame groups above
-                sub1 = {r: got_full[r][:h1] for r in repair_sched.mates}
-                got_full[i_m] = coder.repair_data_shard(
-                    repair_sched, per, sub2, pb, sub1
-                )
-                family_stats_add(family, "degraded_ingress_bytes", ingress)
-            # single pre-sized assembly (was .tobytes() per shard +
-            # b"".join — two full copies of every block)
-            out = bytearray(len(needed) * per)
-            mv = memoryview(out)
-            for j, idx in enumerate(needed):
-                mv[j * per : (j + 1) * per] = got_full[idx]
-            bufpool.count_copy("gather-join")
-            return serve_slice(out, lo - lo_sh * per, hi - lo_sh * per)
 
         # ---- plan: every stripe block overlapping [offset, offset+length) ----
         plan: list[tuple[int, int, int, int, int]] = []  # (part#, per, f_off, lo, hi)
@@ -1286,7 +1125,7 @@ class ErasureSet:
             a block at a time: its runs are one frame long."""
             if pnum not in single_frames:
                 single_frames[pnum] = family != bitrot_io.FAMILY_RS or any(
-                    m.inline_data or _whole_file_hash(m, pnum) is not None
+                    m.inline_data or shardread.whole_file_hash(m, pnum) is not None
                     for _disk, m in sources.values()
                 )
             return single_frames[pnum]
@@ -1528,330 +1367,71 @@ class ErasureSet:
                     out[bi] = buf
             return out
 
-        # ---- repair-plan execution: block-serial baseline --------------
-        # (MINIO_TPU_REPAIR_WINDOWED=0: one block's sub-chunk reads at a
-        # time, any failure abandons the rest of the plan to the generic
-        # gather — kept as the A/B lever the windowed executor's
-        # wall-clock gate measures against)
-        if repair_sched is not None and not _repair_windowed_enabled():
-            rest = None
-            for k, (pnum, per, f_off, lo, hi) in enumerate(plan):
-                try:
-                    piece = repair_read_block(pnum, per, f_off, lo, hi)
-                except (errors.FileCorrupt, errors.FileNotFound,
-                        errors.DiskNotFound, errors.DiskFull,
-                        errors.VolumeNotFound, OSError):
-                    # a helper failed mid-plan (second fault, bitrot):
-                    # the rest of the range takes the generic gather
-                    # path, which discovers and spills around failures
-                    # itself — partial repair is an optimization, never
-                    # a correctness dependency
-                    rest = plan[k:]
-                    break
-                yield piece
-            if rest is None:
-                return
-            plan = rest
-            repair_sched = None
-
-        # ---- repair-plan execution: windowed sub-chunk pipeline --------
-        # The same shape as the healthy path below: a window's sub-chunk
-        # frame reads issue concurrently, the next window's reads start
-        # as readahead while the current one decodes, and the hedged-read
-        # policy covers the plan — except that for sub-chunk reads the
-        # hedged alternative is the generic full-frame gather for that
-        # block. A blown budget races it; a mid-read breaker trip
-        # (DiskNotFound/DiskFull), bitrot, or second fault degrades to it
-        # outright — for that block ONLY. The plan is never abandoned,
-        # and every fallback byte re-verifies its frame digest like any
-        # generic read, so wrong bytes cannot be served.
+        # ---- repair-plan execution (erasure/shardread.py) --------------
+        # The same shape as the healthy path below — a window's reads
+        # issue together, the next window's as readahead — with each
+        # block's sub-chunk reads racing, once hedged or failed, the
+        # generic d-frame gather for that block ONLY.
         if repair_sched is not None:
             i_m = repair_sched.missing
-            SPILL = (errors.FileCorrupt, errors.FileNotFound,
-                     errors.DiskNotFound, errors.DiskFull,
-                     errors.VolumeNotFound, OSError)
 
-            def repair_frames(per, lo, hi):
-                """One block's plan read set: (full-frame shard indices,
-                sub-chunk rows, data rows the range needs)."""
-                lo_sh, hi_sh = lo // per, (hi - 1) // per
-                needed = list(range(lo_sh, min(hi_sh, d - 1) + 1))
-                full_idx = set(i for i in needed if i != i_m)
-                subs: list[int] = []
+            def range_rows(per, lo, hi) -> range:
+                """Data rows [lo, hi) of a block touches."""
+                return range(lo // per, min((hi - 1) // per, d - 1) + 1)
+
+            def plan_reads(blk):
+                _pnum, per, _f_off, lo, hi = blk
+                needed = range_rows(per, lo, hi)
+                full_idx = set(needed) - {i_m}
+                if i_m not in needed:
+                    return full_idx, ()
+                # mates need BOTH sub-chunks: one contiguous frame-group
+                # read each (same bytes, half the round-trips)
+                full_idx.update(repair_sched.mates)
+                return full_idx, [
+                    r for r in repair_sched.b_helpers if r not in full_idx
+                ] + [repair_sched.pb_parity]
+
+            def from_plan(blk, full, subs):
+                """Plan-complete block -> its [lo, hi) bytes."""
+                _pnum, per, _f_off, lo, hi = blk
+                needed = range_rows(per, lo, hi)
                 if i_m in needed:
-                    # mates need BOTH sub-chunks: one contiguous frame-
-                    # group read each (same bytes, half the round-trips)
-                    full_idx.update(repair_sched.mates)
-                    subs = [r for r in repair_sched.b_helpers
-                            if r not in full_idx]
-                    subs.append(repair_sched.pb_parity)
-                return full_idx, subs, needed
-
-            def start_repair_window(win):
-                """Submit every block's plan reads for the window."""
-                futs = {}
-                for bi, (pnum, per, f_off, lo, hi) in enumerate(win):
-                    full_idx, subs, _needed = repair_frames(per, lo, hi)
-                    for idx in full_idx:
-                        futs[(bi, "full", idx)] = pool.submit(
-                            read_shard_block, pnum, idx, per, f_off
-                        )
-                    for r in subs:
-                        futs[(bi, "sub", r)] = pool.submit(
-                            read_sub_chunk, pnum, r, per, f_off, 1
-                        )
-                return futs
-
-            def assemble_repair(entry, full, subs):
-                """Plan-complete block -> its [lo, hi) bytes (the compute
-                half of repair_read_block; reads already resolved)."""
-                pnum, per, f_off, lo, hi = entry
-                _full_idx, _subs, needed = repair_frames(per, lo, hi)
-                got = {i: np.frombuffer(v, dtype=np.uint8)
-                       for i, v in full.items()}
-                if i_m in needed:
-                    ingress = len(got) * (fdig + per)
-                    h1, h2 = bitrot_io.sub_lens(per)
-                    sub2 = {}
-                    for r in repair_sched.b_helpers:
-                        if r in got:
-                            sub2[r] = got[r][h1:]
-                        else:
-                            sub2[r] = subs[r]
-                            ingress += DIGEST + h2
-                    pb = subs[repair_sched.pb_parity]
-                    ingress += DIGEST + h2
-                    sub1 = {r: got[r][:h1] for r in repair_sched.mates}
-                    got[i_m] = coder.repair_data_shard(
-                        repair_sched, per, sub2, pb, sub1
+                    # as the generic path's counter: EVERY frame fetched
+                    # for a block that needs reconstruction, full frames
+                    # the range needed anyway included
+                    family_stats_add(
+                        family, "degraded_ingress_bytes",
+                        len(full) * (fdig + per)
+                        + len(subs) * (DIGEST + bitrot_io.sub_lens(per)[1]),
                     )
-                    family_stats_add(family, "degraded_ingress_bytes", ingress)
-                # single pre-sized assembly (was .tobytes() + b"".join)
+                    full[i_m] = shardread.repair_shard(
+                        coder, repair_sched, per, full, subs
+                    )
                 out = bytearray(len(needed) * per)
                 mv = memoryview(out)
                 for j, i in enumerate(needed):
-                    mv[j * per : (j + 1) * per] = got[i]
+                    mv[j * per : (j + 1) * per] = full[i]
                 bufpool.count_copy("gather-join")
-                lo_sh = lo // per
-                return serve_slice(out, lo - lo_sh * per, hi - lo_sh * per)
+                base = needed[0] * per
+                return serve_slice(out, lo - base, hi - base)
 
-            def gather_repair_window(win, futs):
-                """Resolve a window of plan blocks. Each block is its own
-                race: the sub-chunk read set vs (once hedged or failed)
-                the generic d-shard full gather — whichever completes
-                first serves the block. Returns (pieces, full, subs):
-                pieces[bi] is fallback-decoded bytes, or None meaning the
-                plan reads landed and assembly is deferred (it runs under
-                the next window's readahead)."""
-                nwin = len(win)
-                full = [dict() for _ in range(nwin)]    # bi -> idx: bytes
-                subs = [dict() for _ in range(nwin)]    # bi -> row: array
-                fb_got = [dict() for _ in range(nwin)]  # fallback frames
-                fb_mode = [False] * nwin
-                fb_hedge = [False] * nwin
-                plan_done = [False] * nwin
-                pieces: list[bytes | None] = [None] * nwin
-                pending: dict[tuple, object] = dict(futs)
-                rev = {f: k for k, f in pending.items()}
-                plan_keys: list[set] = [set() for _ in range(nwin)]
-                for k in futs:
-                    plan_keys[k[0]].add(k)
-                hedge_fired = False
-                import time as _time
+            def from_frames(blk, frames):
+                block = decode_window([blk], [frames])[0]
+                return serve_slice(block, blk[3], blk[4])
 
-                deadline = (
-                    _time.monotonic() + hedge_budget
-                    if hedge_budget is not None else None
-                )
+            def read_shard_block(part_num: int, idx: int, per: int, f_off: int):
+                return read_shard_run(part_num, idx, (per,), f_off)[0]
 
-                def unserved(bi):
-                    return pieces[bi] is None and not plan_done[bi]
-
-                def drop_plan_reads(bi):
-                    for k in list(plan_keys[bi]):
-                        f = pending.pop(k, None)
-                        if f is not None:
-                            rev.pop(f, None)
-                            f.cancel()
-                    plan_keys[bi].clear()
-
-                def drop_fb_reads(bi):
-                    for k in [k for k in pending
-                              if k[0] == bi and k[1] == "fb"]:
-                        f = pending.pop(k)
-                        rev.pop(f, None)
-                        f.cancel()
-
-                def fb_submit(bi) -> int:
-                    """Keep fallback block bi able to reach d shards."""
-                    pnum, per, f_off, _lo, _hi = win[bi]
-                    inflight = [k[2] for k in pending
-                                if k[0] == bi and k[1] == "fb"]
-                    have = len(fb_got[bi]) + len(inflight)
-                    tried = set(fb_got[bi]) | bad | set(inflight)
-                    cands = [i for i in range(self.n)
-                             if i in sources and i not in tried]
-                    n_sub = 0
-                    for idx in cands[: max(d - have, 0)]:
-                        f = pool.submit(read_shard_block, pnum, idx, per, f_off)
-                        pending[(bi, "fb", idx)] = f
-                        rev[f] = (bi, "fb", idx)
-                        n_sub += 1
-                    return n_sub
-
-                def enter_fallback(bi, racing) -> int:
-                    """Degrade block bi to the generic gather. ``racing``
-                    (hedge) leaves the plan reads inflight to race; a
-                    failed plan read drops them instead."""
-                    if fb_mode[bi]:
-                        return 0
-                    fb_mode[bi] = True
-                    fb_hedge[bi] = racing
-                    if not racing:
-                        drop_plan_reads(bi)
-                    return fb_submit(bi)
-
-                def finish_plan(bi):
-                    """All plan reads landed: settle the race; assembly
-                    is deferred to the caller (under readahead)."""
-                    plan_done[bi] = True
-                    if fb_mode[bi]:
-                        if fb_hedge[bi]:
-                            fault_registry.stats_add("repair_hedge_losses")
-                        drop_fb_reads(bi)
-
-                def finish_fallback(bi):
-                    if not unserved(bi) or len(fb_got[bi]) < d:
-                        return
-                    block = decode_window([win[bi]], [fb_got[bi]])[0]
-                    _pnum, _per, _f_off, lo, hi = win[bi]
-                    pieces[bi] = serve_slice(block, lo, hi)
-                    fault_registry.stats_add("repair_fallback_blocks")
-                    if fb_hedge[bi]:
-                        fault_registry.stats_add("repair_hedge_wins")
-                    drop_plan_reads(bi)
-
-                try:
-                    while any(unserved(bi) for bi in range(nwin)):
-                        # fallback blocks must stay able to reach d
-                        for bi in range(nwin):
-                            if not (unserved(bi) and fb_mode[bi]):
-                                continue
-                            inflight = sum(
-                                1 for k in pending
-                                if k[0] == bi and k[1] == "fb"
-                            )
-                            if len(fb_got[bi]) + inflight < d:
-                                if (fb_submit(bi) == 0 and inflight == 0
-                                        and not plan_keys[bi]):
-                                    pnum, _per, f_off, _lo, _hi = win[bi]
-                                    raise QuorumError(
-                                        f"cannot read part {pnum} shard "
-                                        f"offset {f_off}: only "
-                                        f"{len(fb_got[bi])} of {d} shards"
-                                    )
-                        if not pending:
-                            continue  # spills just submitted; re-check
-                        timeout = None
-                        if deadline is not None and not hedge_fired:
-                            timeout = max(deadline - _time.monotonic(), 0.0)
-                        # plan-only mode needs every read anyway: one
-                        # ALL_COMPLETED wait registers each future once.
-                        # Once any block races its fallback, settle per
-                        # completion (FIRST_COMPLETED) — whichever side
-                        # lands first serves without waiting on the loser.
-                        racing = hedge_fired or any(fb_mode)
-                        done, _ = _fut_wait(
-                            set(pending.values()), timeout=timeout,
-                            return_when=(
-                                FIRST_COMPLETED if racing else ALL_COMPLETED
-                            ),
-                        )
-                        if not done:
-                            # plan reads blew the hedge budget: race the
-                            # generic full gather for every unserved block
-                            hedge_fired = True
-                            fired = sum(
-                                enter_fallback(bi, True)
-                                for bi in range(nwin) if unserved(bi)
-                            )
-                            if fired:
-                                fault_registry.stats_add("repair_hedge_reads")
-                                fault_registry.emit(
-                                    "hedge.fire", plane="repair",
-                                    bucket=bucket, object=obj,
-                                    budgetMs=round(
-                                        (hedge_budget or 0.0) * 1e3, 1
-                                    ),
-                                    reads=fired,
-                                )
-                            else:
-                                deadline = None  # nothing left to hedge
-                            continue
-                        for f in done:
-                            key = rev.pop(f, None)
-                            if key is None:
-                                continue  # read dropped after its race
-                            pending.pop(key, None)
-                            bi, kind = key[0], key[1]
-                            if kind == "fb":
-                                try:
-                                    fb_got[bi][key[2]] = f.result()
-                                except SPILL:
-                                    bad.add(key[2])
-                                    report_degraded()
-                                else:
-                                    finish_fallback(bi)
-                                continue
-                            plan_keys[bi].discard(key)
-                            try:
-                                if kind == "full":
-                                    full[bi][key[2]] = f.result()
-                                else:
-                                    subs[bi][key[2]] = f.result()
-                            except SPILL:
-                                # mid-plan breaker trip / bitrot / second
-                                # fault: THIS block degrades to the
-                                # generic gather; sibling blocks keep
-                                # their plan reads
-                                if not unserved(bi):
-                                    continue
-                                if fb_mode[bi]:
-                                    # already racing: the plan just lost
-                                    # its own race; the gather carries on
-                                    drop_plan_reads(bi)
-                                else:
-                                    enter_fallback(bi, False)
-                            else:
-                                if unserved(bi) and not plan_keys[bi]:
-                                    finish_plan(bi)
-                finally:
-                    for f in pending.values():
-                        f.cancel()
-                return pieces, full, subs
-
-            r_windows = [
-                plan[i : i + window] for i in range(0, len(plan), window)
-            ]
-            r_futs = start_repair_window(r_windows[0]) if r_windows else {}
-            try:
-                for wi, win in enumerate(r_windows):
-                    pieces, r_full, r_subs = gather_repair_window(win, r_futs)
-                    r_futs = {}
-                    if wi + 1 < len(r_windows):
-                        r_futs = start_repair_window(r_windows[wi + 1])
-                    for bi in range(len(win)):
-                        if pieces[bi] is None:
-                            # plan-complete blocks decode here, under the
-                            # next window's readahead
-                            pieces[bi] = assemble_repair(
-                                win[bi], r_full[bi], r_subs[bi]
-                            )
-                        yield pieces[bi]
-            finally:
-                for f in r_futs.values():
-                    f.cancel()
+            yield from shardread.run_repair_plan(
+                plan, window, pool=pool, d=d, candidates=sorted(sources),
+                full_frame=read_shard_block, sub_frame=reader.sub_chunk,
+                plan_reads=plan_reads, from_plan=from_plan,
+                from_frames=from_frames, hedge_budget=hedge_budget,
+                fire_fields={"bucket": bucket, "object": obj},
+            )
             return
+
 
         # ---- pipelined execution: window k+1 reads under window k decode ----
         windows = [plan[i : i + window] for i in range(0, len(plan), window)]
@@ -2204,7 +1784,6 @@ class ErasureSet:
         d, p = fi.erasure.data_blocks, fi.erasure.parity_blocks
         coder = self.coder_for(fi)  # stored family; unknown -> typed error
         family = coder.family
-        fdig = coder.frame_digests * DIGEST
         sources = self._shard_sources(fi, metas)
 
         # verify the shards we think are good; drop any that fail bitrot
@@ -2238,12 +1817,10 @@ class ErasureSet:
         survivors_idx = sorted(good.keys())[:d]
         missing_idx = tuple(sorted(idx for idx, _ in stale))
 
-        heal_whole_cache: dict[tuple[int, int], bytes] = {}
-        heal_whole_mu = threading.Lock()
         # survivor bytes moved into this heal (the repair-bandwidth
         # number: metrics minio_heal_ingress_bytes_total, heal span).
-        # The windowed repair executor fans reads onto the shared pool,
-        # so the accumulator takes a lock.
+        # The repair-plan executor fans reads onto the shared pool, so
+        # the accumulator takes a lock.
         ingress = 0
         ingress_mu = threading.Lock()
 
@@ -2252,55 +1829,9 @@ class ErasureSet:
             with ingress_mu:
                 ingress += n
 
-        def read_block(part, idx, f_off, per):
-            disk, m = good[idx]
-            wf = _whole_file_hash(m, part.number)
-            if wf is not None:  # legacy whole-file survivor
-                k = (idx, part.number)
-                # coarse lock: legacy survivors are rare and the whole-
-                # file read+verify must happen once, not once per racing
-                # windowed block
-                with heal_whole_mu:
-                    if k not in heal_whole_cache:
-                        raw = m.inline_data if m.inline_data else disk.read_file(
-                            bucket, f"{obj}/{fi.data_dir}/part.{part.number}",
-                            0, -1,
-                        )
-                        ingress_add(len(raw))
-                        heal_whole_cache[k] = bitrot_io.verify_whole_file(
-                            bytes(raw), *wf
-                        )
-                block_i = f_off // (fdig + coder.shard_size)
-                blk = heal_whole_cache[k][block_i * coder.shard_size:][:per]
-                if len(blk) != per:
-                    raise errors.FileCorrupt("short whole-file shard")
-                return blk
-            if m.inline_data:
-                buf = m.inline_data[f_off : f_off + fdig + per]
-            else:
-                buf = disk.read_file(
-                    bucket, f"{obj}/{fi.data_dir}/part.{part.number}",
-                    f_off, fdig + per,
-                )
-            ingress_add(len(buf))
-            return bitrot_io.verify_block(buf, per, family=family)
-
-        def read_sub(part, idx, f_off, per, which):
-            """Sub-chunk frame read from a survivor (partial repair)."""
-            disk, m = good[idx]
-            rel, dlen = bitrot_io.sub_chunk_in_block(per, which)
-            off = f_off + rel
-            if m.inline_data:
-                buf = m.inline_data[off : off + DIGEST + dlen]
-            else:
-                buf = disk.read_file(
-                    bucket, f"{obj}/{fi.data_dir}/part.{part.number}",
-                    off, DIGEST + dlen,
-                )
-            ingress_add(len(buf))
-            return np.frombuffer(
-                bitrot_io.verify_sub_chunk(bytes(buf), dlen), dtype=np.uint8
-            )
+        reader = shardread.ShardReader(
+            bucket, obj, fi, coder, good, on_bytes=ingress_add
+        )
 
         # healed shards keep the OBJECT's format: streaming objects get
         # family-framed digest||block records, legacy whole-file objects
@@ -2322,270 +1853,48 @@ class ErasureSet:
             if sched is not None and all(h in good for h in sched.helpers):
                 repair_sched = sched
 
-        def repair_part_windowed(part, geometry) -> bytearray:
-            """Windowed + hedged partial repair of one part's lost shard
-            (the heal twin of the degraded-GET plan executor): a window
-            of blocks' sub-chunk reads issues concurrently on the shard-
-            read pool, the next window starts as readahead while the
-            current one frames (hash + emit), and a straggling or failed
-            helper degrades THAT block to a generic survivor rebuild —
-            racing it as the hedge when the EWMA budget blows. Raises
-            only when a block can neither repair nor rebuild from the
-            verified survivor set (the caller then falls back to the
-            generic whole-part path). Returns the lost shard's framed
-            bytes for the whole part, in block order."""
+        def repair_part(part, geometry) -> bytearray:
+            """Partial repair of one part's lost shard by the repair-plan
+            executor (erasure/shardread.py): a straggling or failed
+            helper degrades THAT block to a rebuild from d verified
+            survivor frames. Raises only when a block can do neither
+            (the caller then rebuilds the part the generic way). Returns
+            the lost shard's framed bytes for the whole part."""
             sched = repair_sched
-            s_idx = sched.missing
-            pool = _read_pool()
-            window = max(1, int(os.environ.get("MINIO_TPU_READ_WINDOW", "8")))
-            hedge_budget = self._hedge_budget_s()
-            SPILL = (StorageError, OSError)
+            subs_of = [r for r in sched.b_helpers if r not in sched.mates]
+            subs_of.append(sched.pb_parity)
 
-            def start_win(blocks):
-                """Submit one window's plan reads: mates as full frame
-                groups (they need both sub-chunks), the remaining
-                b_helpers + piggyback parity as sub-chunk-2 frames."""
-                futs = {}
-                for bi, (block_i, per) in enumerate(blocks):
-                    f_off = bitrot_io.block_offset(
-                        coder.shard_size, block_i, family
-                    )
-                    for r in sched.mates:
-                        futs[(bi, "full", r)] = pool.submit(
-                            read_block, part, r, f_off, per
-                        )
-                    for r in sched.b_helpers:
-                        if r not in sched.mates:
-                            futs[(bi, "sub", r)] = pool.submit(
-                                read_sub, part, r, f_off, per, 1
-                            )
-                    futs[(bi, "sub", sched.pb_parity)] = pool.submit(
-                        read_sub, part, sched.pb_parity, f_off, per, 1
-                    )
-                return futs
-
-            def assemble(blocks, bi, fullm, subm) -> np.ndarray:
-                _block_i, per = blocks[bi]
-                h1m, _h2m = bitrot_io.sub_lens(per)
-                mate_full = {
-                    r: np.frombuffer(fullm[bi][r], dtype=np.uint8)
-                    for r in sched.mates
+            def lost_from_frames(blk, frames):
+                got = {
+                    i: np.frombuffer(v, dtype=np.uint8)
+                    for i, v in frames.items()
                 }
-                sub2 = {
-                    r: (mate_full[r][h1m:] if r in mate_full else subm[bi][r])
-                    for r in sched.b_helpers
-                }
-                pb = subm[bi][sched.pb_parity]
-                sub1 = {r: v[:h1m] for r, v in mate_full.items()}
-                return coder.repair_data_shard(sched, per, sub2, pb, sub1)
-
-            def gather_win(blocks, futs):
-                """Resolve one window; every block races its plan reads
-                against (once hedged or failed) a generic survivor
-                rebuild. Returns the rebuilt shard per block."""
-                nb = len(blocks)
-                fullm = [dict() for _ in range(nb)]
-                subm = [dict() for _ in range(nb)]
-                fb_got = [dict() for _ in range(nb)]
-                fb_bad: set[int] = set()  # shards whose fb read failed
-                fb_mode = [False] * nb
-                fb_hedge = [False] * nb
-                shards: list[np.ndarray | None] = [None] * nb
-                plan_keys: list[set] = [set() for _ in range(nb)]
-                pending: dict[tuple, object] = dict(futs)
-                rev = {f: k for k, f in pending.items()}
-                for k in futs:
-                    plan_keys[k[0]].add(k)
-                last_err: BaseException | None = None
-                hedge_fired = False
-                import time as _time
-
-                deadline = (
-                    _time.monotonic() + hedge_budget
-                    if hedge_budget is not None else None
-                )
-
-                def drop_plan(bi):
-                    for k in list(plan_keys[bi]):
-                        f = pending.pop(k, None)
-                        if f is not None:
-                            rev.pop(f, None)
-                            f.cancel()
-                    plan_keys[bi].clear()
-
-                def drop_fb(bi):
-                    for k in [k for k in pending
-                              if k[0] == bi and k[1] == "fb"]:
-                        f = pending.pop(k)
-                        rev.pop(f, None)
-                        f.cancel()
-
-                def fb_submit(bi) -> int:
-                    block_i, per = blocks[bi]
-                    f_off = bitrot_io.block_offset(
-                        coder.shard_size, block_i, family
-                    )
-                    inflight = [k[2] for k in pending
-                                if k[0] == bi and k[1] == "fb"]
-                    have = len(fb_got[bi]) + len(inflight)
-                    tried = set(fb_got[bi]) | set(inflight) | fb_bad
-                    cands = [i for i in sorted(good) if i not in tried]
-                    n_sub = 0
-                    for idx in cands[: max(d - have, 0)]:
-                        f = pool.submit(read_block, part, idx, f_off, per)
-                        pending[(bi, "fb", idx)] = f
-                        rev[f] = (bi, "fb", idx)
-                        n_sub += 1
-                    return n_sub
-
-                def enter_fb(bi, racing) -> int:
-                    if fb_mode[bi]:
-                        return 0
-                    fb_mode[bi] = True
-                    fb_hedge[bi] = racing
-                    if not racing:
-                        drop_plan(bi)
-                    return fb_submit(bi)
-
-                def finish_plan(bi):
-                    shards[bi] = assemble(blocks, bi, fullm, subm)
-                    if fb_mode[bi]:
-                        if fb_hedge[bi]:
-                            fault_registry.stats_add("repair_hedge_losses")
-                        drop_fb(bi)
-
-                def finish_fb(bi):
-                    if shards[bi] is not None or len(fb_got[bi]) < d:
-                        return
-                    got = {
-                        i: np.frombuffer(v, dtype=np.uint8)
-                        for i, v in fb_got[bi].items()
-                    }
-                    rec = coder.reconstruct_block(got, blocks[bi][1])
-                    shards[bi] = rec[s_idx]
-                    fault_registry.stats_add("repair_fallback_blocks")
-                    if fb_hedge[bi]:
-                        fault_registry.stats_add("repair_hedge_wins")
-                    drop_plan(bi)
-
-                try:
-                    while any(s is None for s in shards):
-                        for bi in range(nb):
-                            if shards[bi] is not None or not fb_mode[bi]:
-                                continue
-                            inflight = sum(
-                                1 for k in pending
-                                if k[0] == bi and k[1] == "fb"
-                            )
-                            if len(fb_got[bi]) + inflight < d:
-                                if (fb_submit(bi) == 0 and inflight == 0
-                                        and not plan_keys[bi]):
-                                    # neither path can complete: the
-                                    # caller rebuilds this part the
-                                    # generic way
-                                    raise last_err or errors.FileCorrupt(
-                                        "repair fallback lost quorum"
-                                    )
-                        if not pending:
-                            continue
-                        timeout = None
-                        if deadline is not None and not hedge_fired:
-                            timeout = max(deadline - _time.monotonic(), 0.0)
-                        # plan-only mode needs every read anyway: one
-                        # ALL_COMPLETED wait registers each future once.
-                        # Once any block races its fallback, settle per
-                        # completion (FIRST_COMPLETED) — whichever side
-                        # lands first serves without waiting on the loser.
-                        racing = hedge_fired or any(fb_mode)
-                        done, _ = _fut_wait(
-                            set(pending.values()), timeout=timeout,
-                            return_when=(
-                                FIRST_COMPLETED if racing else ALL_COMPLETED
-                            ),
-                        )
-                        if not done:
-                            hedge_fired = True
-                            fired = sum(
-                                enter_fb(bi, True)
-                                for bi in range(nb) if shards[bi] is None
-                            )
-                            if fired:
-                                fault_registry.stats_add("repair_hedge_reads")
-                                fault_registry.emit(
-                                    "hedge.fire", plane="repair", op="heal",
-                                    bucket=bucket, object=obj,
-                                    budgetMs=round(
-                                        (hedge_budget or 0.0) * 1e3, 1
-                                    ),
-                                    reads=fired,
-                                )
-                            else:
-                                deadline = None
-                            continue
-                        for f in done:
-                            key = rev.pop(f, None)
-                            if key is None:
-                                continue
-                            pending.pop(key, None)
-                            bi, kind = key[0], key[1]
-                            if kind == "fb":
-                                try:
-                                    fb_got[bi][key[2]] = f.result()
-                                except SPILL as e:
-                                    # a failed fallback shard must never
-                                    # be re-picked (a persistently
-                                    # corrupt helper would loop forever)
-                                    last_err = e
-                                    fb_bad.add(key[2])
-                                else:
-                                    finish_fb(bi)
-                                continue
-                            plan_keys[bi].discard(key)
-                            try:
-                                if kind == "full":
-                                    fullm[bi][key[2]] = f.result()
-                                else:
-                                    subm[bi][key[2]] = f.result()
-                            except SPILL as e:
-                                last_err = e
-                                if shards[bi] is not None:
-                                    continue
-                                if fb_mode[bi]:
-                                    drop_plan(bi)  # plan lost its race
-                                else:
-                                    enter_fb(bi, False)
-                            else:
-                                if shards[bi] is None and not plan_keys[bi]:
-                                    finish_plan(bi)
-                finally:
-                    for f in pending.values():
-                        f.cancel()
-                return shards
+                return coder.reconstruct_block(got, blk[1])[sched.missing]
 
             out = bytearray()
-            blocks_all = [
-                (block_i, per)
-                for block_i, (_data_len, per) in enumerate(geometry)
-            ]
-            wins = [
-                blocks_all[i : i + window]
-                for i in range(0, len(blocks_all), window)
-            ]
-            futs = start_win(wins[0]) if wins else {}
-            try:
-                for wi, blocks in enumerate(wins):
-                    shards = gather_win(blocks, futs)
-                    futs = {}
-                    if wi + 1 < len(wins):
-                        futs = start_win(wins[wi + 1])  # readahead
-                    for blk in shards:
-                        # framing (bitrot hash + emit) runs under the
-                        # next window's readahead
-                        out += bitrot_io.frame_block(blk.tobytes(), family)
-            finally:
-                for f in futs.values():
-                    f.cancel()
+            for shard in shardread.run_repair_plan(
+                [
+                    (part.number, per,
+                     bitrot_io.block_offset(coder.shard_size, block_i, family))
+                    for block_i, (_data_len, per) in enumerate(geometry)
+                ],
+                max(1, int(os.environ.get("MINIO_TPU_READ_WINDOW", "8"))),
+                pool=_read_pool(), d=d, candidates=sorted(good),
+                full_frame=reader.block, sub_frame=reader.sub_chunk,
+                # mates as full frame groups (they need both sub-chunks)
+                plan_reads=lambda blk: (sched.mates, subs_of),
+                from_plan=lambda blk, full, subs: shardread.repair_shard(
+                    coder, sched, blk[1], full, subs
+                ),
+                from_frames=lost_from_frames,
+                hedge_budget=self._hedge_budget_s(),
+                fire_fields={"op": "heal", "bucket": bucket, "object": obj},
+            ):
+                # framing (bitrot hash + emit) runs under the next
+                # window's readahead
+                out += bitrot_io.frame_block(shard.tobytes(), family)
             return out
+
 
         for part in fi.parts:
             geometry = coder.shard_sizes_for(part.size)
@@ -2607,55 +1916,13 @@ class ErasureSet:
             )
             batched_done = 0
             if repair_sched is not None:
-                s_idx = repair_sched.missing
                 try:
-                    if _repair_windowed_enabled():
-                        # windowed + hedged executor: straggling/failed
-                        # helpers degrade per BLOCK to a generic survivor
-                        # rebuild inside repair_part_windowed; only a
-                        # block that can do neither lands here
-                        rebuilt[s_idx] += repair_part_windowed(
-                            part, geometry
-                        )
-                    else:
-                        # block-serial baseline
-                        # (MINIO_TPU_REPAIR_WINDOWED=0)
-                        for block_i, (data_len, per) in enumerate(geometry):
-                            f_off = bitrot_io.block_offset(
-                                coder.shard_size, block_i, family
-                            )
-                            # group mates need BOTH sub-chunks (every
-                            # mate is a b_helper): one full frame-group
-                            # read each — same bytes as two sub-chunk
-                            # reads, half the ops
-                            h1m, _h2m = bitrot_io.sub_lens(per)
-                            mate_full = {
-                                r: np.frombuffer(
-                                    read_block(part, r, f_off, per),
-                                    dtype=np.uint8,
-                                )
-                                for r in repair_sched.mates
-                            }
-                            sub2 = {
-                                r: (
-                                    mate_full[r][h1m:] if r in mate_full
-                                    else read_sub(part, r, f_off, per, 1)
-                                )
-                                for r in repair_sched.b_helpers
-                            }
-                            pb = read_sub(
-                                part, repair_sched.pb_parity, f_off, per, 1
-                            )
-                            sub1 = {r: v[:h1m] for r, v in mate_full.items()}
-                            blk = coder.repair_data_shard(
-                                repair_sched, per, sub2, pb, sub1
-                            )
-                            rebuilt[s_idx] += bitrot_io.frame_block(
-                                blk.tobytes(), family
-                            )
+                    rebuilt[repair_sched.missing] += repair_part(
+                        part, geometry
+                    )
                     per_part_rebuilt[part.number] = rebuilt
                     continue
-                except (StorageError, OSError):
+                except QuorumError:
                     # helper failed mid-repair AND the per-block fallback
                     # lost quorum: rebuild THIS part the generic way (and
                     # stop trying the shortcut — the survivor set just
@@ -2677,7 +1944,9 @@ class ErasureSet:
                         )
                         for si, idx in enumerate(survivors_idx):
                             surv[bi, si] = np.frombuffer(
-                                read_block(part, idx, f_off, coder.shard_size),
+                                reader.block(
+                                    part.number, idx, coder.shard_size, f_off
+                                ),
                                 dtype=np.uint8,
                             )
                     # reconstruct + bitrot-hash in one device dispatch:
@@ -2699,7 +1968,8 @@ class ErasureSet:
                 got: dict[int, np.ndarray] = {}
                 for idx in survivors_idx:
                     got[idx] = np.frombuffer(
-                        read_block(part, idx, f_off, per), dtype=np.uint8
+                        reader.block(part.number, idx, per, f_off),
+                        dtype=np.uint8,
                     )
                 rec = coder.reconstruct_block(got, per)
                 for idx, _ in stale:

@@ -7,6 +7,7 @@ ops/, so an environment without a toolchain still works (slower).
 
 from __future__ import annotations
 
+import contextlib
 import ctypes
 import os
 import subprocess
@@ -24,13 +25,19 @@ _build_failed = False
 
 
 def _build() -> bool:
+    # a temporary of this process's own: processes that import at once (a
+    # fresh checkout under xdist) each publish a whole file, never one
+    # another's half-written output
+    tmp = f"{_SO}.{os.getpid()}.tmp"
     cmd = ["g++", "-O3", "-mavx2", "-shared", "-fPIC", *_SRCS,
-           "-o", _SO + ".tmp", "-ldl"]
+           "-o", tmp, "-ldl"]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(_SO + ".tmp", _SO)
+        os.replace(tmp, _SO)
         return True
     except (subprocess.SubprocessError, OSError):
+        with contextlib.suppress(OSError):
+            os.unlink(tmp)
         return False
 
 

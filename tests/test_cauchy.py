@@ -322,6 +322,9 @@ def test_heal_partial_repair_ingress(tmp_path, monkeypatch):
     rebuilds byte-identically; MINIO_TPU_EC_REPAIR=0 disables the
     shortcut but not the heal."""
     monkeypatch.setenv("MINIO_TPU_NATIVE_PLANE", "0")
+    # the bound is the PLAN's: no hedge, so a slow host cannot add the
+    # fallback's full frames to the cauchy side
+    monkeypatch.setenv("MINIO_TPU_HEDGE", "0")
     ingress = {}
     body = os.urandom(3 << 20)
     for fam in ("reedsolomon", "cauchy"):

@@ -221,11 +221,24 @@ def test_hedged_read_decodes_around_straggler(tmp_path, monkeypatch):
         "boundary": "storage", "mode": "latency", "latency_ms": 500,
         "target": straggler.endpoint, "op": "read_file", "seed": 3,
     })
+    # the straggler's shard reads, as they start and as they end
+    started, ended = [], []
+    inner_read = straggler.read_file
+
+    def noting_read(*a, **kw):
+        started.append(a)
+        try:
+            return inner_read(*a, **kw)
+        finally:
+            ended.append(a)
+
+    monkeypatch.setattr(straggler, "read_file", noting_read)
     before = _counters()
-    t0 = time.monotonic()
     _, it = es.get_object("cbkt", "straggly")
     got = b"".join(it)
-    hedged_s = time.monotonic() - t0
+    # the straggler's 500 ms never reached the hedged caller: its read was
+    # asked for, and was still asleep when the GET had all its bytes
+    assert started and not ended, (started, ended)
     assert got == body
     after = _counters()
     assert after["hedge_reads"] > before["hedge_reads"], \
@@ -233,22 +246,15 @@ def test_hedged_read_decodes_around_straggler(tmp_path, monkeypatch):
     assert after["hedge_wins"] > before["hedge_wins"], \
         "hedge fired and beat a 500ms straggler: must win"
 
-    # hedge off: the same GET on the same (possibly loaded) host inherits
-    # the straggler — this is the injected latency as actually delivered
+    # hedge off: the same GET inherits the straggler — this is the injected
+    # latency as actually delivered
     monkeypatch.setenv("MINIO_TPU_HEDGE", "0")
     t0 = time.monotonic()
     _, it = es.get_object("cbkt", "straggly")
     assert b"".join(it) == body
     inherited_s = time.monotonic() - t0
     assert inherited_s >= 0.45  # a sleep's lower bound holds under any load
-    # the straggler's 500 ms never reached the hedged caller: judged
-    # against the latency measured here, not a fixed wall-clock bound (a
-    # loaded CI host inflates both GETs alike), the hedge saved at least
-    # half of what was injected
-    assert inherited_s - hedged_s >= 0.25, (
-        f"hedged GET {hedged_s:.3f}s vs straggler-bound GET "
-        f"{inherited_s:.3f}s"
-    )
+    assert len(ended) >= 2  # and this GET did wait its read out
 
 
 def test_latency_breaker_trips_chronically_slow_drive(tmp_path):

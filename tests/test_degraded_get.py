@@ -41,6 +41,9 @@ def served(tmp_path_factory):
     # a drive's breaker re-probes at once, so that one case's offline drives
     # are back for the next (the probe fails while the rule is armed)
     mp.setenv("MINIO_TPU_DRIVE_COOLDOWN_S", "0.01")
+    # counts here are exact: a hedge won on a loaded host puts a parity shard
+    # in a straggler's place and the window rebuilds one shard more
+    mp.setenv("MINIO_TPU_HEDGE", "0")
     mp.delenv("MINIO_COMPRESSION_ENABLE", raising=False)
     base = tmp_path_factory.mktemp("degraded-drives")
     drives = [str(base / f"d{i:02d}") for i in range(16)]

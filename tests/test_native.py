@@ -47,3 +47,35 @@ def test_batch_and_fused():
     np.testing.assert_array_equal(
         native.hh256_batch(MINIO_KEY, full), hash256_batch_numpy(full)
     )
+
+
+def test_four_processes_importing_a_fresh_checkout_all_get_the_library(tmp_path):
+    """A fresh checkout under xdist: every worker's first import finds no
+    gfhash.so and builds it. Each builds to a temporary of its own and
+    publishes a whole file, so none loads another's half-written output."""
+    import os
+    import shutil
+    import subprocess
+    import sys
+
+    src = os.path.dirname(native.__file__)
+    pkg = tmp_path / "minio_tpu" / "native"
+    pkg.mkdir(parents=True)
+    (tmp_path / "minio_tpu" / "__init__.py").write_text("")
+    for name in os.listdir(src):
+        if name.endswith((".py", ".cpp", ".h")):
+            shutil.copy(os.path.join(src, name), pkg / name)
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("PYTHONPATH", "MINIO_TPU_NO_NATIVE")}
+    probe = ("import sys; from minio_tpu import native; "
+             "assert native.__file__.startswith(sys.argv[1]), native.__file__; "
+             "sys.exit(0 if native.available() else 3)")
+    procs = [
+        subprocess.Popen([sys.executable, "-c", probe, str(tmp_path)],
+                         cwd=tmp_path, env=env, stderr=subprocess.PIPE)
+        for _ in range(4)
+    ]
+    ends = [(p.wait(timeout=180), p.stderr.read().decode()) for p in procs]
+    assert [rc for rc, _ in ends] == [0] * 4, ends
+    assert sorted(n for n in os.listdir(pkg) if not n.endswith((".py", ".cpp", ".h"))
+                  and n != "__pycache__") == ["gfhash.so"]

@@ -1,8 +1,7 @@
 """Degraded-plane hardening (windowed + hedged partial repair):
 
 - windowed plan executor serves full and ranged degraded GETs
-  byte-identically across window boundaries (READ_WINDOW=2), in both
-  windowed and block-serial (MINIO_TPU_REPAIR_WINDOWED=0) modes
+  byte-identically across window boundaries (READ_WINDOW=2)
 - injected sub-chunk bitrot mid-plan degrades per BLOCK to the generic
   gather (repair_fallback_blocks advances, bytes stay correct)
 - a straggling helper past the hedge budget fires the repair-plane
@@ -97,30 +96,27 @@ def _counters() -> dict:
 
 def test_windowed_repair_ranges_across_windows(tmp_path, monkeypatch):
     """READ_WINDOW=2 forces multiple windows; full and ranged degraded
-    GETs are byte-identical in windowed AND block-serial modes, and the
-    partial-repair plan actually ran (repair_partial_blocks advances)."""
+    GETs are byte-identical, and the partial-repair plan actually ran
+    (repair_partial_blocks advances)."""
     monkeypatch.setenv("MINIO_TPU_READ_WINDOW", "2")
     es, _ = _rig(tmp_path, "win")
     body = os.urandom((5 << 20) + 12345)  # 6 stripe blocks -> 3 windows
     es.put_object(BKT, "o", body)
     _lose_shard0(es, tmp_path, "win")
 
-    for mode in ("1", "0"):
-        monkeypatch.setenv("MINIO_TPU_REPAIR_WINDOWED", mode)
-        before = family_stats_snapshot()["cauchy"]["repair_partial_blocks"]
+    before = family_stats_snapshot()["cauchy"]["repair_partial_blocks"]
+    es.cache.clear()
+    _, it = es.get_object(BKT, "o")
+    assert _drain(it) == body
+    after = family_stats_snapshot()["cauchy"]["repair_partial_blocks"]
+    assert after > before, "plan did not run"
+    # ranges that start mid-block, span a window boundary, and
+    # cover the tail
+    for off, ln in ((4096, 65536), ((2 << 20) - 7, 1 << 20),
+                    (len(body) - 9000, 9000)):
         es.cache.clear()
-        _, it = es.get_object(BKT, "o")
-        assert _drain(it) == body, f"mode={mode}"
-        after = family_stats_snapshot()["cauchy"]["repair_partial_blocks"]
-        assert after > before, f"plan did not run in mode={mode}"
-        # ranges that start mid-block, span a window boundary, and
-        # cover the tail
-        for off, ln in ((4096, 65536), ((2 << 20) - 7, 1 << 20),
-                        (len(body) - 9000, 9000)):
-            es.cache.clear()
-            _, h = es.open_object(BKT, "o")
-            assert _drain(h.read(off, ln)) == body[off : off + ln], \
-                (mode, off, ln)
+        _, h = es.open_object(BKT, "o")
+        assert _drain(h.read(off, ln)) == body[off : off + ln], (off, ln)
 
 
 def test_plan_block_falls_back_on_bitrot(tmp_path, monkeypatch):
@@ -263,21 +259,6 @@ def test_heal_corrupt_helper_falls_back_per_block(tmp_path, monkeypatch):
     assert _drain(it) == body
     metas, _ = es._read_all_fileinfo(BKT, "o", "", read_data=False)
     es.disks[lost].verify_file(BKT, "o", metas[lost])
-
-
-def test_heal_serial_baseline_still_correct(tmp_path, monkeypatch):
-    """MINIO_TPU_REPAIR_WINDOWED=0 keeps the block-serial heal as a
-    correct A/B lever."""
-    monkeypatch.setenv("MINIO_TPU_REPAIR_WINDOWED", "0")
-    es, _ = _rig(tmp_path, "hser")
-    body = os.urandom(2 << 20)
-    es.put_object(BKT, "o", body)
-    _lose_shard0(es, tmp_path, "hser")
-    res = es.heal_object(BKT, "o")
-    assert res["healed"] and res["partialRepair"], res
-    es.cache.clear()
-    _, it = es.get_object(BKT, "o")
-    assert _drain(it) == body
 
 
 # ---------------------------------------------------------------------------
