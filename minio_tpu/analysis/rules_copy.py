@@ -51,7 +51,7 @@ COPY_BOUNDARY: dict[str, set[str]] = {
     # zero-copy uint8 views for the decode kernels; the heal plane's
     # tobytes feeds the bitrot re-framing writer (cold path, per-object)
     "erasure/set.py": {
-        "decode_window", "lost_from_frames", "repair_part",
+        "stack_survivors", "lost_from_frames", "repair_part",
         "_heal_object_locked",
     },
     # the shard reader's sub-chunk frames and the repair plan's full

@@ -168,10 +168,19 @@ def verify_block(
     return block if view else bytes(block)
 
 
+class FrameRun(list):
+    """A run's verified payloads, in order. ``rows`` is the same bytes as ONE
+    array, ``[frames, length]`` with its rows a frame apart, where the
+    payloads are equal-length views of one read buffer (more than one of
+    them): what a single strided copy of the run takes. Else None."""
+
+    rows = None
+
+
 def verify_run(
     buf: bytes, lens, algo: BitrotAlgorithm = DEFAULT_BITROT_ALGO,
     view: bool = False,
-) -> list:
+) -> FrameRun:
     """Split a run of consecutive reedsolomon frames (digest || block, the
     blocks ``lens`` bytes long, read from the shard file in ONE piece) and
     verify EVERY frame before any payload is handed out; returns the
@@ -183,7 +192,8 @@ def verify_run(
     from another shard. The payloads hash where they were read — no copy
     of the run first — and a stretch of equal-length frames in one native
     call. ``view=True`` returns views of ``buf`` (which the views keep
-    alive), else bytes."""
+    alive) and, where they are equally long, the run as one array
+    (``FrameRun.rows``); else bytes."""
     if len(buf) != sum(lens) + DIGEST_SIZE * len(lens):
         raise errors.FileCorrupt("short shard run")
     mv = memoryview(buf)
@@ -195,8 +205,16 @@ def verify_run(
     for k, dig in enumerate(_run_digests(mv, offs, lens, algo)):
         if dig != mv[offs[k] - DIGEST_SIZE : offs[k]]:
             raise errors.FileCorrupt(f"bitrot detected (frame {k} of run)")
-    out = [mv[o : o + n] for o, n in zip(offs, lens)]
-    return out if view else [bytes(b) for b in out]
+    if not view:
+        return FrameRun(bytes(mv[o : o + n]) for o, n in zip(offs, lens))
+    out = FrameRun(mv[o : o + n] for o, n in zip(offs, lens))
+    if len(lens) > 1 and len(set(lens)) == 1:
+        import numpy as np
+
+        out.rows = np.frombuffer(mv, dtype=np.uint8).reshape(
+            len(lens), DIGEST_SIZE + lens[0]
+        )[:, DIGEST_SIZE:]
+    return out
 
 
 def _run_digests(mv: memoryview, offs, lens, algo: BitrotAlgorithm) -> list[bytes]:

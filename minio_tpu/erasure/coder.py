@@ -64,6 +64,67 @@ def repair_reads_enabled() -> bool:
     return os.environ.get("MINIO_TPU_EC_REPAIR", "1") != "0"
 
 
+class SurvivorStack:
+    """The survivors of one decode group — W same-pattern stripe blocks, d
+    shards of ``per`` bytes each — written ONCE, in the form the rung that
+    will decode them takes (``ErasureCoder.survivor_stack`` says which):
+
+    - ``packed``: the decode mega-kernel's own input, chunk-major
+      ``[per / CHUNK_BYTES, bpad, d, CHUNK_BYTES]`` with W rounded up to
+      the kernel's multiple of 16 and the pad rows zero (written here, on
+      an arena that held other bytes): what ``device_put`` takes;
+    - else ``[d, W, per]``, shard-major: what the XLA rung and the host's
+      GF apply take.
+
+    ``shape`` is (d, W, per) in both. Pooled scratch: ``release`` when the
+    rebuilt rows are on the host (they are fresh arrays, never views)."""
+
+    __slots__ = ("array", "shape", "packed", "_lease")
+
+    def __init__(self, d: int, w: int, per: int, chunk: int = 0,
+                 pooled: bool = True):
+        self.shape = (d, w, per)
+        self.packed = chunk > 0
+        bpad = -(-w // 16) * 16 if self.packed else w
+        nb = d * bpad * per
+        self._lease = bufpool.get_pool().acquire(nb) if pooled else None
+        flat = (
+            self._lease.array[:nb] if pooled else np.empty(nb, dtype=np.uint8)
+        )
+        if self.packed:
+            self.array = flat.reshape(per // chunk, bpad, d, chunk)
+            self.array[:, w:] = 0
+        else:
+            self.array = flat.reshape(d, w, per)
+
+    def put(self, k: int, w0: int, rows: np.ndarray) -> None:
+        """Survivor k of blocks w0 .. w0 + len(rows): ONE strided copy of
+        ``rows`` ([blocks, per], its rows any distance apart)."""
+        n = len(rows)
+        if self.packed:
+            nc, _bpad, _d, chunk = self.array.shape
+            self.array[:, w0 : w0 + n, k] = rows.reshape(
+                n, nc, chunk
+            ).transpose(1, 0, 2)
+        else:
+            self.array[k, w0 : w0 + n] = rows
+
+    def block_major(self) -> np.ndarray:
+        """[W, d, per]: a view of the shard-major stack; the rows taken
+        back out of a packed one (a copy: the rare path on which the
+        kernel it was laid out for did not take it)."""
+        if not self.packed:
+            return self.array.transpose(1, 0, 2)
+        from ..ops import fused_pallas as fp
+
+        return fp.unpack_chunk_major(self.array)[: self.shape[1]]
+
+    def release(self) -> None:
+        if self._lease is not None:
+            self._lease.release()
+            self._lease = None
+
+
 # -- per-family counters (metrics-v3 /api/tpu) ------------------------------
 
 _FSTATS_LOCK = threading.Lock()
@@ -536,39 +597,76 @@ class ErasureCoder:
         family_stats_add(self.family, "decode_host_blocks", 1)
         return {i: rec[i] for i in range(self.t)}
 
+    def _decodes_on_device(self, w: int) -> bool:
+        """A group of w blocks goes to a device rung: not the cauchy
+        family, not a CPU-plane process, not under the device floor."""
+        return (
+            self.family != FAMILY_CAUCHY
+            and self._jax is not None
+            and w * self.t >= int(os.environ.get("MINIO_TPU_DECODE_MIN_SHARDS", "64"))
+        )
+
+    def survivor_stack(
+        self, w: int, per: int, missing: int, pooled: bool = True
+    ) -> SurvivorStack:
+        """The stack a group of w blocks with `missing` shards to rebuild
+        is gathered into, laid out for the rung that will decode it, from
+        what can be observed now: packed where the decode mega-kernel will
+        take the group (family, the device floor, its shapes, no cooldown),
+        [d, w, per] everywhere else."""
+        chunk = 0
+        if self._decodes_on_device(w):
+            from ..ops import bitrot_jax, fused_pallas as fp
+
+            if bitrot_jax.fused_decode_takes(self.d, missing, w, per):
+                chunk = fp.CHUNK_BYTES
+        return SurvivorStack(self.d, w, per, chunk, pooled)
+
     def reconstruct_data_flat(
         self,
-        survivors: np.ndarray,
+        survivors: np.ndarray | SurvivorStack,
         present: tuple[int, ...],
         missing: tuple[int, ...],
         pool=None,
     ) -> np.ndarray:
-        """Rebuild missing data shards from [d, W, per] (shard-major) input.
+        """Rebuild missing data shards from [d, W, per] (shard-major) input,
+        a plain array or the stack `survivor_stack` prepared.
 
         Returns [len(missing), W, per]. The GET hot layout: survivors land
         contiguous per shard row, the native AVX2 GF apply consumes them
         without a transpose, and a thread pool splits the column range so
-        the apply scales past one core (ctypes releases the GIL).
+        the apply scales past one core (ctypes releases the GIL); a packed
+        stack is the decode mega-kernel's input as it stands.
         """
         d_, w, per = survivors.shape
+        packed = isinstance(survivors, SurvivorStack) and survivors.packed
+        if isinstance(survivors, SurvivorStack) and not packed:
+            survivors = survivors.array
         family_stats_add(self.family, "decode_blocks", w)
-        if (
-            self.family != FAMILY_CAUCHY
-            and self._jax is not None
-            and w * self.t >= int(os.environ.get("MINIO_TPU_DECODE_MIN_SHARDS", "64"))
-        ):
+        if self._decodes_on_device(w):
             from ..ops.bitrot_jax import _try_fused_decode, xla_decode
             from ..ops.highwayhash import MINIO_KEY
 
-            arr = survivors.transpose(1, 0, 2)  # [W, d, per]
             # degraded GET rides the decode mega-kernel when shapes allow
-            fused = _try_fused_decode(self._jax, arr, present, missing, MINIO_KEY)
+            if packed:
+                fused = _try_fused_decode(
+                    self._jax, survivors.array, present, missing, MINIO_KEY,
+                    packed_blocks=w,
+                )
+            else:
+                arr = survivors.transpose(1, 0, 2)  # [W, d, per]
+                fused = _try_fused_decode(self._jax, arr, present, missing, MINIO_KEY)
             if fused is not None:
                 return fused[0].transpose(1, 0, 2)
+            if packed:
+                # the kernel the stack was laid out for did not take it
+                arr = survivors.block_major()
             return xla_decode(self._jax, arr, present, missing).transpose(1, 0, 2)
         # the cauchy family, a group under the device floor, a CPU-plane
         # process: the host's GF apply, on the calling thread and the pool
         family_stats_add(self.family, "decode_host_blocks", w)
+        if packed:
+            survivors = survivors.block_major().transpose(1, 0, 2)
         with obs.phase("decode", "host"):
             return self._reconstruct_flat_host(survivors, present, missing, pool)
 

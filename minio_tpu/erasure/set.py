@@ -150,6 +150,55 @@ def shard_frames_snapshot() -> dict[str, int]:
         return dict(_SHARD_FRAMES)
 
 
+# copies into a decode group's survivor stack (erasure/coder.py
+# SurvivorStack), by what one copy moved — a shard's consecutive payloads
+# of a run as one strided array ("run"), or one block's payload ("block")
+# — and by the stack's layout: the decode mega-kernel's chunk-major input
+# ("packed") or [d, W, per] ("rows")
+_STACK_COPIES = {
+    (u, lay): 0 for u in ("run", "block") for lay in ("packed", "rows")
+}
+_STACK_COPIES_LOCK = threading.Lock()
+
+
+def _stack_copies_add(layout: str, runs: int, blocks: int) -> None:
+    with _STACK_COPIES_LOCK:
+        _STACK_COPIES["run", layout] += runs
+        _STACK_COPIES["block", layout] += blocks
+
+
+def stack_copies_snapshot() -> dict[tuple[str, str], int]:
+    with _STACK_COPIES_LOCK:
+        return dict(_STACK_COPIES)
+
+
+def stack_survivors(stack, present, stretches, got) -> None:
+    """Write a decode group's survivors into its stack, once. `stretches`
+    are the group's blocks in stack order, (run, first frame, frames) of a
+    run's consecutive frames; `got[run][shard]` the run's verified payloads
+    of that shard in frame order. Where they lie a frame apart in ONE read
+    buffer (`bitrot_io.FrameRun.rows`) a shard's stretch is one strided
+    copy; else — bytes on the copying path, inline data, whole-file-hash
+    shards, single-frame and unequal-length runs — a copy a block."""
+    by_run = by_block = w = 0
+    for ri, pos, n in stretches:
+        for k, i in enumerate(present):
+            payloads = got[ri][i]
+            rows = getattr(payloads, "rows", None)
+            if rows is not None:
+                stack.put(k, w, rows[pos : pos + n])
+                by_run += 1
+                continue
+            for j in range(n):
+                stack.put(
+                    k, w + j,
+                    np.frombuffer(payloads[pos + j], dtype=np.uint8)[None],
+                )
+            by_block += n
+        w += n
+    _stack_copies_add("packed" if stack.packed else "rows", by_run, by_block)
+
+
 def default_parity_count(drive_count: int) -> int:
     """Default storage-class parity by set width (reference
     internal/config/storageclass defaults)."""
@@ -1164,14 +1213,15 @@ class ErasureSet:
             }
             return runs, futs
 
-        def gather_window(win, runs, futs):
+        def gather_window(runs, futs) -> list[dict[int, list]]:
             """Resolve reads until every run has d shards, spilling to
             the next candidate shard on FAILURE — and, when a straggling
             drive blows the hedge budget, on LATENCY: extra parity reads
             race the straggler and decode around it, whichever reaches d
             first wins (the hedged-read policy; the reference instead
             pays the straggler's full latency before spilling). A run's
-            blocks share their reads, so they share their shards."""
+            blocks share their reads, so they share their shards: per run,
+            each shard's payloads as its read returned them."""
             got: list[dict[int, list]] = [{} for _ in runs]
             pending: dict[tuple[int, int], object] = dict(futs)
             rev = {f: k for k, f in pending.items()}
@@ -1283,88 +1333,85 @@ class ErasureSet:
                 fault_registry.stats_add(
                     "hedge_wins" if used & hedged_idx else "hedge_losses"
                 )
-            # per block, as `decode_window` takes them
-            by_block: list[dict[int, bytes]] = [{} for _ in win]
-            for (_pnum, _f_off, _pers, bis), g in zip(runs, got):
-                for idx, blks in g.items():
-                    for bi, blk in zip(bis, blks):
-                        by_block[bi][idx] = blk
-            return by_block
+            return got
 
-        def decode_window(win, got) -> list:
+        def decode_window(win, runs, got) -> list:
             """Per-block data buffers; same-pattern degraded blocks batch.
 
-            Every block assembles exactly ONCE into a pre-sized buffer
-            (shard payload views copy in directly — the old .tobytes()
-            per shard + b"".join double copy is gone; the single copy is
-            site "gather-join")."""
+            `got[ri]` holds run ri's payloads by shard. Every block
+            assembles exactly ONCE into a pre-sized buffer (shard payload
+            views copy in directly — the old .tobytes() per shard +
+            b"".join double copy is gone; the single copy is site
+            "gather-join")."""
             out: list = [None] * len(win)
-            groups: dict[tuple[tuple[int, ...], int], list[int]] = {}
-            for bi in range(len(win)):
-                present = tuple(sorted(got[bi].keys())[:d])
-                if present == tuple(range(d)):
-                    per = win[bi][1]
-                    with obs.phase("get", "join"):
-                        buf = bytearray(d * per)
-                        mv = memoryview(buf)
-                        for i in range(d):
-                            mv[i * per : (i + 1) * per] = got[bi][i]
-                    bufpool.count_copy("gather-join")
-                    out[bi] = buf
-                else:
+
+            def join(per: int, shard) -> bytearray:
+                with obs.phase("get", "join"):
+                    buf = bytearray(d * per)
+                    mv = memoryview(buf)
+                    for i in range(d):
+                        mv[i * per : (i + 1) * per] = shard(i)
+                bufpool.count_copy("gather-join")
+                return buf
+
+            # (pattern, shard size) -> the group's blocks as stretches of a
+            # run's consecutive frames, [run, first frame, frames]: the tail
+            # block's per differs from full blocks and cannot share a stack
+            groups: dict[tuple[tuple[int, ...], int], list[list[int]]] = {}
+            for ri, (_pnum, _f_off, pers, bis) in enumerate(runs):
+                frames = got[ri]
+                present = tuple(sorted(frames)[:d])
+                for pos, (bi, per) in enumerate(zip(bis, pers)):
+                    if present == tuple(range(d)):
+                        out[bi] = join(per, lambda i: frames[i][pos])
+                        continue
                     # survivor ingress: every frame fetched for a block
                     # that needs reconstruction (the full-shard cost the
                     # repair plan above avoids)
                     family_stats_add(
                         family, "degraded_ingress_bytes",
-                        len(got[bi]) * (fdig + win[bi][1]),
+                        len(frames) * (fdig + per),
                     )
-                    # group by (pattern, shard size): the tail block's per
-                    # differs from full blocks and cannot share a stack
-                    groups.setdefault((present, win[bi][1]), []).append(bi)
-            for (present, per), bis in groups.items():
+                    stretches = groups.setdefault((present, per), [])
+                    last = stretches[-1] if stretches else None
+                    if last and last[0] == ri and last[1] + last[2] == pos:
+                        last[2] += 1
+                    else:
+                        stretches.append([ri, pos, 1])
+            for (present, per), stretches in groups.items():
                 missing = tuple(i for i in range(d) if i not in present)
-                # build [d, W', per] directly: the contiguous layout the
-                # native GF apply consumes, no post-stack transpose
-                # copies. The stack is POOLED scratch — recycled the
-                # moment reconstruction returns (its outputs are fresh
-                # arrays, never views of the stack)
-                nb = d * len(bis) * per
-                stack_lease = bufpool.get_pool().acquire(nb) if zc else None
+                # the survivors, written once in the layout the rung that
+                # will decode this group takes (the mega-kernel's own input
+                # where it will; else [d, W', per], the contiguous layout
+                # the native GF apply consumes). The stack is POOLED
+                # scratch — recycled the moment reconstruction returns (its
+                # outputs are fresh arrays, never views of the stack)
+                stack = None
                 try:
                     with obs.phase("get", "stack"):
-                        if stack_lease is not None:
-                            survivors = stack_lease.array[:nb].reshape(
-                                d, len(bis), per
-                            )
-                        else:
-                            survivors = np.empty(
-                                (d, len(bis), per), dtype=np.uint8
-                            )
-                        for k, i in enumerate(present):
-                            for w, bi in enumerate(bis):
-                                survivors[k, w] = np.frombuffer(
-                                    got[bi][i], dtype=np.uint8
-                                )
+                        stack = coder.survivor_stack(
+                            sum(n for _ri, _pos, n in stretches), per,
+                            len(missing), pooled=zc,
+                        )
+                        stack_survivors(stack, present, stretches, got)
                     # not a leaf: the `decode` phases tile it
-                    with obs.phase("get", "decode_wait", blocks=len(bis),
+                    with obs.phase("get", "decode_wait",
+                                   blocks=stack.shape[1],
                                    missing=len(missing)):
                         rec = coder.reconstruct_data_flat(
-                            survivors, present, missing, pool
+                            stack, present, missing, pool
                         )
                 finally:
-                    if stack_lease is not None:
-                        stack_lease.release()
+                    if stack is not None:
+                        stack.release()
                 mj = {i: j for j, i in enumerate(missing)}
-                for w, bi in enumerate(bis):
-                    with obs.phase("get", "join"):
-                        buf = bytearray(d * per)
-                        mv = memoryview(buf)
-                        for i in range(d):
-                            src = rec[mj[i], w] if i in mj else got[bi][i]
-                            mv[i * per : (i + 1) * per] = src
-                    bufpool.count_copy("gather-join")
-                    out[bi] = buf
+                w = 0
+                for ri, pos, n in stretches:
+                    for p in range(pos, pos + n):
+                        out[runs[ri][3][p]] = join(per, lambda i: (
+                            rec[mj[i], w] if i in mj else got[ri][i][p]
+                        ))
+                        w += 1
             return out
 
         # ---- repair-plan execution (erasure/shardread.py) --------------
@@ -1417,7 +1464,11 @@ class ErasureSet:
                 return serve_slice(out, lo - base, hi - base)
 
             def from_frames(blk, frames):
-                block = decode_window([blk], [frames])[0]
+                pnum, per, f_off, _lo, _hi = blk
+                block = decode_window(
+                    [blk], [(pnum, f_off, (per,), [0])],
+                    [{i: [f] for i, f in frames.items()}],
+                )[0]
                 return serve_slice(block, blk[3], blk[4])
 
             def read_shard_block(part_num: int, idx: int, per: int, f_off: int):
@@ -1444,11 +1495,11 @@ class ErasureSet:
                 # the window's reads were submitted as the last one's
                 # readahead: what is left of them is what a GET waits for
                 with obs.phase("get", "read_wait", blocks=len(win)):
-                    got = gather_window(win, runs, futs)
-                futs = {}
+                    got = gather_window(runs, futs)
+                win_runs, futs = runs, {}
                 if wi + 1 < len(windows):
                     runs, futs = start_window(windows[wi + 1])  # readahead
-                blocks = decode_window(win, got)
+                blocks = decode_window(win, win_runs, got)
                 for (pnum, per, f_off, lo, hi), block in zip(win, blocks):
                     if seg_sink is not None:
                         # the decode always materializes the FULL stripe

@@ -233,7 +233,9 @@ def span(trace_type: str, name: str, **fields):
 # (windowed) read path (erasure/set.py): `start` (once per such read:
 # the first window's shard reads submitted), `read_wait` (a window's shard
 # reads until every block has d shards, hedges included), `stack`
-# (survivors into [d, W, per]), `decode_wait` (the whole
+# (a decode group's survivors written once, a strided copy per shard and
+# run, in the layout its rung takes: the mega-kernel's chunk-major input
+# with its pad rows zeroed, else [d, W, per]), `decode_wait` (the whole
 # reconstruct_data_flat call; the `decode` leaves tile it), `join` (the
 # per-block gather-join copy), `cache_fill` (the block offered to the
 # range-segment cache), `respond` (yield -> resumption: the front end's
@@ -241,7 +243,8 @@ def span(trace_type: str, name: str, **fields):
 # `shard_io` on the read pool's threads (a run of frames: read_file + verify_run).
 # `decode` phases are the leaves of one device reconstruct
 # (ops/bitrot_jax.py, erasure/coder.py): `pad` (survivors made
-# block-major and zero-padded to the kernel's batch), `pack`, `h2d`,
+# block-major and zero-padded to the kernel's batch), `pack` (neither
+# runs for a group `stack` laid out packed), `h2d`,
 # `kernel` (call -> ready; a first call's trace-and-lower too), `d2h`,
 # `unpack`; `host` is a group rebuilt by the native/numpy GF apply.
 PHASES = {

@@ -382,36 +382,68 @@ def xla_decode(codec, survivors, present, missing) -> np.ndarray:
     return host
 
 
-def _try_fused_decode(codec, survivors, present, missing, key):
+def _fused_cm_on() -> bool:
+    import os
+
+    return os.environ.get("MINIO_TPU_FUSED_CM", "1") != "0"
+
+
+def fused_decode_takes(d: int, m: int, b: int, n: int) -> bool:
+    """Whether the next `_try_fused_decode` of b blocks of d survivors, n
+    bytes a shard, m to rebuild, will go to the mega-kernel: the knob, the
+    shapes, and no cooldown after a failure. A caller that lays the
+    survivors out chunk-major itself asks this first; it observes and
+    changes nothing."""
+    if not _fused_cm_on() or _fused_dec_cooldown > 0:
+        return False
+    from . import fused_pallas as fp
+
+    return fp.supports(d, m, -(-b // 16) * 16, n)
+
+
+def _try_fused_decode(codec, survivors, present, missing, key,
+                      packed_blocks: int | None = None):
     """Chunk-major fused reconstruct+verify+hash when shapes allow.
+
+    `survivors` is [B, d, n] (any strides), padded and packed here; or,
+    with `packed_blocks` = B, the kernel's own input already: chunk-major
+    [n / CHUNK_BYTES, bpad, d, CHUNK_BYTES], B rounded up to 16, the pad
+    rows zero (erasure/coder.py SurvivorStack), and `pad` and `pack` are
+    skipped.
 
     Returns (rebuilt [B, m, n], rebuilt_digests [B, m, 32], survivor_
     digests [B, d, 32]) as numpy, or None for the XLA path."""
     global _fused_dec_cooldown, _fused_dec_backoff
-    import os
 
-    if os.environ.get("MINIO_TPU_FUSED_CM", "1") == "0":
+    if not _fused_cm_on():
         return None
     if _fused_dec_cooldown > 0:
         _fused_dec_cooldown -= 1
         return None
     from . import fused_pallas as fp
 
-    surv = np.asarray(survivors, dtype=np.uint8)
-    b, d, n = surv.shape
+    if packed_blocks is None:
+        surv = np.asarray(survivors, dtype=np.uint8)
+        b, d, n = surv.shape
+        bpad = -(-b // 16) * 16
+    else:
+        packed = survivors
+        nc, bpad, d, cb = packed.shape
+        b, n = packed_blocks, nc * cb
     m = len(missing)
-    bpad = -(-b // 16) * 16
     if not fp.supports(d, m, bpad, n):
         return None
     took: dict[str, float] = {}
     try:
-        with obs.phase("decode", "pad"):
-            if bpad != b:
-                surv = np.concatenate(
-                    [surv, np.zeros((bpad - b, d, n), dtype=np.uint8)], axis=0
-                )
-        with obs.phase("decode", "pack"):
-            packed = fp.pack_chunk_major(surv)
+        if packed_blocks is None:
+            with obs.phase("decode", "pad"):
+                if bpad != b:
+                    surv = np.concatenate(
+                        [surv, np.zeros((bpad - b, d, n), dtype=np.uint8)],
+                        axis=0,
+                    )
+            with obs.phase("decode", "pack"):
+                packed = fp.pack_chunk_major(surv)
         with obs.phase("decode", "h2d"):
             surv_cm = jax.block_until_ready(jax.device_put(packed))
             del packed

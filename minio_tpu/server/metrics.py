@@ -1084,13 +1084,25 @@ def _g_api_tpu(server) -> list[str]:
          "minio_fault_hedge_* series of /api/fault")
     # the unit of a shard read on that path: frames over the phase
     # table's `get`/`shard_io` calls are frames per read
-    from ..erasure.set import shard_frames_snapshot
+    from ..erasure.set import shard_frames_snapshot, stack_copies_snapshot
 
     _fmt(out, "minio_tpu_get_shard_frames_total", "counter",
          [({"unit": u}, n) for u, n in sorted(shard_frames_snapshot().items())],
          "Shard frames the reconstructing read path verified, by the read "
          "that held them: one of several consecutive frames of a shard "
          "file (run) or of a single frame (block)")
+    # how the survivors reached a decode group's stack: ONE strided copy
+    # per (shard, run) into the layout the decoding rung takes, or a copy a
+    # block where a run's payloads are not one array
+    _fmt(out, "minio_tpu_get_stack_copies_total", "counter",
+         [({"unit": u, "layout": lay}, n)
+          for (u, lay), n in sorted(stack_copies_snapshot().items())],
+         "Copies into the survivor stack of a degraded read's decode group, "
+         "by what one copy moved (run: a shard's consecutive payloads of "
+         "one read as one strided array; block: one payload) and by the "
+         "stack's layout (packed: the decode mega-kernel's chunk-major "
+         "input, written once; rows: [d, W, per] for the XLA rung and the "
+         "host's GF apply)")
     # device runtime (ops/runtime.py): which device this process holds and
     # what it compiled vs loaded from the persistent compile cache; zeros
     # and no device row on a CPU-plane process

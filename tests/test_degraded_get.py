@@ -230,3 +230,125 @@ def test_the_profiler_gets_the_leaves_and_no_enclosing_phase():
     # an enclosing phase would win every idle gap; the pool's threads would bury it
     assert obs.phase("get", "decode_wait")._ann is None
     assert obs.phase("get", "shard_io")._ann is None
+
+
+# --------------------------------------------------------------------------
+# the packed path, end to end: the survivors written once as the decode
+# mega-kernel takes them. Off the TPU `fp.supports` is false and the kernel
+# does not lower, so both are stood in for; the served path around them is
+# the shipped one. These run last: the tests above count a process that never
+# ran the fused rung.
+# --------------------------------------------------------------------------
+
+
+def stack_copies(series) -> dict:
+    return {(lb["unit"], lb["layout"]): v
+            for lb, v in series["minio_tpu_get_stack_copies_total"]}
+
+
+def test_the_stack_copies_counter_is_on_the_first_scrape(served):
+    *_, first = served
+    assert set(stack_copies(parse_metrics(first))) \
+        == {(u, lay) for u in ("run", "block") for lay in ("packed", "rows")}
+    assert "HELP minio_tpu_get_stack_copies_total" in first
+
+
+def degraded_get_moves(cli, drives, body, offline=(3, 11)):
+    """One degraded GET: what the counters and the phase table moved by."""
+    fault.clear()
+    time.sleep(0.05)
+    take_offline(cli, drives, offline)
+    try:
+        before, phases = scrape(cli), obs.phases_snapshot()
+        r = cli.request("GET", f"/{BUCKET}/{KEY}")
+    finally:
+        fault.clear()
+    assert r.status == 200 and r.body == body
+    time.sleep(0.1)  # the generator books its last `respond` after the body is out
+    after, now = scrape(cli), obs.phases_snapshot()
+    copies = {k: v - stack_copies(before)[k] for k, v in stack_copies(after).items()
+              if v != stack_copies(before)[k]}
+    calls = {k: now[k][2] - phases[k][2] for k in now}
+
+    def moved(name, **match):
+        return total(after, name, **match) - total(before, name, **match)
+
+    return copies, calls, moved
+
+
+def test_off_the_fused_rung_the_stack_is_rows_and_a_copy_a_run(served):
+    _, cli, drives, body, _ = served
+    copies, calls, moved = degraded_get_moves(cli, drives, body)
+    # one window of 8 blocks, one run: a strided copy for each of the 8 survivors
+    assert copies == {("run", "rows"): 8}
+    assert calls["get", "stack"] == 1 and calls["decode", "pad"] == 1
+    assert moved("minio_tpu_decode_dispatches_total", rung="xla") == 1
+
+
+@pytest.fixture
+def fused_rung_stood_in(monkeypatch):
+    from minio_tpu.ops import bitrot_jax
+    from minio_tpu.ops import fused_pallas as fp
+
+    from test_survivor_stack import numpy_kernel, shapes_only
+
+    calls: list = []
+    monkeypatch.setattr(fp, "supports", shapes_only)
+    monkeypatch.setattr(fp, "fused_decode_hash_cm", numpy_kernel(calls))
+    # (restored when the test ends, whatever a failing stand-in left there)
+    monkeypatch.setattr(bitrot_jax, "_fused_dec_cooldown", 0)
+    monkeypatch.setattr(bitrot_jax, "_fused_dec_backoff", 8)
+    return calls, fp, monkeypatch
+
+
+@pytest.mark.parametrize("offline,m", [((3, 11), 1), ((0, 2, 9, 15), None),
+                                       ((1, 2, 3, 4, 10, 11, 12, 13), None)],
+                         ids=["pair", "four-off", "eight-off"])
+def test_a_packed_group_is_written_once_and_skips_pad_and_pack(
+        served, fused_rung_stood_in, offline, m):
+    _, cli, drives, body, _ = served
+    kernel_calls, *_ = fused_rung_stood_in
+    copies, calls, moved = degraded_get_moves(cli, drives, body, offline)
+    order = reference_decode.shard_order(BUCKET, KEY, 16)
+    lost = sum(1 for i in offline if order[i] < 8)
+    assert lost == (m or lost) >= 1
+    # 8 copies a window, all `run`/`packed`: the kernel's input is the stack
+    assert copies == {("run", "packed"): 8}
+    assert [c[0] for c in kernel_calls] == [(128, 16, 8, 1024)]
+    assert len(kernel_calls[0][2]) == lost
+    assert calls["get", "stack"] == 1 == calls["get", "decode_wait"]
+    assert calls["decode", "pad"] == 0 == calls["decode", "pack"]
+    for p in ("h2d", "kernel", "d2h", "unpack"):
+        assert calls["decode", p] == 1
+    assert calls["decode", "host"] == 0
+    # the decode counters move exactly as for a group packed by the entry
+    assert moved("minio_tpu_decode_dispatches_total", rung="fused", missing=str(lost)) == 1
+    assert moved("minio_tpu_decode_dispatches_total") == 1
+    assert moved("minio_tpu_decode_device_blocks_total", rung="fused") == 8
+    assert moved("minio_tpu_decode_pad_blocks_total") == 8
+    assert moved("minio_tpu_decode_blocks_total") == 8
+    assert moved("minio_tpu_decode_host_blocks_total") == 0
+    assert moved("minio_tpu_fused_decode_failures_total") == 0
+
+
+def test_a_kernel_that_raises_hands_the_rows_to_the_xla_rung(served, fused_rung_stood_in):
+    _, cli, drives, body, _ = served
+    _, fp, mp = fused_rung_stood_in
+
+    def raises(*a, **k):
+        raise RuntimeError("stand-in: the kernel call fails")
+
+    mp.setattr(fp, "fused_decode_hash_cm", raises)
+    copies, calls, moved = degraded_get_moves(cli, drives, body)
+    # laid out packed, refused by the kernel, its rows taken back out for XLA
+    assert copies == {("run", "packed"): 8}
+    assert moved("minio_tpu_fused_decode_failures_total") == 1
+    assert moved("minio_tpu_decode_dispatches_total", rung="xla") == 1
+    assert moved("minio_tpu_decode_dispatches_total", rung="fused") == 0
+    assert moved("minio_tpu_decode_device_blocks_total", rung="xla") == 8
+    assert calls["decode", "pack"] == 0 and calls["decode", "pad"] == 1  # xla_decode's own
+    # while the kernel cools down the next group is laid out as rows
+    copies, calls, moved = degraded_get_moves(cli, drives, body)
+    assert copies == {("run", "rows"): 8}
+    assert moved("minio_tpu_decode_dispatches_total", rung="xla") == 1
+    assert moved("minio_tpu_fused_decode_failures_total") == 0
