@@ -3,9 +3,12 @@ ISSUE 29 names; `correct` has teeth on the read side (`broken_get_serve.py`:
 the rest of a run driven with the read path broken one step down must come
 out `correct: false` by the check named, at rehearsal size; PERF.md gives the
 readings on the chip at the cell's own size); every seed gives the same
-reads in another order; the decode's work by hand; the read-side readers on
-a hand-written exposition, and None — never 0, never an exception — from a
-program without the rows (the parent under these benchmark files)."""
+reads in another order; the decode's work by hand; the read-side readers —
+PR 29's thirteen and the five of PR 33 that read what PRs 30 and 32 changed —
+on a hand-written exposition, and None — never 0, never an exception — from
+a program without the rows (an older commit under these benchmark files).
+The cell's entries in `BENCHMARK.json` are held by name, by value and by
+order among themselves; a later PR appends after them."""
 
 import json
 import os
@@ -28,29 +31,51 @@ READERS = ["decode_roofline", "decode_blocks_per_call", "decode_device_block_sha
            "decode_window_first_calls", "get_read_wait_ms", "get_decode_wait_ms", "get_join_ms",
            "decode_host_copy_ms", "decode_link_ms", "decode_kernel_ms",
            "get_threads_cpu_s_per_gib", "read_pool_cpu_s_per_gib", "get_hedge_reads_per_get"]
+# PR 33: what PRs 30 and 32 changed (run reads, the one-pass stack) and the hand-over
+LATER = ["get_stack_ms", "get_respond_ms", "get_shard_reads_per_get", "get_frames_per_read",
+         "get_stack_copies_per_get"]
 
 
 # ---- the cell is what the issue names --------------------------------------
 
 
-def test_the_cell_its_configuration_and_its_metrics_are_appended():
-    assert BENCH["workloads"][-1] == {
-        "name": CELL, "config": CONFIG, "traffic": MIX, "chips": 1,
-        "why": BENCH["workloads"][-1]["why"]}
-    assert BENCH["configs"][-1]["name"] == CONFIG
-    assert sorted(BENCH["configs"][-1]["reduced"]) == ["clients", "drives_are_directories",
-                                                       "objects"]
-    assert [m["name"] for m in BENCH["per_layer"][-len(READERS):]] == READERS
-    for m in BENCH["per_layer"][-len(READERS):]:
-        assert m["workloads"] == [CELL] and m["moves"] == "s3_mib_s"
-    # the tail is not this cell's: its entry still lists the one cell it listed
-    p95 = next(m for m in BENCH["end_to_end"] if m["name"] == "s3_p95_ms")
-    assert p95["workloads"] == ["ec8p8-16d.speedtest-put"]
+def test_the_cell_its_configuration_and_its_metrics_are_held_by_name_and_order():
+    """By name, by value and by order among themselves, never by distance
+    from the end: what a later PR appends to any list stands after them."""
+    cells = [w for w in BENCH["workloads"] if w["name"] == CELL]
+    assert cells == [{"name": CELL, "config": CONFIG, "traffic": MIX, "chips": 1,
+                      "why": cells[0]["why"]}]
+    configs = [c for c in BENCH["configs"] if c["name"] == CONFIG]
+    assert len(configs) == 1
+    assert sorted(configs[0]["reduced"]) == ["clients", "drives_are_directories", "objects"]
+    # the thirteen follow the first seven and PR 25's fourteen, in their order
+    names = [m["name"] for m in BENCH["per_layer"]]
+    assert names[21:34] == READERS and len(set(names)) == len(names)
+    for m in BENCH["per_layer"][21:34]:
+        assert CELL in m["workloads"] and m["moves"] == "s3_mib_s"
     from chipbench.run import metric_names
 
-    assert {m["name"] for m in metric_names(BENCH, "end_to_end", CELL)} == {"s3_mib_s", "setup_s"}
-    assert {m["name"] for m in metric_names(BENCH, "per_layer", CELL)} == set(READERS) | {
+    assert {m["name"] for m in metric_names(BENCH, "end_to_end", CELL)} >= {"s3_mib_s", "setup_s"}
+    assert {m["name"] for m in metric_names(BENCH, "per_layer", CELL)} >= set(READERS) | {
         "server_cpu_s_per_gib", "window_compiles", "device_idle_share"}
+
+
+def test_the_tail_and_the_five_later_readers_are_this_cells_too():
+    """PR 33's additions, by name: the cell reports the tail under the one
+    bound the metric has, and five readers of what PRs 30 and 32 changed
+    follow the thirteen in their order."""
+    p95 = next(m for m in BENCH["end_to_end"] if m["name"] == "s3_p95_ms")
+    assert p95["workloads"][:2] == ["ec8p8-16d.speedtest-put", CELL] and p95["bound"] == 0.25
+    names = [m["name"] for m in BENCH["per_layer"]]
+    assert names[34:39] == LATER
+    for m in BENCH["per_layer"][34:39]:
+        assert CELL in m["workloads"] and m["moves"] == "s3_mib_s"
+        assert m["source"] == "program_counter"
+        assert m["layer"] == {"get_respond_ms": "front end"}.get(m["name"], "erasure read path")
+    from chipbench.run import metric_names
+
+    assert {m["name"] for m in metric_names(BENCH, "end_to_end", CELL)} >= {"s3_p95_ms"}
+    assert {m["name"] for m in metric_names(BENCH, "per_layer", CELL)} >= set(LATER)
 
 
 def test_degraded_get_is_8_clients_reading_16_objects_of_64_mib_with_d03_and_d11_offline():
@@ -136,9 +161,11 @@ GET = ("start", "read_wait", "stack", "decode_wait", "join", "cache_fill", "resp
 DECODE = ("pad", "pack", "h2d", "kernel", "d2h", "unpack", "host")
 
 
-def expo(seconds=None, cpu=None, calls=None, by_m=None, rebuilt=0, first=0, hedges=0) -> dict:
+def expo(seconds=None, cpu=None, calls=None, by_m=None, rebuilt=0, first=0, hedges=0,
+         frames=None, copies=None) -> dict:
     """Every row the program exports for the read side, zero unless given;
-    `by_m`: {shards rebuilt: (fused dispatches, fused blocks)}."""
+    `by_m`: {shards rebuilt: (fused dispatches, fused blocks)}; `frames`:
+    {unit: frames verified}; `copies`: {(unit, layout): stack copies}."""
     lines = []
     for series, table in (("seconds", seconds), ("cpu_seconds", cpu), ("calls", calls)):
         for layer, names in (("get", GET), ("decode", DECODE)):
@@ -155,19 +182,27 @@ def expo(seconds=None, cpu=None, calls=None, by_m=None, rebuilt=0, first=0, hedg
     lines.append(f'minio_tpu_decode_first_calls_total{{rung="fused",missing="2",batch="16"}} {first}')
     for e, v in (("reads", hedges), ("wins", 0), ("losses", 0)):
         lines.append(f'minio_tpu_get_hedges_total{{event="{e}"}} {v}')
+    for u in ("block", "run"):
+        lines.append(f'minio_tpu_get_shard_frames_total{{unit="{u}"}} {(frames or {}).get(u, 0)}')
+        lines += [f'minio_tpu_get_stack_copies_total{{unit="{u}",layout="{lay}"}} '
+                  f'{(copies or {}).get((u, lay), 0)}' for lay in ("packed", "rows")]
     return parse_metrics("\n".join(lines))
 
 
-BEFORE = expo(calls={("get", "start"): 10}, seconds={("get", "read_wait"): 1.0},
-              by_m={1: (80, 640)}, rebuilt=640, hedges=4)
+BEFORE = expo(calls={("get", "start"): 10, ("get", "shard_io"): 640},
+              seconds={("get", "read_wait"): 1.0, ("get", "stack"): 0.5},
+              by_m={1: (80, 640)}, rebuilt=640, hedges=4,
+              frames={"run": 5120}, copies={("run", "packed"): 640})
 TRACED_BEFORE = expo(by_m={1: (130, 1040), 2: (3, 9)}, rebuilt=1060)
 AFTER = expo(
-    calls={("get", "start"): 30},
+    calls={("get", "start"): 30, ("get", "shard_io"): 1928},
     seconds={("get", "read_wait"): 5.0, ("get", "decode_wait"): 9.0, ("get", "join"): 1.0,
+             ("get", "stack"): 2.5, ("get", "respond"): 14.0,
              ("decode", "pad"): 1.0, ("decode", "pack"): 2.0, ("decode", "unpack"): 1.0,
              ("decode", "h2d"): 0.5, ("decode", "d2h"): 1.5, ("decode", "kernel"): 2.0},
     cpu={("get", "decode_wait"): 3.0, ("get", "join"): 1.0, ("get", "shard_io"): 6.0},
-    by_m={1: (230, 1840), 2: (5, 15)}, rebuilt=1920, first=1, hedges=14)
+    by_m={1: (230, 1840), 2: (5, 15)}, rebuilt=1920, first=1, hedges=14,
+    frames={"run": 15360, "block": 8}, copies={("run", "packed"): 1900, ("block", "rows"): 40})
 WANT = {
     "decode_blocks_per_call": (1200 + 15) / (150 + 5),
     "decode_device_block_share": 100.0 * 1215 / 1280,
@@ -184,6 +219,12 @@ WANT = {
     # traced: 100 m=1 dispatches of 8 blocks and 2 m=2 dispatches of 3 by the
     # counters, 51 programs in the trace, 0.01 s busy
     "decode_roofline": 100 * (51 * (800 * 1_179_936 + 6 * 1_311_040) / 102 / 819e9) / 0.01,
+    # the five of PR 33, over the same 20 GETs
+    "get_stack_ms": 100.0,            # 2 s
+    "get_respond_ms": 700.0,
+    "get_shard_reads_per_get": 64.4,  # 1288 reads: 64 a GET and 8 hedged
+    "get_frames_per_read": (1280 * 8 + 8 * 1) / 1288,  # 1280 runs of 8 frames, 8 reads of one
+    "get_stack_copies_per_get": 65.0,  # 1260 of a run + 40 of a block, both layouts
 }
 
 
@@ -196,16 +237,16 @@ def window(before=BEFORE, after=AFTER, **kw):
     return metrics.Window(**base)
 
 
-def test_the_readers_are_the_cells_thirteen():
-    assert sorted(WANT) == sorted(READERS)
+def test_every_reader_of_the_cell_has_a_value_by_hand():
+    assert sorted(WANT) == sorted(READERS + LATER) and len(READERS) == 13 and len(LATER) == 5
 
 
-@pytest.mark.parametrize("name", READERS)
+@pytest.mark.parametrize("name", READERS + LATER)
 def test_reader_reads_the_value(name):
     assert metrics.reader(name).read(window()) == pytest.approx(WANT[name])
 
 
-@pytest.mark.parametrize("name", READERS)
+@pytest.mark.parametrize("name", READERS + LATER)
 def test_a_program_without_the_rows_reads_nothing_and_does_not_raise(name):
     """These files are laid over the parent's checkout too: it exports the
     decode counters by rung only, and no `get` or `decode` phase."""
@@ -228,6 +269,23 @@ def test_the_roofline_is_never_zero_and_needs_a_device_trace(name):
     quiet = window(before=AFTER, after=AFTER, traced_before=AFTER)
     assert metrics.reader(name).read(quiet) is None  # no decode in the trace: nothing, not 0
     assert metrics.reader("get_read_wait_ms").read(quiet) is None  # no GET: no mean
+
+
+@pytest.mark.parametrize("name", LATER)
+def test_a_window_without_a_get_reads_nothing(name):
+    assert metrics.reader(name).read(window(before=AFTER, after=AFTER, traced_before=AFTER)) is None
+
+
+@pytest.mark.parametrize("name,want", [("get_frames_per_read", None),
+                                       ("get_stack_copies_per_get", None),
+                                       ("get_shard_reads_per_get", 64.4)])
+def test_a_program_with_the_read_clock_and_neither_counter_reads_what_it_has(name, want):
+    """PR 29's tree: the `get` phases, no frame and no stack-copy counter."""
+    def strip(series):
+        return {k: v for k, v in series.items() if not k.startswith(
+            ("minio_tpu_get_shard_frames", "minio_tpu_get_stack_copies"))}
+    got = metrics.reader(name).read(window(before=strip(BEFORE), after=strip(AFTER)))
+    assert got is None if want is None else got == pytest.approx(want)
 
 
 # ---- the controls -----------------------------------------------------------
