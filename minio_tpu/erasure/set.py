@@ -172,6 +172,33 @@ def stack_copies_snapshot() -> dict[tuple[str, str], int]:
         return dict(_STACK_COPIES)
 
 
+# bytes of every GET body a read of `_read_range_inner` finished, by the
+# path that produced them: the native span pass ("native": every data
+# shard local and present) or the reconstructing windowed pipeline
+# ("windowed"). A read that the client abandons books nothing.
+_GET_BYTES = {"native": 0, "windowed": 0}
+# shards of acknowledged PUTs that no drive took: a drive whose error was
+# set (offline, failed mid-stream) keeps no shard of the object, which is
+# then queued for heal
+_PUT_OFFLINE_SHARDS = 0
+_BODY_COUNTERS_LOCK = threading.Lock()
+
+
+def _get_bytes_add(path: str, n: int) -> None:
+    with _BODY_COUNTERS_LOCK:
+        _GET_BYTES[path] += n
+
+
+def get_bytes_snapshot() -> dict[str, int]:
+    with _BODY_COUNTERS_LOCK:
+        return dict(_GET_BYTES)
+
+
+def put_offline_shards_snapshot() -> int:
+    with _BODY_COUNTERS_LOCK:
+        return _PUT_OFFLINE_SHARDS
+
+
 def stack_survivors(stack, present, stretches, got) -> None:
     """Write a decode group's survivors into its stack, once. `stretches`
     are the group's blocks in stack order, (run, first frame, frames) of a
@@ -570,6 +597,7 @@ class ErasureSet:
         # and then failed before rename_data swept the staging dir — the
         # staged bytes must not outlive the PUT (the streaming path
         # sweeps the same way after its commit)
+        self._note_partial_put(bucket, obj, errs)
         self._sweep_staging(
             tmp_id, (d for d, e in zip(self.disks, errs) if e is not None)
         )
@@ -759,8 +787,36 @@ class ErasureSet:
                 except Exception:  # noqa: BLE001 — best-effort cleanup
                     pass
             raise
+        # counted as the commit ends (readers divide by `put`/`commit` calls),
+        # before the sweep of sixteen staging dirs
+        self._note_partial_put(bucket, obj, errs)
         self._sweep_staging(tmp_id, self.disks)
         return self._to_object_info(bucket, obj, fi)
+
+    def _note_partial_put(self, bucket: str, obj: str, errs) -> None:
+        """An acknowledged PUT that some drives took no shard of (quorum
+        held without them): count the shards left out and queue the object
+        for heal, as a degraded read does (the reference's addPartial,
+        /root/reference/cmd/erasure-object.go) — until then only a GET or
+        the scanner would find it short. The queue deduplicates and is
+        bounded (erasure/background.py MRFQueue)."""
+        global _PUT_OFFLINE_SHARDS
+        lost = sum(e is not None for e in errs)
+        if lost:
+            with _BODY_COUNTERS_LOCK:
+                _PUT_OFFLINE_SHARDS += lost
+            self._queue_for_heal(bucket, obj)
+
+    def _queue_for_heal(self, bucket: str, obj: str) -> None:
+        """Hand an object short of shards to the heal queue's hook."""
+        if self.on_degraded is not None:
+            try:
+                self.on_degraded(bucket, obj)
+            # miniovet: ignore[error-taint] -- observer callback
+            # isolation: a failing heal-enqueue hook must never fail
+            # the request it was observing
+            except Exception:  # noqa: BLE001
+                pass
 
     def _stream_native(
         self,
@@ -997,15 +1053,9 @@ class ErasureSet:
 
         def report_degraded():
             nonlocal degraded_reported
-            if not degraded_reported and self.on_degraded is not None:
+            if not degraded_reported:
                 degraded_reported = True
-                try:
-                    self.on_degraded(bucket, obj)
-                # miniovet: ignore[error-taint] -- observer callback
-                # isolation: a failing heal-enqueue hook must never fail
-                # the GET it was observing
-                except Exception:  # noqa: BLE001
-                    pass
+                self._queue_for_heal(bucket, obj)
 
         if len(sources) < self.n:
             report_degraded()  # some drive lacks this version entirely
@@ -1095,6 +1145,10 @@ class ErasureSet:
             path_cache: dict[int, list[str] | None] = {}
             k = 0
             ok = True
+            # the span reads of this read, booked as one call when its
+            # native part ends (the body's end, or the fall back below)
+            native_reads = obs.PhaseSum("get", "native")
+            native_bytes = 0
             while k < len(plan):
                 pnum = plan[k][0]
                 if pnum not in path_cache:
@@ -1121,14 +1175,15 @@ class ErasureSet:
                 arrs = np.asarray(
                     [(s[2], s[1], s[3], s[4]) for s in span], dtype=np.int64
                 )
-                out = np.empty(tot, dtype=np.uint8)
-                rc = native.dp_get_span(
-                    paths, d, MINIO_KEY,
-                    np.ascontiguousarray(arrs[:, 0]),
-                    np.ascontiguousarray(arrs[:, 1]),
-                    np.ascontiguousarray(arrs[:, 2]),
-                    np.ascontiguousarray(arrs[:, 3]), out,
-                )
+                with native_reads:
+                    out = np.empty(tot, dtype=np.uint8)
+                    rc = native.dp_get_span(
+                        paths, d, MINIO_KEY,
+                        np.ascontiguousarray(arrs[:, 0]),
+                        np.ascontiguousarray(arrs[:, 1]),
+                        np.ascontiguousarray(arrs[:, 2]),
+                        np.ascontiguousarray(arrs[:, 3]), out,
+                    )
                 if rc != tot:
                     if rc < 0 and rc != native.DP_GET_ENOMEM:
                         # -(block*64 + shard + 1): mark the shard bad
@@ -1154,6 +1209,9 @@ class ErasureSet:
                 mv = memoryview(out)
                 for o in range(0, tot, 1 << 20):
                     yield mv[o : o + (1 << 20)]
+                native_bytes += tot
+            native_reads.book()
+            _get_bytes_add("native", native_bytes)
             if ok:
                 return
             plan = plan[k:]  # resume on the reconstructing path
@@ -1481,6 +1539,7 @@ class ErasureSet:
                 from_frames=from_frames, hedge_budget=hedge_budget,
                 fire_fields={"bucket": bucket, "object": obj},
             )
+            _get_bytes_add("windowed", sum(b[4] - b[3] for b in plan))
             return
 
 
@@ -1518,6 +1577,7 @@ class ErasureSet:
                     # resumed, maybe on another thread of the I/O pool: a
                     # thread's CPU clock says nothing across that
                     responding.book(cpu=False)
+            _get_bytes_add("windowed", sum(b[4] - b[3] for b in plan))
         finally:
             # abandoned iterator (client hung up) or error: don't let
             # readahead reads+verifies hog the shared pool

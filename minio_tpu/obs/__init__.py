@@ -36,6 +36,7 @@ from .trace import (  # noqa: F401
     TYPE_TPU,
     Phase,
     PhaseClock,
+    PhaseSum,
     Span,
     active,
     bind_context,

@@ -241,6 +241,9 @@ def span(trace_type: str, name: str, **fields):
 # range-segment cache), `respond` (yield -> resumption: the front end's
 # write and the executor hop; wall only, the thread may change), and
 # `shard_io` on the read pool's threads (a run of frames: read_file + verify_run).
+# `native` is the healthy GET's own: the native span reads of one read
+# (pread + bitrot verify + assembly in one C++ pass, 16 MiB a span), summed
+# and booked as ONE call when that read's native part ends.
 # `decode` phases are the leaves of one device reconstruct
 # (ops/bitrot_jax.py, erasure/coder.py): `pad` (survivors made
 # block-major and zero-padded to the kernel's batch), `pack` (neither
@@ -253,7 +256,7 @@ PHASES = {
     "put": ("ingest", "stage", "encode_wait", "frame", "md5",
             "drive_write", "commit", "drive_io"),
     "get": ("start", "read_wait", "stack", "decode_wait", "join",
-            "cache_fill", "respond", "shard_io"),
+            "cache_fill", "respond", "shard_io", "native"),
     "decode": ("pad", "pack", "h2d", "kernel", "d2h", "unpack", "host"),
 }
 _PHASE_TYPES = {"dispatch": TYPE_TPU, "put": TYPE_INTERNAL,
@@ -322,6 +325,49 @@ class PhaseClock:
                 _PHASE_TYPES[self._layer], f"{self._layer}.{self._name}",
                 req_id, next(_span_ids), parent_id, wall,
             ))
+
+
+class PhaseSum:
+    """Several stretches of one phase booked as ONE call: the native span
+    reads of a healthy GET, a `with` block each, between which the
+    generator yields to the front end and may change threads. Each stretch
+    adds its wall and thread CPU seconds (and is a TraceAnnotation like a
+    `phase()` leaf); `book()` books the sums as one call, so a reader of
+    the table divides by calls for "per GET". Nothing is booked where no
+    stretch ran."""
+
+    __slots__ = ("_row", "_label", "_ann", "_wall", "_cpu", "_n", "_t0", "_c0")
+
+    def __init__(self, layer: str, name: str):
+        self._row = _phase_table[(layer, name)]
+        self._label = f"{layer}.{name}"
+        self._ann = None
+        self._wall = self._cpu = 0.0
+        self._n = 0
+        self._t0 = self._c0 = 0.0
+
+    def __enter__(self) -> "PhaseSum":
+        self._ann = _trace_annotation(self._label)
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._c0 = time.thread_time()
+        self._t0 = time.monotonic()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self._wall += time.monotonic() - self._t0
+        self._cpu += time.thread_time() - self._c0
+        self._n += 1
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
+        return False
+
+    def book(self) -> None:
+        if self._n:
+            _phase_book(self._row, self._wall, self._cpu)
+            self._wall = self._cpu = 0.0
+            self._n = 0
 
 
 def _trace_annotation(label: str):

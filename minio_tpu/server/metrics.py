@@ -1103,6 +1103,26 @@ def _g_api_tpu(server) -> list[str]:
          "stack's layout (packed: the decode mega-kernel's chunk-major "
          "input, written once; rows: [d, W, per] for the XLA rung and the "
          "host's GF apply)")
+    # both planes of a GET by bytes, and what a PUT to a set with drives
+    # offline left for heal
+    from ..erasure.set import get_bytes_snapshot, put_offline_shards_snapshot
+
+    _fmt(out, "minio_tpu_get_bytes_total", "counter",
+         [({"path": p}, n) for p, n in sorted(get_bytes_snapshot().items())],
+         "Bytes of GET bodies that the erasure read path finished, by the "
+         "path that produced them: the native span pass over healthy data "
+         "shards (native; its time is the phase table's `get`/`native`) "
+         "or the reconstructing windowed pipeline (windowed)")
+    _fmt(out, "minio_tpu_put_offline_shards_total", "counter",
+         [({}, put_offline_shards_snapshot())],
+         "Shards of acknowledged PUTs that no drive took (the drive was "
+         "offline or failed mid-stream and write quorum held without it); "
+         "each such object is queued for heal")
+    bg = getattr(server, "background", None)
+    _fmt(out, "minio_tpu_heal_mrf_pending", "gauge",
+         [({}, len(bg.mrf) if bg is not None else 0)],
+         "Objects waiting in the most-recent-failures heal queue "
+         "(degraded reads and partial PUTs; deduplicated, bounded)")
     # device runtime (ops/runtime.py): which device this process holds and
     # what it compiled vs loaded from the persistent compile cache; zeros
     # and no device row on a CPU-plane process
