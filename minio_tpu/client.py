@@ -95,7 +95,15 @@ class S3Client:
         try:
             conn.request(method, enc_path + (f"?{qs}" if qs else ""), body=body, headers=signed)
             resp = conn.getresponse()
-            data = resp.read()
+            try:
+                data = resp.read()
+            except http.client.IncompleteRead as e:
+                # the server closed the connection inside the body (a read
+                # error after its headers went out): a connection error, as
+                # a reset would be, for callers that catch OSError
+                raise ConnectionResetError(
+                    f"body cut short after {len(e.partial)} bytes"
+                ) from e
             return S3Response(resp.status, {k.lower(): v for k, v in resp.getheaders()}, data)
         finally:
             conn.close()

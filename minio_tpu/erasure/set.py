@@ -1547,7 +1547,9 @@ class ErasureSet:
         windows = [plan[i : i + window] for i in range(0, len(plan), window)]
         runs, futs = start_window(windows[0]) if windows else ([], {})
         starting.book()
-        # yield -> resumption: the front end's write and its executor hop
+        # yield -> resumption: the front end's producer calls the next
+        # next() — its executor hop and the time it stood at a full budget
+        # (server/object_handlers.py send_body_ahead; the writes run beside)
         responding = obs.PhaseClock("get", "respond")
         try:
             for wi, win in enumerate(windows):

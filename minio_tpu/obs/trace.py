@@ -238,12 +238,18 @@ def span(trace_type: str, name: str, **fields):
 # with its pad rows zeroed, else [d, W, per]), `decode_wait` (the whole
 # reconstruct_data_flat call; the `decode` leaves tile it), `join` (the
 # per-block gather-join copy), `cache_fill` (the block offered to the
-# range-segment cache), `respond` (yield -> resumption: the front end's
-# write and the executor hop; wall only, the thread may change), and
+# range-segment cache), `respond` (yield -> the front end's producer
+# calls the next next(): the executor hop and, since the body is produced
+# one read window ahead of the socket, the time the producer stood at a
+# full budget; wall only, the thread may change), and
 # `shard_io` on the read pool's threads (a run of frames: read_file + verify_run).
 # `native` is the healthy GET's own: the native span reads of one read
 # (pread + bitrot verify + assembly in one C++ pass, 16 MiB a span), summed
 # and booked as ONE call when that read's native part ends.
+# `body_wait` and `body_write` are the front end's (server/object_handlers.py
+# get_object), booked per piece of EVERY GET body on the event loop, wall
+# only: the response's writer waiting for the next piece (production that
+# ran under no write) and inside `await resp.write(piece)`.
 # `decode` phases are the leaves of one device reconstruct
 # (ops/bitrot_jax.py, erasure/coder.py): `pad` (survivors made
 # block-major and zero-padded to the kernel's batch), `pack` (neither
@@ -256,7 +262,8 @@ PHASES = {
     "put": ("ingest", "stage", "encode_wait", "frame", "md5",
             "drive_write", "commit", "drive_io"),
     "get": ("start", "read_wait", "stack", "decode_wait", "join",
-            "cache_fill", "respond", "shard_io", "native"),
+            "cache_fill", "respond", "shard_io", "native",
+            "body_wait", "body_write"),
     "decode": ("pad", "pack", "h2d", "kernel", "d2h", "unpack", "host"),
 }
 _PHASE_TYPES = {"dispatch": TYPE_TPU, "put": TYPE_INTERNAL,
@@ -264,11 +271,13 @@ _PHASE_TYPES = {"dispatch": TYPE_TPU, "put": TYPE_INTERNAL,
 # the phases that go to the profiler: leaves only. An enclosing phase
 # (`put`/`encode_wait`, `get`/`decode_wait`) would win every idle gap of a
 # device trace and say nothing; the pools' threads (`drive_io`,
-# `shard_io`) would bury it in events.
+# `shard_io`) would bury it in events; the event loop's (`body_wait`,
+# `body_write`) span awaits during which the loop serves other requests.
 _ANNOTATED = {
     "dispatch": frozenset(PHASES["dispatch"]),
     "decode": frozenset(PHASES["decode"]),
-    "get": frozenset(PHASES["get"]) - {"decode_wait", "shard_io"},
+    "get": frozenset(PHASES["get"])
+    - {"decode_wait", "shard_io", "body_wait", "body_write"},
 }
 _phase_mu = threading.Lock()
 # (layer, name) -> [wall seconds, thread CPU seconds, calls]

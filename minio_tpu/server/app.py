@@ -53,6 +53,10 @@ class S3Server(
         self.kms = KMS()
         self.store = None
         self.streaming_puts = 0  # observability: bodies that never buffered
+        # pieces of GET bodies by whether the response's writer found the
+        # piece waiting ("1": produced ahead of the socket) or had to wait
+        # for it ("0"); the event loop's alone (object_handlers.send_body_ahead)
+        self.get_pieces = {"1": 0, "0": 0}
         # dedicated pool for streaming-body pumps: put_item can block on a
         # full queue, and parking it in the default executor would starve
         # the storage-REST plane that shares it

@@ -1113,6 +1113,14 @@ def _g_api_tpu(server) -> list[str]:
          "path that produced them: the native span pass over healthy data "
          "shards (native; its time is the phase table's `get`/`native`) "
          "or the reconstructing windowed pipeline (windowed)")
+    # the front end's side of a GET body (object_handlers.send_body_ahead):
+    # its time is the phase table's `get`/`body_wait` and `get`/`body_write`
+    pieces = getattr(server, "get_pieces", None) or {"1": 0, "0": 0}
+    _fmt(out, "minio_tpu_get_pieces_total", "counter",
+         [({"ahead": a}, pieces[a]) for a in ("1", "0")],
+         "Pieces of GET bodies by whether the response's writer found the "
+         "piece waiting, produced ahead of the socket (1), or had to wait "
+         "for the read path to produce it (0)")
     _fmt(out, "minio_tpu_put_offline_shards_total", "counter",
          [({}, put_offline_shards_snapshot())],
          "Shards of acknowledged PUTs that no drive took (the drive was "

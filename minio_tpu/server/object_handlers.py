@@ -7,8 +7,10 @@ Split from app.py (the reference's cmd/object-handlers.go)."""
 from __future__ import annotations
 
 import asyncio
+import collections
 import hashlib
 import os
+import threading
 import urllib.parse
 import xml.etree.ElementTree as ET
 from email.utils import parsedate_to_datetime
@@ -16,6 +18,7 @@ from xml.sax.saxutils import escape
 
 from aiohttp import web
 
+from .. import obs
 from ..erasure import listing, quorum
 from ..erasure.types import ObjectInfo
 from . import s3err, signature
@@ -26,6 +29,125 @@ from .handler_utils import (
     _iso8601,
     _http_date,
 )
+
+# how far a GET's body is produced ahead of its socket: one read window
+# (MINIO_TPU_READ_WINDOW's shipped 8 x the 1 MiB stripe block), so that the
+# read path is inside window k+1's reads, stack and decode — on the native
+# plane the next span's read — while window k's pieces are being written
+BODY_AHEAD_BYTES = 8 << 20
+
+
+async def send_body_ahead(pool, it, resp, request, pieces: dict) -> None:
+    """Write the pieces of `it` to `resp`, in order, produced ahead of the
+    socket. `pull` advances the iterator on a thread of `pool` and leaves
+    each piece for the writer — this coroutine — without waiting for it,
+    for as long as the bytes produced and not yet written leave room for
+    another under BODY_AHEAD_BYTES; at a full budget it returns its thread,
+    and the writer submits it again once a write has made room. So one
+    thread at a time advances the iterator, no thread ever waits for the
+    client, and while there is room a piece's hand-over (loop -> pool
+    thread -> loop, each a wait for the GIL) stands in nobody's way. The
+    loop is woken only where the writer waits for a piece: the wake-up is
+    a system call, and a thread that makes one gives the GIL up and queues
+    for it again, once per piece where it could be once per read window.
+    request["_tx"] is metered as each write returns: what left, never what
+    was queued. A read-path error surfaces where the body stops, after the
+    pieces before it. However the body ends, the pull in flight is awaited
+    before the iterator is closed (a generator cannot be closed while a
+    thread executes it), and closed it is before this returns: the read
+    path's `finally` cancels its readahead and the handle's releases the
+    namespace lock.
+
+    Books `get`/`body_wait` and `get`/`body_write` per piece (wall only: the
+    event loop is every request's) and counts in `pieces` the ones the
+    writer found waiting ("1") or had to wait for ("0")."""
+    loop = asyncio.get_running_loop()
+    end = object()
+    wake = asyncio.Event()
+    mu = threading.Lock()  # what follows is shared with the pool's thread
+    ready: collections.deque = collections.deque()  # produced, not yet taken
+    ahead = largest = 0  # bytes produced and unwritten; the largest piece yet
+    parked = False  # the writer waits on `wake`
+    pulling = True  # a pull is submitted or running
+    over = False  # production ended: exhausted, failed, or the writer left
+    failed: list[Exception] = []
+
+    def hand(piece, n: int) -> None:
+        nonlocal ahead, largest, parked
+        with mu:
+            ahead += n
+            largest = max(largest, n)
+            ready.append(piece)
+            wanted, parked = parked, False
+        if wanted:
+            loop.call_soon_threadsafe(wake.set)
+
+    def pull() -> None:
+        nonlocal pulling, over
+        try:
+            while True:
+                with mu:
+                    if over or (ahead and ahead + largest > BODY_AHEAD_BYTES):
+                        pulling = False
+                        return
+                piece = next(it, end)
+                if piece is end:
+                    break
+                hand(piece, len(piece))
+        except Exception as e:  # the read path's: the writer raises it
+            failed.append(e)
+        with mu:
+            over, pulling = True, False
+        # however production ended, the writer never waits in vain
+        hand(end, 0)
+
+    async def take():
+        """-> the next piece, and whether it was found waiting."""
+        nonlocal parked
+        found = "1"
+        while True:
+            with mu:
+                if ready:
+                    return ready.popleft(), found
+                wake.clear()
+                parked = True
+            found = "0"
+            await wake.wait()
+
+    # bytes metered at write time: a client that disconnects mid-stream
+    # must be traced/audited with what actually left, not content_length
+    request["_tx"] = 0
+    waiting = obs.PhaseClock("get", "body_wait")
+    writing = obs.PhaseClock("get", "body_write")
+    pulled = loop.run_in_executor(pool, pull)
+    try:
+        while True:
+            waiting.restart()
+            piece, found = await take()
+            if piece is end:
+                break
+            waiting.book(cpu=False)
+            pieces[found] += 1
+            writing.restart()
+            await resp.write(piece)
+            writing.book(cpu=False)
+            request["_tx"] += len(piece)
+            with mu:
+                ahead -= len(piece)
+                again = not (pulling or over)
+                if again:
+                    pulling = True
+            if again:  # it stood at a full budget: there is room now
+                pulled = loop.run_in_executor(pool, pull)
+        if failed:
+            raise failed[0]
+    finally:
+        # after a hang-up or a write error mid-body the pull ends with the
+        # next() in flight
+        with mu:
+            over = True
+        await pulled
+        it.close()
 
 
 class ObjectHandlersMixin:
@@ -801,19 +923,17 @@ class ObjectHandlersMixin:
             handle.close()  # preconditions/range failures must not leak the rlock
             raise
         await resp.prepare(request)
-        loop = asyncio.get_running_loop()
-        sentinel = object()
-        nxt = lambda: next(it, sentinel)  # noqa: E731
-        # bytes metered at write time: a client that disconnects mid-stream
-        # must be traced/audited with what actually left, not content_length
-        request["_tx"] = 0
         try:
-            while True:
-                chunk = await loop.run_in_executor(self._io_pool, nxt)
-                if chunk is sentinel:
-                    break
-                await resp.write(chunk)
-                request["_tx"] += len(chunk)
+            await send_body_ahead(
+                self._io_pool, it, resp, request, self.get_pieces
+            )
+        except Exception:
+            # the headers went out: a read-path error can reach the client
+            # only as a body that stops short — an error response now would
+            # land inside it and leave the client waiting for the rest
+            if request.transport is not None:
+                request.transport.close()
+            raise
         finally:
             handle.close()  # release the namespace read lock promptly
         await resp.write_eof()

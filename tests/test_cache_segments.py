@@ -45,6 +45,9 @@ def _seg_env(monkeypatch, tmp_path):
     monkeypatch.setenv("MINIO_TPU_CACHE_DISK_DIR", str(tmp_path / "spool"))
     monkeypatch.setenv("MINIO_TPU_CACHE_PREFETCH_SEGMENTS", "0")
     pfmod.reset()
+    # the segment cache is the process's: what an earlier test file of this
+    # xdist worker left in it is not this test's to count or to demote
+    segmod.segment_cache().drop_where(lambda dk: True)
     yield
     freg.clear()
 
