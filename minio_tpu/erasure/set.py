@@ -181,6 +181,9 @@ _GET_BYTES = {"native": 0, "windowed": 0}
 # set (offline, failed mid-stream) keeps no shard of the object, which is
 # then queued for heal
 _PUT_OFFLINE_SHARDS = 0
+# drives asked for xl.meta by the quorum reads that `get_object_info`
+# reached (a stat that missed the FileInfo cache): the set's width a read
+_STAT_DRIVES_ASKED = 0
 _BODY_COUNTERS_LOCK = threading.Lock()
 
 
@@ -197,6 +200,11 @@ def get_bytes_snapshot() -> dict[str, int]:
 def put_offline_shards_snapshot() -> int:
     with _BODY_COUNTERS_LOCK:
         return _PUT_OFFLINE_SHARDS
+
+
+def stat_drives_asked_snapshot() -> int:
+    with _BODY_COUNTERS_LOCK:
+        return _STAT_DRIVES_ASKED
 
 
 def stack_survivors(stack, present, stretches, got) -> None:
@@ -404,13 +412,15 @@ class ErasureSet:
         return fi, metas, read_q, write_q
 
     def _cached_fileinfo(
-        self, bucket: str, obj: str, version_id: str
+        self, bucket: str, obj: str, version_id: str, stat: bool = False
     ) -> tuple[FileInfo, list[FileInfo | None]]:
         """Read-path quorum metadata via the FileInfo cache: hot keys skip
         the N-drive fan-out; concurrent misses singleflight one quorum
         read (read_data=True so GET and HEAD share one entry). Mutation
         paths keep calling ``_quorum_fileinfo`` directly — they read
-        under the write lock and must see authoritative state."""
+        under the write lock and must see authoritative state. ``stat``
+        marks `get_object_info`'s reads: the fan-out they reach is booked
+        as `stat`/`meta_read`, with the drives it asked."""
 
         def load():
             fi, metas, _, _ = self._quorum_fileinfo(
@@ -418,7 +428,16 @@ class ErasureSet:
             )
             return fi, metas
 
-        return self.cache.fileinfo(bucket, obj, version_id, load)
+        def load_for_stat():
+            global _STAT_DRIVES_ASKED
+            with obs.phase("stat", "meta_read", drives=self.n):
+                with _BODY_COUNTERS_LOCK:
+                    _STAT_DRIVES_ASKED += self.n
+                return load()
+
+        return self.cache.fileinfo(
+            bucket, obj, version_id, load_for_stat if stat else load
+        )
 
     # -- put ---------------------------------------------------------------
 
@@ -890,12 +909,11 @@ class ErasureSet:
     # -- get ---------------------------------------------------------------
 
     def get_object_info(self, bucket: str, obj: str, version_id: str = "") -> ObjectInfo:
-        fi, _ = self._cached_fileinfo(bucket, obj, version_id)
-        if fi.deleted:
-            if not version_id:
+        with obs.phase("stat", "info"):
+            fi, _ = self._cached_fileinfo(bucket, obj, version_id, stat=True)
+            if fi.deleted and not version_id:
                 raise ObjectNotFound(f"{bucket}/{obj}")
             return self._to_object_info(bucket, obj, fi)
-        return self._to_object_info(bucket, obj, fi)
 
     def open_object(
         self, bucket: str, obj: str, version_id: str = "",
@@ -1605,14 +1623,18 @@ class ErasureSet:
             obs.TYPE_INTERNAL, "erasure.delete_object", bucket=bucket, object=obj
         ):
             mtx = self.ns.new(bucket, obj)
+            lock_wait = obs.PhaseClock("delete", "lock_wait")
             if not _lock_dyn(mtx, write=True):
                 raise QuorumError(f"namespace write lock timeout on {bucket}/{obj}")
             try:
-                oi = self._delete_object_locked(bucket, obj, version_id, versioned)
+                lock_wait.book()
+                with obs.phase("delete", "drive_delete"):
+                    oi = self._delete_object_locked(bucket, obj, version_id, versioned)
             finally:
                 mtx.unlock()
             # invalidate + broadcast outside the lock, before returning
-            self.cache.invalidate_object(bucket, obj)
+            with obs.phase("delete", "invalidate"):
+                self.cache.invalidate_object(bucket, obj)
             return oi
 
     def _delete_object_locked(

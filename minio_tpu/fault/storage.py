@@ -51,6 +51,9 @@ class FaultInjectedDisk(StorageAPI):
         # injection by design (chaos runs force the Python read path)
         return self._inner.local_path(volume, path)
 
+    def close(self) -> None:
+        self._inner.close()
+
     @staticmethod
     def _modes_for(name: str) -> tuple[str, ...]:
         """Fault modes this op can actually express — check() must not

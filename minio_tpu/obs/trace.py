@@ -256,6 +256,20 @@ def span(trace_type: str, name: str, **fields):
 # runs for a group `stack` laid out packed), `h2d`,
 # `kernel` (call -> ready; a first call's trace-and-lower too), `d2h`,
 # `unpack`; `host` is a group rebuilt by the native/numpy GF apply.
+# `op` phases are the S3 operation as its handler sees it (server/app.py):
+# wall from the parsed, authorized request to the finished response, one
+# row per kind of object request, booked on the event loop, wall only —
+# seconds / calls is "per operation", calls / window the operations rate.
+# `stat` is `ErasureSet.get_object_info` on the I/O pool's thread: `info`
+# the whole call — a HEAD, and whoever else stats an object: a PUT's and a
+# DELETE's handler look the key up first (tier sweep, object lock) —
+# `meta_read` the quorum read of xl.meta on every drive of the set, which
+# only a miss of the FileInfo cache reaches. `delete` phases tile `delete_object`
+# (erasure/set.py) on the same thread: `lock_wait` (the namespace write
+# lock; a PhaseClock around the bare acquisition), `drive_delete` (`delete_version` on every drive and their join),
+# `invalidate` (the caches, the broadcast). `trash`/`reclaim` is one entry
+# of `<drive>/.minio.sys/trash` removed by that drive's reclaimer thread
+# (storage/xlstorage.py), off every request's path.
 PHASES = {
     "dispatch": ("wait", "window", "assemble", "pack", "h2d", "kernel",
                  "d2h", "unpack", "frame", "numpy", "fanout"),
@@ -265,19 +279,29 @@ PHASES = {
             "cache_fill", "respond", "shard_io", "native",
             "body_wait", "body_write"),
     "decode": ("pad", "pack", "h2d", "kernel", "d2h", "unpack", "host"),
+    "op": ("get_object", "head_object", "put_object", "delete_object"),
+    "stat": ("info", "meta_read"),
+    "delete": ("lock_wait", "drive_delete", "invalidate"),
+    "trash": ("reclaim",),
 }
 _PHASE_TYPES = {"dispatch": TYPE_TPU, "put": TYPE_INTERNAL,
-                "get": TYPE_INTERNAL, "decode": TYPE_TPU}
+                "get": TYPE_INTERNAL, "decode": TYPE_TPU,
+                "op": TYPE_INTERNAL, "stat": TYPE_INTERNAL,
+                "delete": TYPE_INTERNAL, "trash": TYPE_STORAGE}
 # the phases that go to the profiler: leaves only. An enclosing phase
 # (`put`/`encode_wait`, `get`/`decode_wait`) would win every idle gap of a
 # device trace and say nothing; the pools' threads (`drive_io`,
 # `shard_io`) would bury it in events; the event loop's (`body_wait`,
-# `body_write`) span awaits during which the loop serves other requests.
+# `body_write`, every `op` row) span awaits during which the loop serves
+# other requests.
 _ANNOTATED = {
     "dispatch": frozenset(PHASES["dispatch"]),
     "decode": frozenset(PHASES["decode"]),
     "get": frozenset(PHASES["get"])
     - {"decode_wait", "shard_io", "body_wait", "body_write"},
+    "stat": frozenset({"meta_read"}),
+    "delete": frozenset(PHASES["delete"]) - {"lock_wait"},
+    "trash": frozenset(PHASES["trash"]),
 }
 _phase_mu = threading.Lock()
 # (layer, name) -> [wall seconds, thread CPU seconds, calls]

@@ -265,7 +265,8 @@ class S3Server(
         return gen, (lambda: count[0])
 
     def close(self) -> None:
-        """Stop background workers (IAM refresh/watch, scanner) — for
+        """Stop background workers (IAM refresh/watch, scanner, the drives'
+        trash reclaimers) — for
         embedders and tests that start/stop servers within one process;
         without this, watcher threads keep dialing dead backends."""
         iam = getattr(self, "iam", None)
@@ -274,6 +275,12 @@ class S3Server(
         if self.background is not None:
             try:
                 self.background.stop()
+            except Exception:  # noqa: BLE001 — best-effort teardown
+                pass
+        # the drives' own threads (the trash reclaimers) end with the server
+        for disk in getattr(self.store, "disks", ()):
+            try:
+                disk.close()
             except Exception:  # noqa: BLE001 — best-effort teardown
                 pass
 
@@ -291,6 +298,20 @@ class S3Server(
         ):
             return
         self.replication.queue_mutation(bucket, key, version_id, op)
+
+    @staticmethod
+    async def _timed_op(op: str, handling):
+        """Book one call of the phase `op`/<op> around an object handler:
+        wall from the parsed request to the finished response (a GET's
+        body written, a PUT's read), whatever the answer. Wall only: the
+        event loop is every request's."""
+        from .. import obs
+
+        clock = obs.PhaseClock("op", op)
+        try:
+            return await handling
+        finally:
+            clock.book(cpu=False)
 
     async def _run(self, fn, *args, **kw):
         return await asyncio.get_running_loop().run_in_executor(
@@ -912,7 +933,7 @@ class S3Server(
                 return await self.put_object_part(request, bucket, key, body)
             if "x-amz-copy-source" in request.headers:
                 return await self.copy_object(request, bucket, key)
-            return await self.put_object(request, bucket, key, body)
+            return await self._timed_op("put_object", self.put_object(request, bucket, key, body))
         if m == "GET":
             if "uploadId" in q:
                 return await self.list_parts(request, bucket, key)
@@ -920,13 +941,13 @@ class S3Server(
                 return await self.get_object_attributes(request, bucket, key)
             if "lambdaArn" in q:
                 return await self.get_object_lambda(request, bucket, key)
-            return await self.get_object(request, bucket, key)
+            return await self._timed_op("get_object", self.get_object(request, bucket, key))
         if m == "HEAD":
-            return await self.head_object(request, bucket, key)
+            return await self._timed_op("head_object", self.head_object(request, bucket, key))
         if m == "DELETE":
             if "uploadId" in q:
                 return await self.abort_multipart(request, bucket, key)
-            return await self.delete_object(request, bucket, key)
+            return await self._timed_op("delete_object", self.delete_object(request, bucket, key))
         if m == "POST":
             if "uploads" in q:
                 return await self.new_multipart(request, bucket, key)

@@ -1126,6 +1126,36 @@ def _g_api_tpu(server) -> list[str]:
          "Shards of acknowledged PUTs that no drive took (the drive was "
          "offline or failed mid-stream and write quorum held without it); "
          "each such object is queued for heal")
+    from ..erasure.set import stat_drives_asked_snapshot
+
+    _fmt(out, "minio_tpu_stat_drives_asked_total", "counter",
+         [({}, stat_drives_asked_snapshot())],
+         "Drives asked for xl.meta by the quorum reads that a stat of an "
+         "object (a HEAD; a PUT's and a DELETE's look-up of their key) "
+         "reached past the FileInfo cache; their time is the "
+         "phase table's `stat`/`meta_read`")
+    # what deletes and overwrites moved into <drive>/.minio.sys/trash and
+    # what the drives' reclaimers made of it (storage/xlstorage.py); the
+    # reclaimers' time is the phase table's `trash`/`reclaim`
+    from ..storage.xlstorage import trash_stats
+
+    ts = trash_stats()
+    _fmt(out, "minio_tpu_trash_moved_total", "counter", [({}, ts["moved"])],
+         "Entries renamed into a drive's trash directory (booked at the "
+         "rename; what a previous process left there, when adopted)")
+    _fmt(out, "minio_tpu_trash_moved_bytes_total", "counter",
+         [({}, ts["moved_bytes"])])
+    _fmt(out, "minio_tpu_trash_reclaimed_total", "counter",
+         [({}, ts["reclaimed"])],
+         "Trash entries removed by a drive's reclaimer (booked when the "
+         "removal ENDS)")
+    _fmt(out, "minio_tpu_trash_reclaimed_bytes_total", "counter",
+         [({}, ts["reclaimed_bytes"])])
+    _fmt(out, "minio_tpu_trash_failed_total", "counter", [({}, ts["failed"])],
+         "Trash entries whose removal raised: left where they are, not "
+         "tried again by this process")
+    _fmt(out, "minio_tpu_trash_pending", "gauge", [({}, ts["pending"])],
+         "Trash entries moved aside and neither removed nor given up")
     bg = getattr(server, "background", None)
     _fmt(out, "minio_tpu_heal_mrf_pending", "gauge",
          [({}, len(bg.mrf) if bg is not None else 0)],

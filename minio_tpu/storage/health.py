@@ -269,6 +269,11 @@ class HealthCheckedDisk(StorageAPI):
         # pure path math — no I/O, so no circuit involvement
         return self._inner.local_path(volume, path)
 
+    def close(self) -> None:
+        # teardown, not a drive call: an open breaker must not keep the
+        # drive's background threads alive
+        self._inner.close()
+
     def walk_dir(self, volume, base=""):
         # generator: account the iteration, not just construction. The
         # walk's wall time measures NAMESPACE SIZE (one call enumerates

@@ -4,8 +4,11 @@ Behavioral mirror of the reference's xlStorage (/root/reference/cmd/
 xl-storage.go): one directory per drive; objects live at
 <drive>/<bucket>/<object>/xl.meta with erasure shard files in a
 uuid-named data dir next to it; writes stage in <drive>/.minio.sys/tmp and
-move into place with atomic renames; deletes move to a trash dir that is
-purged asynchronously (moveToTrash, xl-storage.go:1295).
+move into place with atomic renames; what a delete or an overwrite
+replaces is renamed into <drive>/.minio.sys/trash on the request path
+(moveToTrash, xl-storage.go:1295) and removed from there by the drive's
+reclaimer thread (`TrashReclaimer`), which the rename wakes: the request
+is acknowledged after its renames, the removal follows.
 
 Differences from the reference, by design:
 - No O_DIRECT (Python path; the native IO helper can add it later) — but
@@ -17,11 +20,14 @@ Differences from the reference, by design:
 from __future__ import annotations
 
 import os
+import queue
 import shutil
+import stat
 import threading
 import uuid
 from typing import BinaryIO, Iterator
 
+from .. import obs
 from . import errors
 from .datatypes import DiskInfo, FileInfo, VolInfo
 from .format import XLMeta
@@ -78,6 +84,147 @@ def fanout_stats() -> dict:
         return dict(_FANOUT)
 
 
+# ---- the trash -------------------------------------------------------------
+# Process-wide counts of what `_to_trash` moved aside and what the drives'
+# reclaimers made of it. `moved` is booked at the rename (and for what a
+# previous process left in a trash directory, when the drive adopts it),
+# `reclaimed` when a removal ENDS, `failed` for an entry whose removal
+# raised: it stays where it is, is not tried again by this process, and
+# the next one to open the drive adopts it once more.
+
+_TRASH_LOCK = threading.Lock()
+_TRASH = {
+    "moved": 0,
+    "moved_bytes": 0,
+    "reclaimed": 0,
+    "reclaimed_bytes": 0,
+    "failed": 0,
+}
+
+
+def _trash_add(**counts: int) -> None:
+    with _TRASH_LOCK:
+        for name, n in counts.items():
+            _TRASH[name] += n
+
+
+def trash_stats() -> dict:
+    """Snapshot of the trash counters; `pending` is what was moved aside
+    and has been neither removed nor given up."""
+    with _TRASH_LOCK:
+        out = dict(_TRASH)
+    out["pending"] = out["moved"] - out["reclaimed"] - out["failed"]
+    return out
+
+
+def _tree_bytes(path: str) -> int:
+    """Bytes of the regular files under `path` (itself, where it is one);
+    a symlink is neither followed nor counted."""
+    try:
+        st = os.lstat(path)
+        if not stat.S_ISDIR(st.st_mode):
+            return st.st_size if stat.S_ISREG(st.st_mode) else 0
+        return sum(_tree_bytes(os.path.join(path, name)) for name in os.listdir(path))
+    except OSError:
+        return 0
+
+
+def _remove_tree(path: str) -> None:
+    """`shutil.rmtree` for what a trash holds — a data directory of part
+    files, seldom deeper — in half its system calls (a drive's reclaimer
+    makes them beside the requests' own): every name is unlinked, which
+    removes a file and a symlink alike and never follows one, and only what
+    refuses to be unlinked because it is a directory is listed."""
+    try:
+        os.unlink(path)
+        return
+    except IsADirectoryError:
+        pass
+    except PermissionError:  # what some platforms say of a directory
+        if os.path.islink(path) or not os.path.isdir(path):
+            raise
+    for name in os.listdir(path):
+        _remove_tree(os.path.join(path, name))
+    os.rmdir(path)
+
+
+_TRASH_IDLE_S = 5.0  # an idle reclaimer thread ends after this long
+
+
+class TrashReclaimer:
+    """One drive's trash, emptied off the request path. `put` hands over
+    an entry of the trash directory — it never blocks and starts the
+    thread where none runs, so a drive that moves nothing aside has none —
+    and the thread removes entry after entry, woken by each `put`. It
+    touches nothing but direct children of its trash directory, and
+    `_remove_tree` unlinks a symlink where it finds one, never what it
+    points to. `stop` lets it finish the entry in hand and ends it; what
+    is still queued stays in the directory for the next process."""
+
+    def __init__(self, trash_dir: str):
+        self.trash_dir = trash_dir
+        self._q: queue.SimpleQueue = queue.SimpleQueue()
+        self._mu = threading.Lock()
+        self._thread: threading.Thread | None = None
+        self._stopped = False
+
+    def put(self, path: str, nbytes: int) -> None:
+        with self._mu:
+            if self._stopped:
+                return
+            self._q.put((path, nbytes))  # never blocks
+            if self._thread is None:
+                self._thread = threading.Thread(
+                    target=self._run, name="trash-reclaim", daemon=True
+                )
+                self._thread.start()
+
+    def _run(self) -> None:
+        while True:
+            try:
+                item = self._q.get(timeout=_TRASH_IDLE_S)
+            except queue.Empty:
+                # nothing for a while: the thread ends, the next `put`
+                # starts another (a drive nobody closes leaks none)
+                with self._mu:
+                    if self._q.empty():
+                        self._thread = None
+                        return
+                continue
+            if item is None:
+                return
+            path, nbytes = item
+            with obs.phase("trash", "reclaim"):
+                gone = self._remove(path)
+            if gone:
+                _trash_add(reclaimed=1, reclaimed_bytes=nbytes)
+            else:
+                _trash_add(failed=1)
+
+    def _remove(self, path: str) -> bool:
+        if os.path.dirname(path) != self.trash_dir:
+            return False  # not an entry of this drive's trash: not ours
+        try:
+            _remove_tree(path)
+        except OSError:
+            # gone all the same (a sibling process adopted it too)?
+            return not os.path.lexists(path)
+        return True
+
+    def stop(self, timeout: float = 10.0) -> None:
+        with self._mu:
+            self._stopped = True
+            thread, self._thread = self._thread, None
+        if thread is not None:
+            self._q.put(None)
+            thread.join(timeout)
+
+    @property
+    def running(self) -> bool:
+        with self._mu:
+            return self._thread is not None and self._thread.is_alive()
+
+
 def _clean_rel(path: str) -> str:
     """Reject traversal; normalize an object path to a safe relative path."""
     if path.startswith("/"):
@@ -96,6 +243,8 @@ class XLStorage(StorageAPI):
         self._meta_lock = threading.RLock()
         for sysdir in (TMP_DIR, TRASH_DIR, MULTIPART_DIR, BUCKETS_META_DIR):
             os.makedirs(os.path.join(self.root, sysdir), exist_ok=True)
+        self.trash = TrashReclaimer(os.path.join(self.root, TRASH_DIR))
+        self.empty_trash()  # what a previous process moved aside and left
 
     # -- path helpers ------------------------------------------------------
 
@@ -562,16 +711,33 @@ class XLStorage(StorageAPI):
     # -- trash -------------------------------------------------------------
 
     def _to_trash(self, full_path: str) -> None:
-        dst = os.path.join(self.root, TRASH_DIR, str(uuid.uuid4()))
+        """Rename `full_path` into the trash — all the caller waits for —
+        and wake the reclaimer, which removes it."""
+        dst = os.path.join(self.trash.trash_dir, str(uuid.uuid4()))
         try:
             os.replace(full_path, dst)
         except OSError:
             shutil.rmtree(full_path, ignore_errors=True)
+            return
+        self._reclaim(dst)
+
+    def _reclaim(self, entry: str) -> None:
+        nbytes = _tree_bytes(entry)
+        _trash_add(moved=1, moved_bytes=nbytes)
+        self.trash.put(entry, nbytes)
 
     def empty_trash(self) -> None:
-        trash = os.path.join(self.root, TRASH_DIR)
-        for name in os.listdir(trash):
-            shutil.rmtree(os.path.join(trash, name), ignore_errors=True)
+        """Hand every entry that lies in the trash directory to the
+        reclaimer: at construction, what a previous process left there."""
+        try:
+            names = os.listdir(self.trash.trash_dir)
+        except OSError:
+            return
+        for name in names:
+            self._reclaim(os.path.join(self.trash.trash_dir, name))
+
+    def close(self) -> None:
+        self.trash.stop()
 
     def _prune_empty(self, dir_path: str, stop_at: str) -> None:
         """Remove empty parent dirs up to (not incl.) the volume root."""
