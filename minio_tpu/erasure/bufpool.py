@@ -44,10 +44,13 @@ import threading
 
 import numpy as np
 
-# size classes are powers of two from 64 KiB up; anything larger than
-# the top class allocates unpooled (released back to the allocator, not
-# the pool) so one giant request cannot pin the whole budget
+# size classes are powers of two from 64 KiB to 1 MiB, then whole MiB:
+# an arena a few bytes over a power of two (64 stripe blocks at 12+4 with
+# their shard pad, 64 MiB + 512 B) takes the next MiB, not twice its size.
+# Anything larger than the top class allocates unpooled (released back to
+# the allocator, not the pool) so one giant request cannot pin the budget
 _MIN_CLASS = 1 << 16
+_MIB = 1 << 20
 _MAX_CLASS = 1 << 27  # 128 MiB — one full streaming batch at the default cap
 
 _TRUTHY = ("1", "true", "on", "yes")
@@ -195,6 +198,8 @@ class BufferPool:
 
     @staticmethod
     def _class_for(nbytes: int) -> int:
+        if nbytes > _MIB:
+            return -(-nbytes // _MIB) * _MIB
         c = _MIN_CLASS
         while c < nbytes:
             c <<= 1

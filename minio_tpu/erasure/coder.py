@@ -162,6 +162,23 @@ def decode_matrix_cache_snapshot() -> dict:
     return decode_cache.snapshot()
 
 
+# batches the streaming encode yielded, by its plane: "arena" (the pooled
+# ingest arena, iter_encode_zc's own path) or "copy" (the legacy staging
+# path that MINIO_TPU_ZEROCOPY=0 selects)
+_PUT_BATCHES = {"arena": 0, "copy": 0}
+_PUT_BATCHES_LOCK = threading.Lock()
+
+
+def _put_batches_add(plane: str) -> None:
+    with _PUT_BATCHES_LOCK:
+        _PUT_BATCHES[plane] += 1
+
+
+def put_batches_snapshot() -> dict[str, int]:
+    with _PUT_BATCHES_LOCK:
+        return dict(_PUT_BATCHES)
+
+
 def encode_blocks_numpy(
     np_codec, blocks: np.ndarray, family: str = FAMILY_RS
 ) -> tuple[np.ndarray, np.ndarray]:
@@ -217,12 +234,15 @@ class EncodedBatch:
     parity indices (i >= d) are views of the dispatch's result arrays;
     the shard rows of data indices (i < d) are views of the INGEST ARENA
     itself — the dispatcher hands no data byte back. ``raw`` is the input
-    slice this batch encoded (md5/size folding), a memoryview into the
-    same arena on the pooled path. So the caller MUST finish both the md5
-    fold and every ``append_file(shard_vecs[i])`` before calling
-    :meth:`release` — the release returns the arena to the pool, and a
-    data row read after it reads another request's bytes
-    (docs/ERASURE.md buffer-ownership contract)."""
+    this batch encoded, as a list of buffers to fold in order (md5/size):
+    on the pooled path one memoryview per stripe block, of ``block_size``
+    bytes, into the same arena (a block lies at a stride of
+    ``d × shard_size``, its zero pad after it); one buffer elsewhere. So
+    the caller MUST finish both the md5 fold and every
+    ``append_file(shard_vecs[i])`` before calling :meth:`release` — the
+    release returns the arena to the pool, and a data row read after it
+    reads another request's bytes (docs/ERASURE.md buffer-ownership
+    contract)."""
 
     __slots__ = ("shard_vecs", "raw", "_lease")
 
@@ -463,13 +483,14 @@ class ErasureCoder:
                     v.append(digests[b, i].data)
                     v.append(row.data)
 
-    def _emit_zc(self, lease, nbytes: int) -> EncodedBatch:
-        """Encode the first nbytes (whole stripe blocks) of a pooled
-        ingest arena. The arena IS the dispatch geometry — reshape, no
-        copy — and the batch takes over the lease (released by the
-        caller once md5 + shard appends are done)."""
-        full = nbytes // self.block_size
-        arr = lease.array[:nbytes].reshape(full, self.d, self.shard_size)
+    def _emit_zc(self, lease, full: int) -> EncodedBatch:
+        """Encode the first `full` stripe blocks of a pooled ingest arena,
+        laid at a stride of d × shard_size (each block's zero pad after
+        it). The arena IS the dispatch geometry — reshape, no copy — and
+        the batch takes over the lease (released by the caller once md5 +
+        shard appends are done)."""
+        stride = self.d * self.shard_size
+        arr = lease.array[: full * stride].reshape(full, self.d, self.shard_size)
         vecs: list[list] = [[] for _ in range(self.t)]
         max_blocks = max(1, MAX_DEVICE_SHARDS // self.t)
         for start in range(0, full, max_blocks):
@@ -477,7 +498,8 @@ class ErasureCoder:
             parity, digests = self._encode_full_blocks(chunk)
             with obs.phase("put", "frame"):
                 self._frame_into(vecs, chunk, parity, digests)
-        return EncodedBatch(vecs, lease.view(nbytes), lease)
+        raw = [lease.view(self.block_size, b * stride) for b in range(full)]
+        return EncodedBatch(vecs, raw, lease)
 
     def iter_encode_zc(
         self, reader, max_batch_bytes: int | None = None
@@ -489,22 +511,26 @@ class ErasureCoder:
         full-block path (site "staging" stays 0; the partial tail block
         is the one inherent copy, counted as "tail-block").
 
-        Falls back to the counting legacy path when MINIO_TPU_ZEROCOPY=0
-        (the A/B lever) or when d does not divide block_size (the flat
-        stream cannot alias as [B, d, per] — shard padding interleaves).
+        Block b of a batch lies at arena bytes [b·S, b·S + S), S = d ×
+        shard_size: its block_size input bytes, then the zero pad of its
+        last shard where d does not divide block_size (8 bytes at 12+4;
+        none where it does, S == block_size). Falls back to the counting
+        legacy path when MINIO_TPU_ZEROCOPY=0 (the A/B lever).
         Every yielded batch must be release()d by the caller; abandoning
         the generator releases the in-fill arena via close().
         """
-        per = self.shard_size
-        if self.d * per != self.block_size or not bufpool.zerocopy_enabled():
+        if not bufpool.zerocopy_enabled():
             for chunks, raw in self.iter_encode(reader, max_batch_bytes):
                 with obs.phase("put", "frame"):
                     vecs = [[bytes(c)] for c in chunks]
-                yield EncodedBatch(vecs, raw)
+                _put_batches_add("copy")
+                yield EncodedBatch(vecs, [raw])
             return
+        bs = self.block_size
+        stride = self.d * self.shard_size
         batch_blocks = max(1, MAX_DEVICE_SHARDS // self.t)
         if max_batch_bytes is not None:
-            batch_blocks = max(1, min(batch_blocks, max_batch_bytes // self.block_size))
+            batch_blocks = max(1, min(batch_blocks, max_batch_bytes // bs))
         # round DOWN to a power of two: the dispatcher buckets batch
         # sizes to powers of two, so an exact-fit arena dispatches as-is
         # (no bucket copy, no pad) instead of padding 192 -> 256
@@ -512,11 +538,11 @@ class ErasureCoder:
         while p2 * 2 <= batch_blocks:
             p2 <<= 1
         batch_blocks = p2
-        batch_bytes = batch_blocks * self.block_size
+        batch_bytes = batch_blocks * stride
         pool = bufpool.get_pool()
         lease = None
         mv: memoryview | None = None
-        pos = 0
+        blk = fill = 0  # the block being filled, and its input bytes so far
         ingest = obs.PhaseClock("put", "ingest")  # as in iter_encode: booked once per batch
         try:
             for chunk in reader:
@@ -528,31 +554,39 @@ class ErasureCoder:
                     if lease is None:
                         lease = pool.acquire(batch_bytes)
                         mv = lease.view(batch_bytes)
-                        pos = 0
-                    n = min(len(cmv) - off, batch_bytes - pos)
-                    mv[pos : pos + n] = cmv[off : off + n]
-                    pos += n
+                        blk = fill = 0
+                    at = blk * stride + fill
+                    n = min(len(cmv) - off, bs - fill)
+                    mv[at : at + n] = cmv[off : off + n]
+                    fill += n
                     off += n
-                    if pos == batch_bytes:
-                        ingest.book()
-                        batch, lease, mv = self._emit_zc(lease, pos), None, None
-                        yield batch
-                        ingest.restart()
+                    if fill == bs:
+                        # a pooled arena holds another PUT's bytes: the
+                        # pad is written each time its block fills
+                        lease.array[blk * stride + bs : (blk + 1) * stride] = 0
+                        blk, fill = blk + 1, 0
+                        if blk == batch_blocks:
+                            ingest.book()
+                            batch, lease, mv = self._emit_zc(lease, blk), None, None
+                            _put_batches_add("arena")
+                            yield batch
+                            ingest.restart()
             if lease is not None:
                 ingest.book()
-                full = (pos // self.block_size) * self.block_size
                 # the tail residue is copied OUT of the arena before the
                 # full-block batch hands the lease to the caller
-                tail = bytes(mv[full:pos]) if pos > full else b""
-                if full:
-                    batch, lease, mv = self._emit_zc(lease, full), None, None
+                tail = bytes(mv[blk * stride : blk * stride + fill])
+                if blk:
+                    batch, lease, mv = self._emit_zc(lease, blk), None, None
+                    _put_batches_add("arena")
                     yield batch
                 else:
                     lease.release()
                     lease = mv = None
                 if tail:
+                    _put_batches_add("arena")
                     yield EncodedBatch(
-                        [[bytes(c)] for c in self._encode_tail_buffer(tail)], tail
+                        [[bytes(c)] for c in self._encode_tail_buffer(tail)], [tail]
                     )
         finally:
             if lease is not None:

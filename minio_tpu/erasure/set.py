@@ -731,8 +731,9 @@ class ErasureSet:
                                 " aborting"
                             )
                         with obs.phase("put", "md5"):
-                            md5.update(batch.raw)
-                        size += len(batch.raw)
+                            for piece in batch.raw:
+                                md5.update(piece)
+                                size += len(piece)
                         with obs.phase("put", "drive_write"):
                             futs = []
                             for i, disk in enumerate(self.disks):

@@ -1126,6 +1126,14 @@ def _g_api_tpu(server) -> list[str]:
          "Shards of acknowledged PUTs that no drive took (the drive was "
          "offline or failed mid-stream and write quorum held without it); "
          "each such object is queued for heal")
+    from ..erasure.coder import put_batches_snapshot
+
+    pb = put_batches_snapshot()
+    _fmt(out, "minio_tpu_put_batches_total", "counter",
+         [({"plane": p}, pb[p]) for p in ("arena", "copy")],
+         "Batches the streaming PUT's encode yielded, by its plane: the "
+         "pooled ingest arena in dispatcher geometry (arena) or the legacy "
+         "staging copies that MINIO_TPU_ZEROCOPY=0 selects (copy)")
     from ..erasure.set import stat_drives_asked_snapshot
 
     _fmt(out, "minio_tpu_stat_drives_asked_total", "counter",

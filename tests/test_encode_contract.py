@@ -65,11 +65,13 @@ def _ondrive_files(tmp_path, tag: str, drives: int) -> list[bytes]:
 
 
 # (d, p, family, the plane iter_encode_zc takes, forced onto the numpy rung).
-# 4+3 is nobody else's geometry: its process-wide dispatcher is this file's
-# to demote and put back.
+# "copy" is the legacy plane MINIO_TPU_ZEROCOPY=0 selects; 12+4 takes the
+# arena with each block's 8-byte shard pad. 4+3 is nobody else's geometry:
+# its process-wide dispatcher is this file's to demote and put back.
 CASES = {
     "8+8-arena": (8, 8, "reedsolomon", "arena", False),
-    "12+4-legacy": (12, 4, "reedsolomon", "legacy", False),
+    "12+4-arena": (12, 4, "reedsolomon", "arena", False),
+    "12+4-copy": (12, 4, "reedsolomon", "copy", False),
     "cauchy-4+2": (4, 2, "cauchy", "arena", False),
     "numpy-rung-4+3": (4, 3, "reedsolomon", "arena", True),
 }
@@ -82,7 +84,7 @@ def test_streaming_put_frames_data_rows_from_the_submitted_array(
     d, p, family, plane, numpy_rung = CASES[case]
     monkeypatch.setenv("MINIO_TPU_BACKEND", "jax")
     monkeypatch.setenv("MINIO_TPU_NATIVE_PLANE", "0")
-    monkeypatch.setenv("MINIO_TPU_ZEROCOPY", "1")
+    monkeypatch.setenv("MINIO_TPU_ZEROCOPY", "0" if plane == "copy" else "1")
     monkeypatch.setenv("MINIO_TPU_STREAM_BATCH_MB", "2")
     monkeypatch.delenv("MINIO_COMPRESSION_ENABLE", raising=False)
     coder = ErasureCoder(d, p, family=family)
@@ -100,8 +102,9 @@ def test_streaming_put_frames_data_rows_from_the_submitted_array(
         # the coder's batches, as the streaming PUT consumes them
         files = [bytearray() for _ in range(coder.t)]
         per_frame = 4 if family == "cauchy" else 2  # pieces per (block, shard)
+        pad = d * coder.shard_size - BLOCK_SIZE  # 8 at 12+4, else 0
         for batch in coder.iter_encode_zc(_gen(data), 2 << 20):
-            arena = np.frombuffer(batch.raw, dtype=np.uint8)
+            arena = [np.frombuffer(r, dtype=np.uint8) for r in batch.raw]
             for i in range(coder.t):
                 vec = batch.shard_vecs[i]
                 for piece in vec:
@@ -112,16 +115,19 @@ def test_streaming_put_frames_data_rows_from_the_submitted_array(
                 rows = [np.frombuffer(v, dtype=np.uint8)
                         for j, v in enumerate(vec) if j % 2 == 1]
                 # a data row IS the ingest arena; a parity row never is
-                assert all(np.shares_memory(r, arena) == (i < d) for r in rows), i
+                assert all(
+                    any(np.shares_memory(r, a) for a in arena) == (i < d) for r in rows
+                ), i
             if plane == "arena":
-                # and together the data rows are the arena, byte for byte
-                nblk = len(batch.raw) // BLOCK_SIZE
-                pieces = [
-                    batch.shard_vecs[i][b * per_frame + 1 + 2 * h]
-                    for b in range(nblk) for i in range(d)
-                    for h in range(per_frame // 2)
-                ]
-                assert b"".join(bytes(x) for x in pieces) == bytes(batch.raw)
+                # and together a block's data rows are its input, then its
+                # zero pad, byte for byte
+                assert all(len(r) == BLOCK_SIZE for r in batch.raw)
+                for b, r in enumerate(batch.raw):
+                    pieces = [
+                        batch.shard_vecs[i][b * per_frame + 1 + 2 * h]
+                        for i in range(d) for h in range(per_frame // 2)
+                    ]
+                    assert b"".join(bytes(x) for x in pieces) == bytes(r) + bytes(pad)
             batch.release()
         assert [bytes(f) for f in files] == want
 

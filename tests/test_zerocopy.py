@@ -46,12 +46,19 @@ def _drain(it):
 # ---------------------------------------------------------------------------
 
 
+# (d, p): 2 + 2 divides a stripe block; 12 + 4 (the 16-drive default) and
+# 6 + 2 do not, so their arena lays a block at d × shard_size with a zero pad
+GEOMETRIES = [(2, 2), (12, 4), (6, 2)]
+
+
+@pytest.mark.parametrize("geometry", GEOMETRIES, ids=lambda g: f"{g[0]}+{g[1]}")
 @pytest.mark.parametrize("family", ["reedsolomon", "cauchy"])
 @pytest.mark.parametrize("backend", ["numpy", "jax"])
-def test_streaming_put_byte_identity(tmp_path, monkeypatch, family, backend):
+def test_streaming_put_byte_identity(tmp_path, monkeypatch, family, backend, geometry):
     """Streaming PUT + GET payloads and etags are byte-identical with
     MINIO_TPU_ZEROCOPY=1 and =0 — the pooled-arena path changes where
     bytes live, never what they are."""
+    d, p = geometry
     monkeypatch.setenv("MINIO_TPU_BACKEND", backend)
     monkeypatch.setenv("MINIO_TPU_EC_FAMILY", family)
     monkeypatch.setenv("MINIO_TPU_NATIVE_PLANE", "0")
@@ -60,8 +67,8 @@ def test_streaming_put_byte_identity(tmp_path, monkeypatch, family, backend):
     etags, payloads, ranges = [], [], []
     for zc in ("1", "0"):
         monkeypatch.setenv("MINIO_TPU_ZEROCOPY", zc)
-        es = _store(tmp_path, f"{family[:2]}-{backend[:1]}-{zc}-")
-        oi = es.put_object("zc", "obj", _gen(data))
+        es = _store(tmp_path, f"{family[:2]}-{backend[:1]}-{zc}-", n=d + p)
+        oi = es.put_object("zc", "obj", _gen(data), parity=p)
         assert oi.size == len(data)
         etags.append(oi.etag)
         _, it = es.get_object("zc", "obj")
@@ -124,26 +131,68 @@ def test_pallas_interpret_encode_from_arena_view():
 # ---------------------------------------------------------------------------
 
 
-def test_streaming_put_staging_zero(tmp_path, monkeypatch):
+@pytest.mark.parametrize("geometry", [(2, 2), (12, 4)], ids=lambda g: f"{g[0]}+{g[1]}")
+def test_streaming_put_staging_zero(tmp_path, monkeypatch, geometry):
     """An aligned streaming PUT through the Python plane counts ZERO
-    staging copies — chunks land directly in pooled arenas — while the
-    legacy lever counts at least one per batch."""
+    staging copies — chunks land directly in pooled arenas, at 12 + 4 too,
+    where d does not divide the block — while the legacy lever counts at
+    least one per batch."""
+    d, p = geometry
     monkeypatch.setenv("MINIO_TPU_NATIVE_PLANE", "0")
     monkeypatch.setenv("MINIO_TPU_ZEROCOPY", "1")
     data = RNG.integers(0, 256, size=8 * 1024 * 1024, dtype=np.uint8).tobytes()
-    es = _store(tmp_path, "st1-")
+    es = _store(tmp_path, "st1-", n=d + p)
     bufpool.copies_reset()
-    es.put_object("zc", "obj", _gen(data, step=1 << 20))
+    es.put_object("zc", "obj", _gen(data, step=1 << 20), parity=p)
     snap = bufpool.copies_snapshot()
     assert snap["staging"] == 0, snap
     ps = bufpool.pool_stats_snapshot()
     assert ps["acquires"] > 0 and ps["violations"] == 0
 
     monkeypatch.setenv("MINIO_TPU_ZEROCOPY", "0")
-    es2 = _store(tmp_path, "st0-")
+    es2 = _store(tmp_path, "st0-", n=d + p)
     bufpool.copies_reset()
-    es2.put_object("zc", "obj", _gen(data, step=1 << 20))
+    es2.put_object("zc", "obj", _gen(data, step=1 << 20), parity=p)
     assert bufpool.copies_snapshot()["staging"] > 0
+
+
+def test_a_reused_arena_leaves_no_byte_in_the_shard_pad(tmp_path, monkeypatch):
+    """A pooled arena's bytes are another PUT's: one of the 12 + 4 batch's
+    size, filled with 0xFF and released, is the next streaming PUT's
+    arena — and every data shard's pad bytes on the drives are zero, and
+    every shard is what the numpy codec makes of the block."""
+    monkeypatch.setenv("MINIO_TPU_NATIVE_PLANE", "0")
+    monkeypatch.setenv("MINIO_TPU_ZEROCOPY", "1")
+    monkeypatch.setenv("MINIO_TPU_POOL_MB", "1024")  # room beside other tests' arenas
+    monkeypatch.delenv("MINIO_COMPRESSION_ENABLE", raising=False)
+    monkeypatch.delenv("MINIO_TPU_STREAM_BATCH_MB", raising=False)
+    coder = ErasureCoder(12, 4)
+    per, bs = coder.shard_size, coder.block_size
+    pad = 12 * per - bs
+    assert pad == 8
+    pool = bufpool.get_pool()
+    dirty = pool.acquire(64 * 12 * per)  # the batch at the 64 MiB cap
+    dirty.array[:] = 0xFF
+    dirty.release()
+    hits0 = pool.stats_snapshot()["hits"]
+    data = RNG.integers(0, 256, size=3 * bs + 5, dtype=np.uint8).tobytes()
+    es = _store(tmp_path, "pad-", n=16)
+    es.put_object("zc", "obj", _gen(data, step=65_537), parity=4)
+    assert pool.stats_snapshot()["hits"] > hits0  # it took the dirty arena
+
+    files: list[bytes] = [b""] * 16
+    for i in range(16):
+        root = str(tmp_path / f"pad-{i}")
+        fi = XLStorage(root).read_version("zc", "obj")
+        with open(os.path.join(root, "zc", "obj", fi.data_dir, "part.1"), "rb") as f:
+            files[fi.erasure.index - 1] = f.read()
+    frame = 32 + per
+    for b in range(3):
+        want = coder._np.encode_data(data[b * bs : (b + 1) * bs])  # [16, per]
+        for i in range(16):
+            row = files[i][b * frame + 32 : (b + 1) * frame]
+            assert row == want[i].tobytes(), (b, i)
+        assert files[11][(b + 1) * frame - pad : (b + 1) * frame] == bytes(pad)
 
 
 def test_dispatcher_exact_fit_arena_direct(tmp_path, monkeypatch):
@@ -230,6 +279,31 @@ def test_pool_budget_and_oversize():
     assert s["unpooled"] == 1 and s["resident_bytes"] == 1 << 20
 
 
+def test_pool_classes_above_one_mib_are_whole_mib():
+    """The 12 + 4 ingest arena (64 blocks of 12 × 87,382 B, 512 B over
+    64 MiB) is pooled in the 65 MiB class, recycled and hit; 64 MiB and
+    1 MiB keep their classes; the budget counts a class's real bytes."""
+    cls = bufpool.BufferPool._class_for
+    assert cls(67_109_376) == 65 << 20 < 1 << 27
+    assert cls(64 << 20) == 64 << 20 and cls(128 << 20) == 128 << 20
+    assert cls(1 << 20) == 1 << 20 and cls(4096) == 1 << 16
+    assert cls((1 << 20) + 1) == 2 << 20 and cls(16 * 12 * 87_382) == 17 << 20
+    pool = bufpool.BufferPool(budget_bytes=65 << 20)
+    a = pool.acquire(67_109_376)
+    arena = a.array
+    assert arena.nbytes == 65 << 20
+    a.release()
+    s = pool.stats_snapshot()
+    assert s["resident_bytes"] == 65 << 20 and s["unpooled"] == 0
+    b = pool.acquire(67_109_376)
+    assert b.array is arena and pool.stats_snapshot()["hits"] == 1
+    c = pool.acquire(64 << 20)  # another class: a miss, not the 65 MiB arena
+    assert c.array.nbytes == 64 << 20 and pool.stats_snapshot()["misses"] == 2
+    b.release()
+    c.release()  # over the budget beside the 65 MiB arena: not kept
+    assert pool.stats_snapshot()["resident_bytes"] == 65 << 20
+
+
 # ---------------------------------------------------------------------------
 # chaos: buffers referenced by in-flight requests never get recycled
 # ---------------------------------------------------------------------------
@@ -300,23 +374,37 @@ def test_mid_get_invalidation_never_poisons_served_chunks(tmp_path, monkeypatch)
 # ---------------------------------------------------------------------------
 
 
+MIB = 1024 * 1024
+# (body bytes, reader chunk bytes): chunks that straddle block edges
+BODIES = [
+    (0, 1), (1, 1), (MIB - 1, 65_537), (MIB, 8), (MIB + 1, 700_001),
+    (3 * MIB + 777, 700_001), (64 * MIB + 777, MIB + 3),
+]
+
+
+@pytest.mark.parametrize("body,step", BODIES, ids=lambda v: str(v))
+@pytest.mark.parametrize("geometry", GEOMETRIES, ids=lambda g: f"{g[0]}+{g[1]}")
 @pytest.mark.parametrize("family", ["reedsolomon", "cauchy"])
-def test_iter_encode_zc_matches_legacy_shards(family):
+def test_iter_encode_zc_matches_legacy_shards(family, geometry, body, step):
     """iter_encode_zc's writev vectors concatenate to the exact shard
-    files the legacy staging path produces, tail block included."""
-    coder = ErasureCoder(2, 2, family=family)
-    data = RNG.integers(0, 256, size=3 * 1024 * 1024 + 777,
-                        dtype=np.uint8).tobytes()
-    want = coder.encode_part(data).shard_files
+    files the legacy staging path produces, tail block included, at the
+    streaming PUT's 64 MiB batch; its raw pieces are the body."""
+    d, p = geometry
+    coder = ErasureCoder(d, p, family=family)
+    data = RNG.integers(0, 256, size=body, dtype=np.uint8).tobytes()
+    want = coder.encode_part(data).shard_files if body else [b""] * coder.t
     files = [bytearray() for _ in range(coder.t)]
-    raw = bytearray()
-    for batch in coder.iter_encode_zc(iter(_gen(data)), 1 << 21):
-        raw += batch.raw
+    md5, raw = hashlib.md5(), bytearray()
+    for batch in coder.iter_encode_zc(iter(_gen(data, step)), 64 * MIB):
+        for piece in batch.raw:
+            md5.update(piece)
+            raw += piece
         for i in range(coder.t):
             for piece in batch.shard_vecs[i]:
                 files[i] += piece
         batch.release()
     assert bytes(raw) == data
+    assert md5.hexdigest() == hashlib.md5(data).hexdigest()
     assert [bytes(f) for f in files] == want
 
 
