@@ -92,7 +92,8 @@ _ALL: list[Knob] = [
        "op does not pass an explicit size."),
     _k("MINIO_TPU_PROFILE_CONTINUOUS", "1", "diag",
        "Always-on wall-time attribution sampler (~19 Hz, publishes the "
-       "/api/diag attribution series); 0 disables."),
+       "/api/diag attribution series) and, on its tick, the GIL-wait and "
+       "event-loop-lag probes of /api/tpu; 0 disables all three."),
     _k("MINIO_TPU_PROFILE_CONTINUOUS_HZ", "19", "diag",
        "Continuous profiler sample rate in Hz (clamped to [1, 250]); "
        "prime-ish default avoids phase-locking with periodic work."),

@@ -10,9 +10,10 @@ open spans unconditionally.
 seconds and one call to a process-global table whether or not anyone
 subscribes (``phases_snapshot()``; exported on ``/api/tpu``), publishes
 the same ``Span`` record when someone does, and — for the leaf phases
-that surround a device call (the dispatch thread's, the device decode's
-and a reconstructing GET's own) — enters a ``jax.profiler.TraceAnnotation``
-so the phase lands in a device trace on the profiler's own clock.
+of the threads that feed the device (a streaming PUT's request thread,
+the dispatch thread's work, the device decode's and a reconstructing
+GET's own) — enters a ``jax.profiler.TraceAnnotation`` so the phase lands
+in a device trace on the profiler's own clock.
 
 The request context is a ``contextvars.ContextVar`` so it survives both
 ``await`` hops and executor hops (``ContextPool``/``bind_context`` copy
@@ -288,17 +289,22 @@ _PHASE_TYPES = {"dispatch": TYPE_TPU, "put": TYPE_INTERNAL,
                 "get": TYPE_INTERNAL, "decode": TYPE_TPU,
                 "op": TYPE_INTERNAL, "stat": TYPE_INTERNAL,
                 "delete": TYPE_INTERNAL, "trash": TYPE_STORAGE}
-# the phases that go to the profiler: leaves only. An enclosing phase
-# (`put`/`encode_wait`, `get`/`decode_wait`) would win every idle gap of a
-# device trace and say nothing; the pools' threads (`drive_io`,
-# `shard_io`) would bury it in events; the event loop's (`body_wait`,
-# `body_write`, every `op` row) span awaits during which the loop serves
-# other requests.
+# the phases that go to the profiler: leaves of the threads that feed the
+# device, so that an idle gap of a device trace is named by the work the
+# host was doing. An enclosing phase (`put`/`encode_wait`,
+# `get`/`decode_wait`) would win every idle gap and say nothing, and so
+# would a wait for work (`dispatch`/`wait`, `window`: the dispatch thread
+# with nothing to do); the pools' threads (`drive_io`, `shard_io`) would
+# bury it in events; the event loop's (`body_wait`, `body_write`, every
+# `op` row) span awaits during which the loop serves other requests; a
+# stopwatch across a yield (`get`/`respond`, `start`) may end on another
+# thread than it began on, which a TraceAnnotation cannot.
 _ANNOTATED = {
-    "dispatch": frozenset(PHASES["dispatch"]),
+    "dispatch": frozenset(PHASES["dispatch"]) - {"wait", "window"},
+    "put": frozenset({"ingest", "stage", "frame", "md5", "drive_write", "commit"}),
     "decode": frozenset(PHASES["decode"]),
     "get": frozenset(PHASES["get"])
-    - {"decode_wait", "shard_io", "body_wait", "body_write"},
+    - {"decode_wait", "shard_io", "body_wait", "body_write", "start", "respond"},
     "stat": frozenset({"meta_read"}),
     "delete": frozenset(PHASES["delete"]) - {"lock_wait"},
     "trash": frozenset(PHASES["trash"]),
@@ -330,16 +336,24 @@ class PhaseClock:
     generator's ingest between two yields. ``book()`` adds the wall and
     thread CPU seconds since the last ``restart()`` (or construction) as
     one call, and publishes the record a ``phase()`` would when tracing is
-    active; ``restart()`` after the yield leaves the consumer's time out."""
+    active; ``restart()`` after the yield leaves the consumer's time out.
+    An annotated (layer, name) makes each stretch from ``restart()`` to
+    ``book()`` one TraceAnnotation, as ``PhaseSum`` does: only a stretch
+    that stays on one thread may be."""
 
-    __slots__ = ("_layer", "_name", "_row", "_t0", "_c0")
+    __slots__ = ("_layer", "_name", "_row", "_ann", "_t0", "_c0")
 
     def __init__(self, layer: str, name: str):
         self._layer, self._name = layer, name
         self._row = _phase_table[(layer, name)]
+        self._ann = None
         self.restart()
 
     def restart(self) -> None:
+        if _annotated(self._layer, self._name):
+            self._ann = _trace_annotation(f"{self._layer}.{self._name}")
+            if self._ann is not None:
+                self._ann.__enter__()
         self._c0 = time.thread_time()
         self._t0 = time.monotonic()
 
@@ -348,6 +362,9 @@ class PhaseClock:
         on another thread than it began on (a generator resumed by an
         executor), where a thread's CPU clock says nothing."""
         wall = time.monotonic() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
         _phase_book(
             self._row, wall, time.thread_time() - self._c0 if cpu else 0.0
         )
@@ -403,6 +420,11 @@ class PhaseSum:
             self._n = 0
 
 
+def _annotated(layer: str, name: str) -> bool:
+    """Whether the (layer, phase) goes to the profiler (`_ANNOTATED`)."""
+    return name in _ANNOTATED.get(layer, ())
+
+
 def _trace_annotation(label: str):
     """A profiler TraceMe, only where jax is already loaded: the phase
     clock must never be what imports jax into a CPU-plane process. With no
@@ -432,7 +454,7 @@ class Phase:
         # see nothing but an enclosing request-thread phase
         self._ann = (
             _trace_annotation(f"{layer}.{name}")
-            if name in _ANNOTATED.get(layer, ()) else None
+            if _annotated(layer, name) else None
         )
         self._t0 = self._c0 = 0.0
 

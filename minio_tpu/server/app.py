@@ -1356,6 +1356,9 @@ def main(argv: list[str] | None = None) -> None:
                 print(f"SFTP gateway on port {args.sftp}", flush=True)
 
         app["bootstrap"] = asyncio.create_task(boot_then_gateways())
+        if srv.cprofiler is not None:
+            # the sampler's tick probes this loop's lag from now on
+            srv.cprofiler.watch_loop(asyncio.get_running_loop())
 
         if sanitizer.enabled():
             # stall watchdog on the serving loop: blocking work that the

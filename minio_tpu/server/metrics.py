@@ -1004,6 +1004,30 @@ def _g_api_tpu(server) -> list[str]:
          [({"layer": layer, "phase": name}, row[2])
           for (layer, name), row in phases],
          "Entries of each named phase")
+    # the continuous sampler's two probes (server/profiling.py): every
+    # row there from the first scrape, at zero where the sampler is off
+    from . import profiling
+
+    cp = getattr(server, "cprofiler", None)
+    snap = cp.snapshot() if cp is not None else {}
+    gil = snap.get("gil_wait") or profiling.WaitHistogram().copy()
+    lag = snap.get("loop_lag") or profiling.WaitHistogram().copy()
+    _fmt(out, "minio_tpu_gil_wait_seconds_bucket", "counter",
+         _hist_rows(profiling.WAIT_BUCKETS, gil["counts"]),
+         "Lateness of the continuous sampler's wake-up: the OS wake-up "
+         "plus the wait for the interpreter lock")
+    _fmt(out, "minio_tpu_gil_wait_seconds_sum", "counter",
+         [({}, f"{gil['sum_s']:.6f}")])
+    _fmt(out, "minio_tpu_gil_wait_seconds_count", "counter",
+         [({}, gil["count"])])
+    _fmt(out, "minio_tpu_loop_lag_seconds_bucket", "counter",
+         _hist_rows(profiling.WAIT_BUCKETS, lag["counts"]),
+         "Wait of a call_soon_threadsafe callback for the server's event "
+         "loop, handed off once a sampler tick")
+    _fmt(out, "minio_tpu_loop_lag_seconds_sum", "counter",
+         [({}, f"{lag['sum_s']:.6f}")])
+    _fmt(out, "minio_tpu_loop_lag_seconds_count", "counter",
+         [({}, lag["count"])])
     first = {(r, b): (0, 0.0) for r in dmod.RUNGS
              for b in dmod.BUCKET_BLOCK_BUCKETS}
     first.update({

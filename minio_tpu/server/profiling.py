@@ -25,11 +25,14 @@ rate, same idea as Linux perf's default 99 Hz) avoids phase-locking
 with 10/20/100 Hz periodic work; at ~50 ms per sample over a handful of
 threads the overhead is far below one percent. Counts are mutated and
 snapshotted under one lock (the dispatcher-stats snapshot idiom) — the
-runtime sanitizer sees no unguarded shared state.
+runtime sanitizer sees no unguarded shared state. Its tick carries the
+two probes of the interpreter lock and the event loop (exported on
+``/api/tpu``); MINIO_TPU_PROFILE_CONTINUOUS=0 turns all of it off.
 """
 
 from __future__ import annotations
 
+import bisect
 import os
 import sys
 import threading
@@ -198,18 +201,64 @@ def classify_stack(frame) -> tuple[str, str]:
     return "other", state
 
 
+# upper edges, seconds, of the two probes' histograms (0.1 ms to 1 s; the
+# last bucket, +Inf, holds the rest)
+WAIT_BUCKETS = (0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
+                0.025, 0.05, 0.1, 0.25, 0.5, 1.0)
+
+
+class WaitHistogram:
+    """Fixed-edge counts, their sum and number, of one probe's waits. Not
+    locked: its owner books and copies it under its own lock."""
+
+    __slots__ = ("counts", "sum_s")
+
+    def __init__(self):
+        self.counts = [0] * (len(WAIT_BUCKETS) + 1)
+        self.sum_s = 0.0
+
+    def add(self, seconds: float) -> None:
+        self.counts[bisect.bisect_left(WAIT_BUCKETS, seconds)] += 1
+        self.sum_s += seconds
+
+    def copy(self) -> dict:
+        return {"counts": list(self.counts), "sum_s": self.sum_s,
+                "count": sum(self.counts)}
+
+
 class ContinuousProfiler:
     """The always-on sampler thread. ``snapshot()`` is the ONLY reader
-    and the sampler loop the only writer, both under ``_mu`` — the
-    dispatcher-stats snapshot idiom, no unguarded shared Counter."""
+    and the sampler loop (and the event-loop callback it hands off) the
+    only writers, all under ``_mu`` — the dispatcher-stats snapshot idiom,
+    no unguarded shared Counter.
+
+    Each tick also carries two probes. **GIL wait**: the tick's lateness,
+    the monotonic time once ``Event.wait`` has returned minus the time it
+    was due to end — what any thread pays to come back from a blocking
+    call: the OS wake-up (the floor, at rest) and the queue for the
+    interpreter lock. **Event-loop lag**: a callback handed to the
+    server's loop with ``call_soon_threadsafe`` (the route a GET body's
+    pieces and a PUT's body chunks take) books how long it waited to run.
+    A stack sampled while its thread queues for the lock is classified
+    ``running``: the sampler sees the frame the thread will resume, not
+    whether it holds the lock."""
 
     def __init__(self, hz: float = 19.0):
         self.hz = max(1.0, min(hz, 250.0))
         self._mu = threading.Lock()
         self._counts: Counter[tuple[str, str]] = Counter()
         self._samples = 0
+        self._gil = WaitHistogram()
+        self._lag = WaitHistogram()
+        self._event_loop = None
         self._stop = threading.Event()
         self._thread: threading.Thread | None = None
+
+    def watch_loop(self, loop) -> None:
+        """Probe `loop`'s lag from the next tick on (the server's loop,
+        once it runs)."""
+        with self._mu:
+            self._event_loop = loop
 
     def start(self) -> "ContinuousProfiler":
         self._thread = threading.Thread(
@@ -229,14 +278,34 @@ class ContinuousProfiler:
                 "samples": self._samples,
                 "counts": dict(self._counts),
                 "hz": self.hz,
+                "gil_wait": self._gil.copy(),
+                "loop_lag": self._lag.copy(),
             }
+
+    def _lagged(self, handed: float) -> None:
+        """On the event loop: book how long the hand-off waited."""
+        lag = time.monotonic() - handed
+        with self._mu:
+            self._lag.add(lag)
 
     def _loop(self) -> None:
         me = threading.get_ident()
         interval = 1.0 / self.hz
         # Event.wait doubles as the pacing sleep and the stop signal;
         # the dedicated daemon sampler thread never serves requests
-        while not self._stop.wait(interval):
+        while True:
+            due = time.monotonic() + interval
+            if self._stop.wait(interval):
+                return
+            late = time.monotonic() - due
+            with self._mu:
+                self._gil.add(max(late, 0.0))
+                loop = self._event_loop
+            if loop is not None:
+                try:
+                    loop.call_soon_threadsafe(self._lagged, time.monotonic())
+                except RuntimeError:  # a closed loop: nothing to probe
+                    pass
             tick: Counter[tuple[str, str]] = Counter()
             for tid, frame in sys._current_frames().items():
                 if tid == me:
@@ -249,7 +318,8 @@ class ContinuousProfiler:
 
 def start_continuous_from_env() -> ContinuousProfiler | None:
     """The knob-gated boot hook (server/app.py main): returns a started
-    profiler, or None when MINIO_TPU_PROFILE_CONTINUOUS=0."""
+    profiler, or None when MINIO_TPU_PROFILE_CONTINUOUS=0 — which turns
+    the GIL-wait and event-loop-lag probes off with the sampler."""
     if os.environ.get(CONTINUOUS_KNOB, "1") == "0":
         return None
     try:

@@ -242,11 +242,13 @@ def test_a_decode_shape_counts_one_first_call_when_it_ends(served):
 def test_the_profiler_gets_the_leaves_and_no_enclosing_phase():
     pytest.importorskip("jax")
     for layer, name in (("decode", "kernel"), ("decode", "host"), ("get", "read_wait"),
-                        ("get", "join"), ("get", "respond")):
+                        ("get", "join"), ("get", "stack")):
         assert obs.phase(layer, name)._ann is not None, (layer, name)
     # an enclosing phase would win every idle gap; the pool's threads would bury it
     assert obs.phase("get", "decode_wait")._ann is None
     assert obs.phase("get", "shard_io")._ann is None
+    # the stopwatch across a yield may be resumed by another thread
+    assert obs.PhaseClock("get", "respond")._ann is None
 
 
 # --------------------------------------------------------------------------

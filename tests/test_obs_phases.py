@@ -14,6 +14,7 @@ import pytest
 
 from minio_tpu import obs
 from minio_tpu.client import S3Client
+from minio_tpu.obs import trace
 from minio_tpu.server.metrics import TracePubSub
 
 from test_s3_api import ServerThread
@@ -160,6 +161,38 @@ def test_only_dispatch_phases_go_to_the_profiler():
     # a request-thread phase encloses whole dispatches: it would win every
     # idle gap of a device trace and say nothing
     assert obs.phase("put", "encode_wait")._ann is None
+
+
+def test_the_profiler_annotations_are_the_leaves_of_the_threads_that_feed_the_device():
+    """An idle gap of a device trace is named by the host event covering
+    most of it: the request work, not a wait."""
+    ann = trace._ANNOTATED
+    assert ann["put"] == {"ingest", "stage", "frame", "md5", "drive_write", "commit"}
+    # waits stay out: the dispatch thread's wait for work, a PUT's wait for
+    # its dispatch, the drive pool's own phase
+    assert ann["dispatch"] == set(obs.PHASES["dispatch"]) - {"wait", "window"}
+    assert "encode_wait" not in ann["put"] and "drive_io" not in ann["put"]
+    # a stopwatch across a yield may end on another thread: never annotated
+    assert not {"respond", "start", "body_wait", "body_write"} & ann["get"]
+    assert "op" not in ann
+    pytest.importorskip("jax")
+    assert obs.phase("put", "md5")._ann is not None
+    assert obs.phase("dispatch", "wait")._ann is None
+
+
+def test_an_annotated_stopwatch_is_one_annotation_a_stretch():
+    pytest.importorskip("jax")
+    clock = obs.PhaseClock("put", "ingest")
+    assert clock._ann is not None  # entered at the stretch's start
+    _, _, n0 = _row("put", "ingest")
+    clock.book()
+    assert clock._ann is None  # ... and left where it is booked
+    assert _row("put", "ingest")[2] == n0 + 1
+    clock.restart()
+    assert clock._ann is not None
+    clock.book()
+    assert obs.PhaseClock("get", "respond")._ann is None
+    assert obs.PhaseClock("op", "get_object")._ann is None
 
 
 # -- one streaming PUT, a subscriber attached: one tree -----------------------
