@@ -89,6 +89,23 @@ def metric_names(bench: dict, group: str, cell: str) -> list[dict]:
     return [m for m in bench[group] if cell in m.get("workloads", [cell])]
 
 
+def in_window(records: list, t0: float, t1: float) -> tuple[list, list, list]:
+    """(the requests that completed inside the window, those of them
+    acknowledged 200, the others)."""
+    timed = [r for r in records if t0 <= r.done <= t1]
+    return timed, [r for r in timed if r.status == 200], [r for r in timed if r.status != 200]
+
+
+def end_to_end(acked: list, lat_ms: list[float], window_s: float, setup_s: float) -> dict:
+    """The end-to-end metrics of a run, by name: `acked` are the requests
+    acknowledged 200 that completed inside the window (every kind: GET, PUT,
+    HEAD, DELETE), `lat_ms` their latencies."""
+    return {"s3_mib_s": sum(r.nbytes for r in acked) / (1 << 20) / window_s,
+            "s3_ops_s": len(acked) / window_s,
+            "s3_p95_ms": traffic_mod.percentile(lat_ms, 0.95),
+            "setup_s": setup_s}
+
+
 def make_generator(mix: dict, endpoint: str, bucket: str, seed: int, config: dict,
                    drives: list[str]):
     """The generator the traffic file names, built with the four arguments
@@ -206,9 +223,7 @@ def run(args) -> int:
         mem = srv.ask("memstats")
 
         records = early + gen.records()
-        timed = [r for r in records if t0 <= r.done <= t1]
-        acked = [r for r in timed if r.status == 200]
-        failed = [r for r in timed if r.status != 200]
+        timed, acked, failed = in_window(records, t0, t1)
         check(acked, f"no request was acknowledged in the window "
               f"({len(failed)} failed: {failed[0].error if failed else ''})")
         acked_bytes = sum(r.nbytes for r in acked)
@@ -250,9 +265,7 @@ def run(args) -> int:
     finally:
         cleanup()
 
-    e2e = {"s3_mib_s": acked_bytes / (1 << 20) / window_s,
-           "s3_p95_ms": traffic_mod.percentile(lat_ms, 0.95),
-           "setup_s": setup_s}
+    e2e = end_to_end(acked, lat_ms, window_s, setup_s)
     units = {m["name"]: m["unit"] for g in ("end_to_end", "per_layer") for m in bench[g]}
     if args.trace:
         w = metrics_mod.Window(

@@ -76,9 +76,9 @@ def test_the_cells_the_configuration_and_the_metrics_are_held_by_name_and_order(
     from chipbench.run import metric_names
 
     for cell in (OFF, HEALTHY):
-        # the rate and the set-up, no tail: `s3_p95_ms` lists its cells by name
+        # the two rates and the set-up, no tail: `s3_p95_ms` lists its cells by name
         assert {m["name"] for m in metric_names(BENCH, "end_to_end", cell)} \
-            == {"s3_mib_s", "setup_s"}
+            == {"s3_mib_s", "s3_ops_s", "setup_s"}
         mine = {m["name"] for m in metric_names(BENCH, "per_layer", cell)}
         assert mine >= {n for n, cells in READERS.items() if cell in cells} | {
             "server_cpu_s_per_gib", "window_compiles", "device_idle_share"}

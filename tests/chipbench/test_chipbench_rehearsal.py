@@ -120,6 +120,9 @@ def test_traced_rehearsal_reports_counters_and_no_device_metric(cache):
     assert not {"codec_roofline", "device_idle_share"} & set(last["metrics"])
     assert last["device"]["busy_s"] is None and last["device"]["window_s"] > 0
     assert "breakdown" not in last and last["correct"] is True
+    # the end-to-end metrics ride along on the traced line, the operations rate among them
+    assert {m["name"] for m in BENCH["end_to_end"]} <= set(last["end_to_end_traced"])
+    assert last["end_to_end_traced"]["s3_ops_s"] > 0
 
 
 def test_refuses_to_pass_without_a_tpu(cache, tmp_path):

@@ -68,7 +68,8 @@ def test_metric_entry(m):
 def test_metric_names_are_unique_and_setup_is_there():
     names = [m["name"] for m in ALL_METRICS]
     assert len(set(names)) == len(names)
-    assert {"s3_mib_s", "s3_p95_ms", "setup_s"} <= {m["name"] for m in BENCH["end_to_end"]}
+    assert {"s3_mib_s", "s3_p95_ms", "setup_s", "s3_ops_s"} \
+        <= {m["name"] for m in BENCH["end_to_end"]}
 
 
 @pytest.mark.parametrize("c", BENCH["configs"], ids=lambda c: c["name"])
@@ -155,6 +156,60 @@ def test_every_cell_reports_what_its_per_layer_metrics_move():
         # a metric that lists this cell, or lists none, must move something
         # the cell reports
         assert mine and all(m["moves"] in e2e for m in mine), w["name"]
+
+
+# ---- the end-to-end metrics of a window -------------------------------------
+
+
+def record(op, done, status=200, mib=0):
+    return traffic.Request(client=0, op=op, key="k", body=0, sent=done - 0.5, done=done,
+                           status=status, ok=status == 200,
+                           nbytes=mib * traffic.MIB if status == 200 else 0)
+
+
+def test_s3_ops_s_counts_every_kind_acknowledged_inside_the_window():
+    from chipbench.run import end_to_end, in_window
+
+    inside = [record("GET", 11.0, mib=10), record("PUT", 12.0, mib=10), record("HEAD", 13.0),
+              record("DELETE", 14.0), record("GET", 15.0, mib=10), record("HEAD", 20.0)]
+    records = [record("PUT", 9.5, mib=10)] + inside + [
+        record("PUT", 16.0, status=500),   # failed: in `failed`, not a rate
+        record("GET", 20.5, mib=10)]       # finished after the window closed
+    timed, acked, failed = in_window(records, 10.0, 20.0)
+    assert acked == inside and [r.status for r in failed] == [500] and len(timed) == 7
+    e2e = end_to_end(acked, [500.0] * len(acked), 10.0, 42.0)
+    assert e2e["s3_ops_s"] == pytest.approx(6 / 10.0)
+    # the HEADs and the DELETE move no object bytes: the rate of bytes is blind to them
+    assert e2e["s3_mib_s"] == pytest.approx(30 / 10.0)
+    assert e2e["setup_s"] == 42.0 and e2e["s3_p95_ms"] == 500.0
+    assert {m["name"] for m in BENCH["end_to_end"]} <= set(e2e)
+
+
+@pytest.mark.parametrize("n,window_s", [(1, 51.0), (97, 51.0), (140, 50.987654321),
+                                        (403, 53.8)])
+def test_s3_ops_s_is_s3_mib_s_over_64_where_every_request_moves_64_mib(n, window_s):
+    from chipbench.run import end_to_end
+
+    acked = [record("GET" if i % 2 else "PUT", 1.0 + i / n, mib=64) for i in range(n)]
+    e2e = end_to_end(acked, [1.0] * n, window_s, 1.0)
+    assert e2e["s3_ops_s"] * 64 == pytest.approx(e2e["s3_mib_s"], rel=1e-12, abs=0)
+
+
+def test_s3_ops_s_is_held_by_name_with_the_bound_of_s3_mib_s():
+    e2e = {m["name"]: m for m in BENCH["end_to_end"]}
+    # no `workloads` key: every cell reports it, those appended later too
+    assert e2e["s3_ops_s"] == {"name": "s3_ops_s", "unit": "ops/s", "better": "higher",
+                               "bound": 0.25, "source": "host_clock"}
+    # one value (s3_mib_s / 64 in the 64 MiB cells) never gets two verdicts
+    assert e2e["s3_ops_s"]["bound"] == e2e["s3_mib_s"]["bound"]
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in BENCH["workloads"]]
+                         + ["a-config.a-mix-appended-later"])
+def test_every_cell_reports_s3_ops_s(cell):
+    from chipbench.run import metric_names
+
+    assert "s3_ops_s" in {m["name"] for m in metric_names(BENCH, "end_to_end", cell)}
 
 
 def test_pairs_are_unique_and_every_config_is_used():

@@ -74,10 +74,12 @@ def test_the_cell_the_configuration_and_the_readers_are_held_by_name_and_order()
         assert m["source"] == "program_counter" and m["layer"] == READERS[m["name"]]
     from chipbench.run import metric_names
 
-    # the rate and the set-up, no tail: `s3_p95_ms` lists its cells by name
-    assert {m["name"] for m in metric_names(BENCH, "end_to_end", CELL)} == {"s3_mib_s", "setup_s"}
+    # the two rates and the set-up, no tail: `s3_p95_ms` lists its cells by name
+    assert {m["name"] for m in metric_names(BENCH, "end_to_end", CELL)} \
+        == {"s3_mib_s", "s3_ops_s", "setup_s"}
     mine = {m["name"] for m in metric_names(BENCH, "per_layer", CELL)}
-    assert mine >= set(READERS) | {"server_cpu_s_per_gib", "window_compiles", "device_idle_share"}
+    assert mine >= set(READERS) | {"server_cpu_s_per_gib", "window_compiles", "device_idle_share",
+                                   "window_first_calls"}
     # no roofline reader: the encode is the PUT cells' kernels, no new one is added
     assert not {n for n in mine if n.endswith("_roofline")}
     # and no cell that was there reports a reader of this one
